@@ -23,7 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...framework.core import Tensor, no_grad, _Slot
 from ...framework.random import split_key
-from ...framework.jax_compat import shard_map
+from jax import shard_map
 from ...framework import fault_injection as _fault
 from ...jit.api import (functional_call, state_arrays, aot_compile,
                         count_train_use, export_step_metrics,
@@ -152,8 +152,14 @@ class HybridTrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
         self.param_shardings = {
             k: NamedSharding(mesh, store_specs[k])
             for k in self.param_specs}
+        # params are donated every step; place a private copy
+        # (jnp.array), as TrainStep does, so the model's own Parameters
+        # stay valid: device_put of a replicated leaf shares the buffer
+        # on the device it already lives on — even with may_alias=False
+        # under jax 0.9.0 — and the first step's donation would delete
+        # the model's array with it
         self.params = {
-            k: jax.device_put(v, self.param_shardings[k])
+            k: jax.device_put(jnp.array(v), self.param_shardings[k])
             for k, v in params.items()}
         self.buffers = buffers
 
@@ -225,9 +231,17 @@ class HybridTrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
             def run(inputs):
                 from ...jit.api import (reset_aux_losses,
                                         collect_aux_losses)
+                from ...ops import kernels_partitioned_over
                 reset_aux_losses(model_ref)
-                out = functional_call(model_ref, ps, bufs, inputs[:-1],
-                                      rng_key=key, training=True)
+                # this step is ONE auto-partitioned program over the
+                # mesh, and a Mosaic kernel cannot be partitioned
+                # automatically: while the forward traces, the flash
+                # kernel runs per shard — batch over 'dp', heads over
+                # 'mp', the axes this step shards them on
+                with kernels_partitioned_over(mesh, "dp", "mp"):
+                    out = functional_call(model_ref, ps, bufs,
+                                          inputs[:-1], rng_key=key,
+                                          training=True)
                 tgt = Tensor(inputs[-1])
                 l = loss_fn(out if isinstance(out, Tensor) else Tensor(out),
                             tgt)

@@ -20,7 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ...framework.jax_compat import shard_map
+from jax import shard_map
 
 from ...framework.core import Tensor, no_grad, _Slot
 from ...framework.random import split_key

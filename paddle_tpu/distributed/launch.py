@@ -44,10 +44,16 @@ def _parse(argv=None):
     base.add_argument("--nproc_per_node", type=int,
                       default=int(os.environ.get("PADDLE_NPROC_PER_NODE",
                                                  "1")),
-                      help="ranks to launch on this host (TPU: usually 1 "
-                           "process drives all local chips; >1 needs "
-                           "--devices to partition chips across ranks, "
-                           "or the CPU backend for testing)")
+                      help="ranks to launch on this host. A chip belongs "
+                           "to ONE process at a time: on a host with "
+                           "chips either one process drives them all "
+                           "(the default 1; fleet.init in a single "
+                           "controller) or --devices splits them across "
+                           "the ranks. More than one rank WITHOUT "
+                           "--devices gives every rank every chip, and "
+                           "the second rank fails on the chip's lock — "
+                           "that combination is for the CPU backend "
+                           "(tests) only")
     base.add_argument("--log_dir", default=None,
                       help="per-rank logs as <log_dir>/workerlog.<rank>; "
                            "default: ranks inherit the launcher's stdout")

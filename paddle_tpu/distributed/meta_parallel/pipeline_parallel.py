@@ -24,7 +24,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from ...framework.jax_compat import shard_map
+from jax import shard_map
 
 from ...framework.core import Tensor
 from ...jit.api import functional_call, state_arrays, _bind, _restore

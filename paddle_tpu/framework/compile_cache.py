@@ -9,20 +9,28 @@ it on for the WHOLE framework at import time, so every
 process is written to (and reloaded from) disk. A warm process skips the
 cold compile entirely.
 
-Environment knobs (documented in docs/PERFORMANCE.md):
+ONE rule says where the cache lives (`resolve_cache_dir`, jax-free so
+bench.py's parent can ask it too):
 
-  PADDLE_TPU_COMPILE_CACHE        cache directory; "0"/"off"/"none"
-                                  disables. Default:
-                                  ~/.cache/paddle_tpu/xla_cache
+  1. `JAX_COMPILATION_CACHE_DIR` set: JAX reads it itself and the
+     framework writes `jax_compilation_cache_dir` NOWHERE — whoever runs
+     the program (a chip tool that carries the cache home, a CI job)
+     places the cache from outside.
+  2. else `PADDLE_TPU_COMPILE_CACHE`: a directory, or "0"/"off"/"none"
+     to disable (the test suite's setting).
+  3. else `<checkout>/.xla_cache` (in .gitignore) — a fixed path inside
+     the tree, never the home directory, a temporary name, a pid or a
+     time: the path must not move between runs for a second run to
+     start warm.
+
   PADDLE_TPU_CACHE_MIN_COMPILE_SECS  only cache compiles slower than this
                                   (default 0: cache everything — a bench
                                   or trainer wants every entry warm)
   PADDLE_TPU_CACHE_MIN_ENTRY_BYTES   skip entries smaller than this
                                   (default 0)
 
-The cache is an optimization, never a blocker: any failure to configure
-it (read-only filesystem, old jaxlib) leaves the framework fully
-functional with cold compiles.
+A cache directory that cannot be created is reported once (a warning
+naming the path) and the process runs with cold compiles.
 
 Beyond the on-at-import wiring, this module owns two more cache
 concerns:
@@ -47,15 +55,19 @@ import os
 import shutil
 import threading
 import time
+import warnings
 
-import jax
+# jax is imported inside the functions that configure it: the path rule
+# (resolve_cache_dir) and the file helpers stay importable by a process
+# that must not touch jax (bench.py's parent, tools/seed_compile_cache)
 
 __all__ = ["enable_compile_cache", "disable_compile_cache", "cache_dir",
-           "DEFAULT_CACHE_DIR", "pack", "seed_from", "observe_compile",
-           "PACK_SCHEMA"]
+           "resolve_cache_dir", "DEFAULT_CACHE_DIR", "pack", "seed_from",
+           "observe_compile", "PACK_SCHEMA"]
 
 DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "paddle_tpu", "xla_cache")
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
 
 _OFF_VALUES = ("0", "off", "none", "false", "disabled")
 
@@ -67,78 +79,77 @@ def cache_dir():
     return _state["dir"]
 
 
-def enable_compile_cache(path=None):
-    """Point JAX's persistent compilation cache at `path` (or the
-    PADDLE_TPU_COMPILE_CACHE env var, or the default user-cache dir).
+def _is_off(value):
+    return str(value).strip().lower() in _OFF_VALUES
 
-    Idempotent; safe to call before or after backend init (the config is
-    consulted at compile time). Returns the active directory, or None
-    when disabled/unavailable. An explicit `path` wins over the env var;
-    with neither, a cache dir some earlier caller already configured on
-    jax (e.g. bench.py's child before importing the framework) is kept
-    rather than clobbered.
-    """
-    env = os.environ.get("PADDLE_TPU_COMPILE_CACHE", "")
+
+def resolve_cache_dir(path=None):
+    """The cache directory under the module's ONE rule (see the module
+    doc), or None when disabled. `path` is a caller's explicit choice;
+    like PADDLE_TPU_COMPILE_CACHE it yields to JAX_COMPILATION_CACHE_DIR
+    (only an explicit "off" beats the variable). Touches neither jax
+    nor the filesystem."""
     if path is None:
-        path = env or None
-    if path is None:
-        # respect a dir configured directly on jax before framework import
-        try:
-            existing = jax.config.jax_compilation_cache_dir
-        except AttributeError:
-            existing = None
-        if existing:
-            _state["dir"] = existing
-            return existing
-        path = DEFAULT_CACHE_DIR
-    if str(path).strip().lower() in _OFF_VALUES:
-        _state["dir"] = None
+        path = os.environ.get("PADDLE_TPU_COMPILE_CACHE") or None
+    if path is not None and _is_off(path):
         return None
-    path = os.path.abspath(os.path.expanduser(str(path)))
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or path \
+        or DEFAULT_CACHE_DIR
+    return os.path.abspath(os.path.expanduser(str(path)))
+
+
+def enable_compile_cache(path=None):
+    """Turn JAX's persistent compilation cache on at
+    `resolve_cache_dir(path)`. Idempotent; safe to call before or after
+    backend init (the config is consulted at compile time). Returns the
+    active directory, or None when disabled or when the directory
+    cannot be created (warned once, naming the path)."""
+    import jax
+    path = resolve_cache_dir(path)
+    if path is None:
+        disable_compile_cache()
+        return None
     try:
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(os.environ.get("PADDLE_TPU_CACHE_MIN_COMPILE_SECS", "0")))
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes",
-            int(os.environ.get("PADDLE_TPU_CACHE_MIN_ENTRY_BYTES", "0")))
-        _make_keys_portable()
-    except Exception:
-        _state["dir"] = None
+    except OSError as e:
+        warnings.warn(
+            f"compile cache directory {path!r} cannot be created ({e}); "
+            "running with cold compiles", RuntimeWarning, stacklevel=2)
+        disable_compile_cache()
         return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # the ONE writer of the directory; where the variable is set,
+        # jax has read it itself and nothing here overrides it
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(os.environ.get("PADDLE_TPU_CACHE_MIN_COMPILE_SECS", "0")))
+    jax.config.update(
+        "jax_persistent_cache_min_entry_size_bytes",
+        int(os.environ.get("PADDLE_TPU_CACHE_MIN_ENTRY_BYTES", "0")))
+    # Make cache keys independent of the cache DIRECTORY PATH, so a
+    # packed artifact seeds any machine and a checkout that moves still
+    # hits. jax plants GPU-oriented sub-caches
+    # (xla_gpu_per_fusion_autotune_cache_dir, ...) INSIDE the cache dir
+    # and leaves those debug options in the key, so the key hashes the
+    # absolute path (re-checked on jax 0.9.0, PR 21: the same program
+    # under two directories still gets two keys by default and one key
+    # with "none"). The sub-caches do nothing on TPU/CPU, so default
+    # them OFF; PADDLE_TPU_CACHE_XLA_CACHES overrides (jax's values:
+    # "all", "none", or a comma list of the flag names).
+    jax.config.update(
+        "jax_persistent_cache_enable_xla_caches",
+        os.environ.get("PADDLE_TPU_CACHE_XLA_CACHES", "none"))
     _state["dir"] = path
     return path
 
 
-def _make_keys_portable():
-    """Make cache keys independent of the cache DIRECTORY PATH, so a
-    packed artifact seeds any machine. jax >= 0.4.36 plants
-    GPU-oriented sub-caches (xla_gpu_kernel_cache_file,
-    xla_gpu_per_fusion_autotune_cache_dir) INSIDE the compilation cache
-    dir and — in this jaxlib — fails to strip those debug options from
-    the cache key, so the key hashes the absolute cache path: the same
-    program compiled under ~/.cache and under ./xla_cache gets two
-    different keys, and a seeded directory can never hit (measured on
-    this container: a byte-identical seeded cache recompiled from
-    cold). Those sub-caches do nothing on TPU/CPU, so default them OFF;
-    PADDLE_TPU_CACHE_XLA_CACHES overrides (jax's values: "all", "none",
-    or a comma list of the flag names)."""
-    try:
-        jax.config.update(
-            "jax_persistent_cache_enable_xla_caches",
-            os.environ.get("PADDLE_TPU_CACHE_XLA_CACHES", "none"))
-    except Exception:
-        pass  # older jax: no sub-caches, keys already portable
-
-
 def disable_compile_cache():
-    """Turn the persistent cache off for this process."""
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:
-        pass
+    """Turn the persistent cache off for this process (the switch, not
+    the directory: `jax_compilation_cache_dir` is left as it is)."""
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
     _state["dir"] = None
 
 
@@ -312,6 +323,7 @@ def pack(dest, source=None):
         shutil.copy2(p, os.path.join(dest, n))
         names.append(n)
         total += os.path.getsize(p)
+    import jax
     manifest = {"schema": PACK_SCHEMA, "entries": names,
                 "total_bytes": total, "jax": jax.__version__,
                 "packed_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ",
@@ -326,12 +338,10 @@ def copy_seed_entries(source, dest):
     """The pure-file half of seeding (no jax/framework state): copy the
     cache entries of `source` (a pack() artifact or a raw cache dir)
     into `dest`, skipping entries already present. Returns
-    (seeded, skipped). NOTE: bench.py's PARENT process deliberately
-    re-implements this loop (bench._seed_cache) instead of importing it
-    — this module imports jax at module top, and the parent stays
-    jax-free by contract; keep the two skip-lists (_NON_ENTRY_FILES
-    here, the inline tuple there) in sync when adding non-entry
-    files."""
+    (seeded, skipped). NOTE: bench.py's PARENT process re-implements
+    this loop (bench._seed_cache); keep the two skip-lists
+    (_NON_ENTRY_FILES here, the inline tuple there) in sync when adding
+    non-entry files."""
     os.makedirs(dest, exist_ok=True)
     seeded = skipped = 0
     for n in sorted(os.listdir(source)):

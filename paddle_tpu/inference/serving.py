@@ -2442,6 +2442,14 @@ class GenerationEngine(_SchedulerLifecycle):
         return {"load_report": self.load_report(),
                 "pool_stats": self.cache.pool_stats()}
 
+    def compiled_texts(self):
+        """{signature: optimized HLO} of every ragged-step executable
+        compiled so far for this engine's model — the serving twin of
+        TrainStep.compiled_text (inspection/tests: chip_smoke.py reads
+        them for the kernels' tpu_custom_calls)."""
+        return {sig: entry[0].as_text() for sig, entry in
+                getattr(self.model, "_ragged_exec", {}).items()}
+
     def warm(self, prompt_len, max_new_tokens=None):
         """Blocking warm_async: AOT-compile every ragged signature one
         request of `prompt_len` touches. Returns the count compiled

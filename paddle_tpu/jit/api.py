@@ -1082,7 +1082,17 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                         "(oom@train.step fault): failed to allocate "
                         "request for 8.00GiB on device")
                 out = compiled(*args)
-            except (FloatingPointError, RuntimeError) as e:
+            except Exception as e:
+                # under jax_debug_nans an AOT executable raises jax's
+                # INTERNAL nan error (jax 0.9.0: not a
+                # FloatingPointError, not exported) — known by its name,
+                # so that `import paddle_tpu.jit` depends on no private
+                # jax path
+                internal_nan = \
+                    type(e).__name__ == "InternalFloatingPointError"
+                if not (internal_nan or isinstance(
+                        e, (FloatingPointError, RuntimeError))):
+                    raise
                 if _mobs.is_oom(e):
                     # allocator exhaustion: dump mem_state.json forensics
                     # and re-raise naming the top holders
@@ -1105,6 +1115,14 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                                      step=int(self._step_i),
                                      error=str(e)[:300])
                 _flight.dump("nan", exc=e)
+                if internal_nan:
+                    # jit's own call path would turn it into a
+                    # FloatingPointError after an op-by-op re-run; a
+                    # compiled executable has no such path — report the
+                    # FloatingPointError it is
+                    raise FloatingPointError(
+                        "jax_debug_nans detected a non-finite value in "
+                        f"the compiled {span} program: {e}") from e
                 if donated_rerun:
                     raise FloatingPointError(
                         "jax_debug_nans detected a non-finite value in "
@@ -1180,8 +1198,7 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
         The TPU-native analogue of the reference executor running many
         iterations per `Executor.run` call (ref python/paddle/fluid/
         executor.py): the whole loop lives on device, so per-step host
-        dispatch (and, under a remote/tunneled TPU, per-step round-trip
-        latency) disappears. Best for small/host-bound models. For models
+        dispatch disappears. Best for small/host-bound models. For models
         whose params+optimizer state dominate HBM, per-step `__call__`
         with buffer donation can be faster: XLA double-buffers a while-
         loop carry, where donated per-dispatch buffers update in place

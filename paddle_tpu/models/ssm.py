@@ -482,11 +482,10 @@ class SSMForCausalLM(nn.Layer):
     def _attn_block_geometry(self, cache, n_tokens, n_rows, width):
         """(QB, S) of the hybrid attention layers' q-block plan — same
         contract as gpt._ragged_block_geometry."""
-        from ..ops.pallas.attention_core import MXU_ROWS, choose_q_block
+        from ..ops.pallas.attention_core import choose_ragged_q_block
         paged = cache.paged
         fold = max(self.cfg.num_heads // paged.n_heads, 1)
-        q_block = choose_q_block(int(n_tokens),
-                                 cap=max(MXU_ROWS // fold, 1))
+        q_block = choose_ragged_q_block(int(n_tokens), fold)
         return int(n_tokens) // q_block, int(n_rows) * int(width)
 
     def ragged_arg_specs(self, cache, n_tokens, n_rows, width):

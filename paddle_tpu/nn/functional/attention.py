@@ -3,8 +3,9 @@
 Parity: python/paddle/nn/functional/sparse_attention.py + the attention
 core of python/paddle/nn/layer/transformer.py. On TPU the hot path is the
 Pallas flash-attention kernel (paddle_tpu/ops/pallas/flash_attention.py);
-this module exposes the framework-level API and falls back to the XLA
-softmax(QK^T)V composition when the kernel is unavailable (CPU tests).
+this module exposes the framework-level API and uses the XLA
+softmax(QK^T)V composition off the TPU backend (CPU tests), under
+dropout or with an explicit mask.
 """
 import math
 

@@ -815,15 +815,14 @@ class PagedKVCache:
         out_idx += [0] * n_row_pad
         bounds = np.asarray(bounds, np.int32)
         tok_seq = np.asarray(tok_seq, np.int32)
-        # q-block plan for the blocked kernel — the same choose_q_block
-        # the kernel wrapper would apply, computed here so the serving
-        # step ships a ready-made plan (no in-trace derivation, no
-        # device round-trips in the scheduler)
-        from .pallas.attention_core import MXU_ROWS, choose_q_block
+        # q-block plan for the blocked kernel — the same
+        # choose_ragged_q_block the kernel wrapper would apply,
+        # computed here so the serving step ships a ready-made plan (no
+        # in-trace derivation, no device round-trips in the scheduler)
+        from .pallas.attention_core import choose_ragged_q_block
         from .pallas.paged_attention import build_block_plan
         fold = max(int(q_heads or self.n_heads) // self.n_heads, 1)
-        q_block = choose_q_block(len(bounds),
-                                 cap=max(MXU_ROWS // fold, 1))
+        q_block = choose_ragged_q_block(len(bounds), fold)
         blk_pages, blk_seq, blk_start, blk_n = build_block_plan(
             pt, tok_seq, bounds, P, q_block)
         return {

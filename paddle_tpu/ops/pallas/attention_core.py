@@ -52,6 +52,24 @@ def choose_q_block(n_tokens, cap=MXU_ROWS):
     return bq
 
 
+def choose_ragged_q_block(n_tokens, fold=1):
+    """Tokens per q-block of the ragged serving kernel for a model that
+    folds `fold` query heads onto each kv head. The kernel's tiles are
+    [M, D] with M = block * fold, so the block is choose_q_block under
+    cap MXU_ROWS // fold, then DOUBLED until M is a multiple of the
+    sublane tile (or the block is the whole token axis, which the
+    chip's tiling accepts as a full dimension) — a head count that is
+    not a power of two (20 query heads on one kv head) still yields a
+    tileable M. The host planner and the kernel wrapper both call this,
+    which is the whole shape contract between them."""
+    n = max(int(n_tokens), 1)
+    fold = max(int(fold), 1)
+    bq = choose_q_block(n, cap=max(MXU_ROWS // fold, 1))
+    while bq < n and (bq * fold) % MIN_DOT_ROWS and n % (2 * bq) == 0:
+        bq *= 2
+    return bq
+
+
 def choose_flash_blocks(t_q, t_k, d):
     """(block_q, block_k) for the training kernel. Biggest blocks win
     decisively on real TPU (measured on [128, 1024, 64] bf16: 1024x1024

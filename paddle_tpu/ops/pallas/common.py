@@ -7,8 +7,11 @@ pre-typed constants (and wrap every float closure scalar in jnp.float32).
 """
 import numpy as np
 
-# i32 index-map constant (x64 mode would make a literal 0 trace as i64)
+# i32 index constants (x64 mode would make a literal 0 trace as i64,
+# which Mosaic refuses in an index map or a DMA slice)
 I0 = np.int32(0)
+I1 = np.int32(1)
+I2 = np.int32(2)
 
 # additive mask value; finite so exp() underflows cleanly instead of NaN
 NEG_INF = -1e30

@@ -190,6 +190,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, interpret):
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse
@@ -223,6 +224,7 @@ def _flash_bwd(causal, scale, interpret, res, dout):
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, I0)),
         out_shape=jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        name="flash_attention_dq",
         interpret=interpret,
     )(q, k, v, dout, lse, delta)
 
@@ -248,6 +250,7 @@ def _flash_bwd(causal, scale, interpret, res, dout):
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
+        name="flash_attention_dkv",
         interpret=interpret,
     )(q, k, v, dout, lse, delta)
     return dq, dk, dv
@@ -266,11 +269,37 @@ def flash_attention_arrays(q, k, v, causal=False, scale=None,
     return jnp.swapaxes(out.reshape(B, H, Tq, D), 1, 2)
 
 
-def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
-    """Tensor-level entry used by F.scaled_dot_product_attention."""
+def _per_shard(fn, mesh, batch_axis, head_axis, q_shape):
+    """`fn` per shard of `mesh`: batch over `batch_axis`, heads over
+    `head_axis` (each where it divides), everything else replicated. A
+    Mosaic kernel cannot be partitioned automatically — inside an
+    auto-partitioned SPMD program the chip's lowering refuses the bare
+    call ("wrap the call in a shard_map") — and attention is independent
+    per (batch, head), so the per-shard call IS the partitioning."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    B, _, H, _ = q_shape
+    part = lambda axis, n: axis if n % mesh.shape[axis] == 0 else None
+    spec = P(part(batch_axis, B), None, part(head_axis, H), None)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)
+
+
+def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
+                    partition=None):
+    """Tensor-level entry used by F.scaled_dot_product_attention.
+    `partition` = (mesh, batch_axis, head_axis), handed down by the
+    builder of an auto-partitioned SPMD program
+    (ops.kernels_partitioned_over), runs the kernel per shard of that
+    mesh; None calls it bare."""
     from ...framework.core import apply_op
     interpret = core.default_interpret(interpret)
-    return apply_op(
-        lambda qa, ka, va: flash_attention_arrays(
-            qa, ka, va, causal=causal, scale=scale, interpret=interpret),
-        q, k, v)
+
+    def fn(qa, ka, va):
+        call = lambda a, b, c: flash_attention_arrays(
+            a, b, c, causal=causal, scale=scale, interpret=interpret)
+        if partition is not None:
+            call = _per_shard(call, *partition, qa.shape)
+        return call(qa, ka, va)
+
+    return apply_op(fn, q, k, v)

@@ -31,23 +31,26 @@ passes over contiguous buffers (the *Neptune* pattern, arxiv 2510.08726):
   norm) accumulated on the side.
 
 Per-leaf metadata — lr scale, decay-applies, need-clip, and the norm
-weight hybrid sharding uses to de-duplicate replicated leaves — rides as
-scalar-prefetched arrays (`pltpu.PrefetchScalarGridSpec`): the kernel
-looks its leaf up through the chunk->leaf offset table, so chunks never
-carry per-element metadata. Stores are exact-sized (padding to the
-kernel chunk grid exists only transiently at the Pallas call boundary),
-and a run-bucket's metadata is uniform by construction, so the off-TPU
-path resolves it to python-static decisions per bucket.
+weight hybrid sharding uses to de-duplicate replicated leaves — is
+uniform per bucket BY CONSTRUCTION (a bucket is one (dtype, scan-group
+run, metadata class)), so both execution paths resolve it to
+python-static decisions per bucket (`BucketLayout.bucket_meta`): no
+per-chunk table rides to the kernel. Stores are exact-sized (padding to
+whole 128-lane rows exists only transiently at the Pallas call
+boundary, and only for a bucket whose size is not a multiple of 128).
 
 Execution modes (`FusedEpilogue`): on TPU the passes run as real Pallas
-kernels (per-chunk grid, buffers aliased in place via
-input_output_aliases to compose with the step's donation). Off-TPU the
-SAME `_math` bodies run directly on the whole flat buffers — XLA:CPU
-then fuses them like any elementwise graph, so tier-1 proves the
-identical update math, and `PADDLE_TPU_FUSED_INTERPRET=1` (or
-interpret=True) additionally routes CPU through Pallas interpret mode
-so the kernel plumbing itself — grid, BlockSpecs, scalar prefetch,
-offset-table lookups — is exercised by tests too.
+kernels over the bucket viewed as [rows, 128] — `_BLOCK_ROWS` rows per
+grid step (a bucket smaller than that is one full block; a tail block
+is masked out of the reductions), buffers aliased in place via
+input_output_aliases to compose with the step's donation. Off-TPU the
+SAME per-bucket math (`_pass1_block`/`_pass2_block`) runs directly on
+the whole flat buffers — XLA:CPU then fuses it like any elementwise
+graph, so tier-1 proves the identical update math, and
+`PADDLE_TPU_FUSED_INTERPRET=1` (or interpret=True) additionally routes
+CPU through Pallas interpret mode with the SAME blocking the chip gets,
+so the kernel plumbing itself — grid, BlockSpecs, tail mask, SMEM
+accumulators — is exercised by tests too.
 """
 import functools
 import os
@@ -60,20 +63,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .common import I0
 
-__all__ = ["BucketLayout", "FusedEpilogue", "default_chunk"]
+__all__ = ["BucketLayout", "FusedEpilogue"]
 
-# per-leaf metadata bit flags (leaf_flags i32 scalar-prefetch array)
+# per-leaf metadata bit flags (BucketLayout.leaf_flags)
 FLAG_NEED_CLIP = 1
 FLAG_DECAY = 2
 
 _F32 = jnp.float32
-
-
-def default_chunk():
-    """Elements per kernel chunk (leaf alignment + TPU block width).
-    One lane row (128) keeps per-leaf padding negligible even for toy
-    models; real models see ~0 relative padding at any setting."""
-    return int(os.environ.get("PADDLE_TPU_FUSED_CHUNK", "128"))
+# the kernels view a bucket as [rows, 128]: one lane row per 128
+# elements, _BLOCK_ROWS rows per grid step. 1024 rows keeps pass 2's
+# nine double-buffered windows (g, p bf16; two moments and the master
+# f32, in and out) near 8 MB of VMEM and GPT-medium's largest bucket
+# (75.5M elements) at 576 grid steps.
+_LANES = 128
+_BLOCK_ROWS = 1024
 
 
 def _scan_group_order(named_leaves):
@@ -121,33 +124,28 @@ class _Bucket:
     """One (dtype, scan-group run) flat buffer: the run's members (same
     role across the layer stack, same metadata) pack back-to-back in
     layer order so a stacked view is one contiguous — free — reshape.
-    Exact-sized; the Pallas drivers pad to the chunk grid transiently."""
-    __slots__ = ("dtype", "leaves", "chunk", "n_chunks", "total",
-                 "chunk_leaf", "cursor", "last_group")
+    Exact-sized; the Pallas drivers pad to whole lane rows
+    transiently."""
+    __slots__ = ("dtype", "leaves", "total", "cursor")
 
-    def __init__(self, dtype, chunk):
+    def __init__(self, dtype):
         self.dtype = dtype
         self.leaves = []
-        self.chunk = chunk
-        self.n_chunks = 0
         self.total = 0
         self.cursor = 0
-        self.last_group = None      # (group_id, meta) of previous leaf
-        self.chunk_leaf = None      # np.int32 [n_chunks] -> leaf.index
 
 
 class BucketLayout:
     """Static description of the dtype-bucketed flat layout for one
-    parameter tree, plus the per-leaf metadata tables the kernels
-    prefetch. Built once at TrainStep construction; everything here is
-    host-side numpy, nothing traced."""
+    parameter tree, plus the per-leaf metadata tables. Built once at
+    TrainStep construction; everything here is host-side numpy, nothing
+    traced."""
 
-    def __init__(self, named_leaves, chunk=None, meta=None):
+    def __init__(self, named_leaves, meta=None):
         """named_leaves: ordered [(name, shape, dtype)]. meta: optional
         {name: {"need_clip": bool, "decay": bool, "lr_scale": float,
         "norm_weight": float}} — missing names/keys default to
         (True, True, 1.0, 1.0), which reproduces the tree path."""
-        self.chunk = int(chunk or default_chunk())
         meta = meta or {}
         # ONE bucket per (dtype, scan-group run, metadata class): the
         # run's members (same role across the layer stack) pack densely
@@ -173,7 +171,7 @@ class BucketLayout:
                 float(m.get("norm_weight", 1.0)))
             if prev != (gid, mtup, str(dt)):
                 key = f"{dt}#{len(self.buckets)}"
-                b = self.buckets[key] = _Bucket(dt, self.chunk)
+                b = self.buckets[key] = _Bucket(dt)
             prev = (gid, mtup, str(dt))
             leaf = _Leaf(name, shape, size, b.cursor, len(flags))
             b.cursor += size
@@ -188,16 +186,9 @@ class BucketLayout:
         self.leaf_norm_weight = np.asarray(norm_w, np.float32)
         for b in self.buckets.values():
             # stores are EXACT-sized (padding would ride every store
-            # traversal); the Pallas drivers pad to the chunk grid
+            # traversal); the Pallas drivers pad to whole lane rows
             # transiently at the kernel boundary
             b.total = b.cursor
-            b.n_chunks = -(-b.total // self.chunk)
-            cl = np.zeros((b.n_chunks,), np.int32)
-            for leaf in b.leaves:
-                c0 = leaf.start // self.chunk
-                c1 = (leaf.start + max(leaf.size, 1) - 1) // self.chunk
-                cl[c0:c1 + 1] = leaf.index
-            b.chunk_leaf = cl
         self.n_leaves = len(flags)
         # unpack with a custom VJP: the cotangent of the flat buffer is
         # ONE concatenate of leaf cotangents per bucket, not the pad+add
@@ -207,20 +198,16 @@ class BucketLayout:
             lambda store: (self._unpack_impl(store), None),
             lambda _, cts: (self.pack(cts),))
 
-    def segments(self, key):
-        """Maximal runs of one bucket with UNIFORM per-leaf metadata:
-        [(start, end, flags, lr_scale, norm_weight)] in elements. The
-        direct (off-TPU) path executes one pure-1-D sweep per segment
-        with the metadata folded in as python-static decisions — with
-        default metadata that is exactly ONE whole-bucket sweep, which
-        XLA:CPU schedules copy-free even under donation (a reshape or
-        per-row metadata array in the fused expression would defeat its
-        in-place analysis)."""
-        b = self.buckets[key]
-        li = b.leaves[0].index  # metadata is uniform per run-bucket
-        return [(0, b.total, int(self.leaf_flags[li]),
-                 float(self.leaf_lr_scale[li]),
-                 float(self.leaf_norm_weight[li]))]
+    def bucket_meta(self, key):
+        """(flags, lr_scale, norm_weight) of one bucket — uniform over
+        its leaves by construction, so both the kernels and the direct
+        path fold it in as python-static decisions (exactly how the
+        tree path decides per leaf): no per-row metadata array in the
+        fused expression, which XLA:CPU's in-place analysis under
+        donation and the chip's SMEM budget both want."""
+        li = self.buckets[key].leaves[0].index
+        return (int(self.leaf_flags[li]), float(self.leaf_lr_scale[li]),
+                float(self.leaf_norm_weight[li]))
 
     # -- pack / unpack ---------------------------------------------------
     # Buckets are stored 1-D [total]. This is load-bearing for honest
@@ -228,14 +215,14 @@ class BucketLayout:
     # every unpack slice start with a flattening bitcast, and XLA's
     # HloCostAnalysis cannot see slice utilization through that bitcast
     # — every consumer fusion of a 512-byte bias would be charged the
-    # whole megabuffer. The kernels reshape to [n_chunks, chunk] at
-    # their call boundary, where the whole buffer is genuinely read.
+    # whole megabuffer. The kernels reshape to [rows, 128] at their
+    # call boundary, where the whole buffer is genuinely read.
     def bucket_shape(self, key):
         b = self.buckets[key]
         return (b.total,)
 
     def pack(self, tree, dtype_map=None, keys=None):
-        """Tree {name: array} -> {bucket_key: [n_chunks, chunk]}.
+        """Tree {name: array} -> {bucket_key: [total]}.
         dtype_map optionally overrides the storage dtype per bucket key
         (moment/master buffers share the param layout at another
         dtype); keys restricts packing to a subset of buckets (master
@@ -285,32 +272,36 @@ class BucketLayout:
 
 
 # ---------------------------------------------------------------------------
-# the shared per-block math — ONE definition executed by both the Pallas
-# kernels (TPU / interpret) and the direct off-TPU path
+# the shared per-bucket math — ONE definition executed by both the Pallas
+# kernels (TPU / interpret, on [rows, 128] blocks) and the direct off-TPU
+# path (on the whole 1-D bucket); metadata is python-static per bucket
 # ---------------------------------------------------------------------------
 
-def _pass1_math(g, inv, flags, nw, write_u):
-    """Unscale + weighted partial L2 + non-finite sweep of one [R, C]
-    block. Returns (u or None, partial_sumsq, nonfinite_flag)."""
+def _pass1_block(g, inv, write_u):
+    """Unscale + L2 sum + non-finite sweep of one block (any shape).
+    Returns (u or None, sumsq, nonfinite_flag) — the caller applies the
+    bucket's static norm weight."""
     g32 = g.astype(_F32)
     # found_inf sweeps the RAW grads (pre-unscale), exactly like the
     # tree path's GradScaler.jit_unscale_and_update
-    nonfin = jnp.any(~jnp.isfinite(g32)).astype(_F32)
+    nonfin = jnp.max(jnp.where(jnp.isfinite(g32), jnp.float32(0.0),
+                               jnp.float32(1.0)))
     if write_u:
         u = (g32 * inv).astype(g.dtype)
         u32 = u.astype(_F32)
     else:
         u, u32 = None, g32
-    clip_on = ((flags & FLAG_NEED_CLIP) > 0).astype(_F32)
-    w = (nw * clip_on)[:, 0]
-    ss = jnp.sum(w * jnp.sum(u32 * u32, axis=1))
-    return u, ss, nonfin
+    return u, jnp.sum(u32 * u32), nonfin
+
+
+def _norm_weight(flags, nw):
+    """A bucket's static weight in the global grad norm: its
+    replication weight, or 0 when its leaves are outside the clip."""
+    return nw if (flags & FLAG_NEED_CLIP) else 0.0
 
 
 def _update_core(kind, hp, w, g32, ms32, lr, lr_t):
-    """The optimizer recurrence itself, shared by the Pallas kernels
-    (vector metadata, [R, C] blocks) and the direct 1-D segment path.
-    Returns (np32, new_moments32)."""
+    """The optimizer recurrence itself. Returns (np32, new_moments32)."""
     if kind in ("adam", "adamw"):
         # (1 - beta) precomputed in f64 then rounded, exactly like the
         # tree path's weak-typed python-float literals — bit parity
@@ -331,40 +322,23 @@ def _update_core(kind, hp, w, g32, ms32, lr, lr_t):
     return w - lr * g32, []  # sgd
 
 
-def _pass1_direct(layout, key, g, inv, write_u):
-    """Pass 1 as pure 1-D sweeps: unscale + non-finite over the whole
-    bucket, the weighted L2 per metadata segment (python-static
-    weights). No reshapes, no per-row metadata arrays — XLA:CPU keeps
-    the whole thing in-place-analyzable and fusible."""
-    g32 = g.astype(_F32)
-    nonfin = jnp.any(~jnp.isfinite(g32)).astype(_F32)
-    if write_u:
-        u = (g32 * inv).astype(g.dtype)
-        u32 = u.astype(_F32)
-    else:
-        u, u32 = None, g32
-    segs = layout.segments(key)
-    ss = jnp.zeros((), _F32)
-    for start, end, flags, _lrsc, nw in segs:
-        w = nw if (flags & FLAG_NEED_CLIP) else 0.0
-        if not w:
-            continue
-        part = u32 if len(segs) == 1 else jax.lax.slice(u32, (start,),
-                                                        (end,))
-        ss = ss + jnp.float32(w) * jnp.sum(part * part)
-    return u, ss, nonfin
-
-
-def _pass2_segment(g, p, ms, mw, flags, lrsc, nw, sc, *, kind, hp,
-                   global_clip, clip_value, with_stats):
-    """One metadata-uniform 1-D segment of pass 2: the same math as the
-    Pallas kernel, with the per-leaf metadata resolved to python-static
-    decisions (exactly how the tree path decides per leaf)."""
+def _pass2_block(g, p, ms, mw, meta, sc, *, kind, hp, global_clip,
+                 clip_value, with_stats):
+    """Clip + decoupled decay + moment update + master downcast +
+    found_inf skip of one block (any shape) of one bucket, `meta` its
+    static (flags, lr_scale, norm_weight). `sc` = [lr, lr_t, found_inf,
+    clip_factor] (lr_t is the bias-corrected Adam rate, == lr for
+    SGD/Momentum). Returns (new_p, new_moments, new_master,
+    param_sumsq, update_sumsq)."""
+    flags, lrsc, nw = meta
     found = sc[2] > jnp.float32(0.0)
     clip_f = sc[3]
     lr = sc[0] if lrsc == 1.0 else sc[0] * jnp.float32(lrsc)
     lr_t = sc[1] if lrsc == 1.0 else sc[1] * jnp.float32(lrsc)
 
+    # per-leaf need_clip gates BOTH the factor application here and
+    # the norm contribution in pass 1 (same mask, same semantics as
+    # nn.clip.clip_grads_tree with a need_clip mask)
     if global_clip and (flags & FLAG_NEED_CLIP):
         g = (g.astype(_F32) * clip_f).astype(g.dtype)
     if clip_value is not None:
@@ -378,86 +352,6 @@ def _pass2_segment(g, p, ms, mw, flags, lrsc, nw, sc, *, kind, hp,
         w = w * (jnp.float32(1.0) - lr * jnp.float32(wd))
     np32, new_m32 = _update_core(kind, hp, w, g32,
                                  [m.astype(_F32) for m in ms], lr, lr_t)
-    npw = np32.astype(p.dtype)
-    new_p = jnp.where(found, p, npw)
-    new_ms = [jnp.where(found, old, nm.astype(old.dtype))
-              for old, nm in zip(ms, new_m32)]
-    new_mw = jnp.where(found, mw, np32) if mw is not None else None
-    sp = su = None
-    if with_stats:
-        sel32 = new_p.astype(_F32)
-        sp = jnp.float32(nw) * jnp.sum(sel32 * sel32)
-        su = jnp.float32(nw) * jnp.sum((sel32 - p32) * (sel32 - p32))
-    return new_p, new_ms, new_mw, sp, su
-
-
-def _pass2_direct(layout, key, g, p, ms, mw, scalars, *, kind, hp,
-                  global_clip, clip_value, with_stats):
-    """Pass 2 as 1-D metadata segments (one whole-bucket sweep in the
-    default all-uniform case), concatenating per-segment outputs when
-    the metadata actually varies."""
-    segs = layout.segments(key)
-    if len(segs) == 1:
-        _s, _e, flags, lrsc, nw = segs[0]
-        return _pass2_segment(g, p, ms, mw, flags, lrsc, nw, scalars,
-                              kind=kind, hp=hp, global_clip=global_clip,
-                              clip_value=clip_value,
-                              with_stats=with_stats)
-    pieces, sp_t, su_t = [], jnp.zeros((), _F32), jnp.zeros((), _F32)
-    for start, end, flags, lrsc, nw in segs:
-        cut = lambda a: jax.lax.slice(a, (start,), (end,))  # noqa: E731
-        po, mos, mwo, sp, su = _pass2_segment(
-            cut(g), cut(p), [cut(m) for m in ms],
-            cut(mw) if mw is not None else None, flags, lrsc, nw,
-            scalars, kind=kind, hp=hp, global_clip=global_clip,
-            clip_value=clip_value, with_stats=with_stats)
-        pieces.append((po, mos, mwo))
-        if with_stats:
-            sp_t, su_t = sp_t + sp, su_t + su
-    new_p = jnp.concatenate([pc[0] for pc in pieces])
-    new_ms = [jnp.concatenate([pc[1][j] for pc in pieces])
-              for j in range(len(ms))]
-    new_mw = jnp.concatenate([pc[2] for pc in pieces]) \
-        if mw is not None else None
-    return new_p, new_ms, new_mw, \
-        sp_t if with_stats else None, su_t if with_stats else None
-
-
-def _pass2_math(g, p, ms, mw, flags, lrsc, nw, sc, *, kind, hp,
-                global_clip, clip_value, with_stats):
-    """Clip + decoupled decay + moment update + master downcast +
-    found_inf skip of one [R, C] block. `sc` = [lr, lr_t, found_inf,
-    clip_factor] (lr_t is the bias-corrected Adam rate, == lr for
-    SGD/Momentum); flags/lrsc/nw broadcast [R, 1]. Returns (new_p,
-    new_moments, new_master, param_sumsq, update_sumsq)."""
-    lr = sc[0] * lrsc
-    lr_t = sc[1] * lrsc
-    found = sc[2] > jnp.float32(0.0)
-    clip_f = sc[3]
-
-    if global_clip:
-        # per-leaf need_clip gates BOTH the factor application here and
-        # the norm contribution in pass 1 (same mask, same semantics as
-        # nn.clip.clip_grads_tree with a need_clip mask)
-        f = jnp.where((flags & FLAG_NEED_CLIP) > 0, clip_f,
-                      jnp.float32(1.0))
-        g = (g.astype(_F32) * f).astype(g.dtype)
-    if clip_value is not None:
-        g = jnp.clip(g, jnp.asarray(clip_value[0], g.dtype),
-                     jnp.asarray(clip_value[1], g.dtype))
-    g32 = g.astype(_F32)
-    p32 = p.astype(_F32)
-    w = mw if mw is not None else p32
-    wd = hp.get("wd", 0.0)
-    if wd:
-        decay_on = (flags & FLAG_DECAY) > 0
-        w = w * jnp.where(decay_on,
-                          jnp.float32(1.0) - lr * jnp.float32(wd),
-                          jnp.float32(1.0))
-
-    np32, new_m32 = _update_core(kind, hp, w, g32,
-                                 [m.astype(_F32) for m in ms], lr, lr_t)
-
     # downcast tails (master keeps f32; the working param is its
     # rounded shadow), then the branchless found_inf skip
     npw = np32.astype(p.dtype)
@@ -470,9 +364,8 @@ def _pass2_math(g, p, ms, mw, flags, lrsc, nw, sc, *, kind, hp,
         # norm_weight de-duplicates mesh-replicated leaves in the psum'd
         # health sums, exactly like pass 1's grad-norm partials
         sel32 = new_p.astype(_F32)
-        sp = jnp.sum(nw[:, 0] * jnp.sum(sel32 * sel32, axis=1))
-        su = jnp.sum(nw[:, 0] * jnp.sum((sel32 - p32) * (sel32 - p32),
-                                        axis=1))
+        sp = jnp.float32(nw) * jnp.sum(sel32 * sel32)
+        su = jnp.float32(nw) * jnp.sum((sel32 - p32) * (sel32 - p32))
     return new_p, new_ms, new_mw, sp, su
 
 
@@ -480,82 +373,93 @@ def _pass2_math(g, p, ms, mw, flags, lrsc, nw, sc, *, kind, hp,
 # Pallas kernel wrappers over the shared math
 # ---------------------------------------------------------------------------
 
-def _pad_to(x, n):
-    """Tail-pad a 1-D buffer to the Pallas chunk grid (stores are
+def _as_rows(x):
+    """A 1-D bucket as [rows, 128] (tail-padded with zeros to a whole
+    lane row when its size is not a multiple of 128 — stores are
     exact-sized; only the kernel boundary sees the padded view)."""
-    if x.shape[0] == n:
-        return x
-    return jnp.concatenate([x, jnp.zeros((n - x.shape[0],), x.dtype)])
+    n_rows = -(-x.shape[0] // _LANES)
+    pad = n_rows * _LANES - x.shape[0]
+    if pad:
+        x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
+    return x.reshape(n_rows, _LANES)
 
 
-def _row_meta(cl_ref, table_ref, i, rows):
-    """Per-row [rows, 1] view of a per-leaf metadata table through the
-    chunk->leaf offset table. rows == 1 is the TPU layout (one chunk
-    per program, pure scalar SMEM reads); rows == n_chunks is the
-    interpret-mode layout (whole bucket in one block), where the lookup
-    is a tiny vector gather."""
-    if rows == 1:
-        return table_ref[cl_ref[i]].reshape(1, 1)
-    return table_ref[cl_ref[...]].reshape(rows, 1)
+def _block_rows(n_rows):
+    """Rows per grid step: the whole bucket when it is at most one
+    block (a full dimension always tiles), else _BLOCK_ROWS (a multiple
+    of every dtype's sublane tile). The SAME choice in interpret mode
+    and on the chip, so tier-1 runs the blocking that ships."""
+    return n_rows if n_rows <= _BLOCK_ROWS else _BLOCK_ROWS
 
 
-def _pass1_kernel(cl_ref, fl_ref, nw_ref, sc_ref, g_ref, *rest,
-                  write_u, rows):
+def _tail_masked(refs, rows, n_rows):
+    """Load blocks; where the grid's last block hangs over the bucket
+    (n_rows % rows), zero its out-of-range rows — their loads are
+    unspecified and must not reach the reductions (their stores are
+    dropped by the pipeline)."""
+    vals = [r[...] for r in refs]
+    if n_rows % rows == 0:
+        return vals
+    row = pl.program_id(0) * rows + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, _LANES), 0)
+    keep = row < n_rows
+    return [jnp.where(keep, v, jnp.zeros((), v.dtype)) for v in vals]
+
+
+def _smem(shape):
+    """A whole small operand in scalar memory. The index map is spelt
+    out in int32: under x64 the default one returns i64 zeros, which
+    Mosaic cannot legalize."""
+    return pl.BlockSpec(shape, lambda i: (I0,) * len(shape),
+                        memory_space=pltpu.SMEM)
+
+
+_ACC_SHAPE = jax.ShapeDtypeStruct((1, 1), _F32)
+_ACC = _smem((1, 1))
+
+
+def _pass1_kernel(inv_ref, g_ref, *rest, write_u, weight, rows, n_rows):
     if write_u:
         u_ref, ss_ref, fi_ref = rest
     else:
         ss_ref, fi_ref = rest
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         ss_ref[0, 0] = jnp.float32(0.0)
         fi_ref[0, 0] = jnp.float32(0.0)
 
-    flags = _row_meta(cl_ref, fl_ref, i, rows)
-    nw = _row_meta(cl_ref, nw_ref, i, rows)
-    u, ss, nonfin = _pass1_math(g_ref[...], sc_ref[0], flags, nw,
-                                write_u)
+    g, = _tail_masked([g_ref], rows, n_rows)
+    u, ss, nonfin = _pass1_block(g, inv_ref[0], write_u)
     if write_u:
         u_ref[...] = u
     fi_ref[0, 0] = jnp.maximum(fi_ref[0, 0], nonfin)
-    ss_ref[0, 0] += ss
+    if weight:
+        ss_ref[0, 0] += jnp.float32(weight) * ss
 
 
-def _pass2_kernel(cl_ref, fl_ref, lrs_ref, nw_ref, sc_ref, *refs, kind,
-                  n_moments, has_master, with_stats, global_clip,
-                  clip_value, hp, rows):
+def _pass2_kernel(sc_ref, *refs, n_moments, has_master, with_stats,
+                  rows, n_rows, **math_kw):
     n_in = 2 + n_moments + (1 if has_master else 0)
     ins, outs = refs[:n_in], refs[n_in:]
-    g_ref, p_ref = ins[0], ins[1]
-    m_refs = ins[2:2 + n_moments]
-    mw_ref = ins[2 + n_moments] if has_master else None
-    po_ref = outs[0]
-    mo_refs = outs[1:1 + n_moments]
-    mwo_ref = outs[1 + n_moments] if has_master else None
-    sp_ref = outs[-2] if with_stats else None
-    su_ref = outs[-1] if with_stats else None
-
-    i = pl.program_id(0)
     if with_stats:
-        @pl.when(i == 0)
+        sp_ref, su_ref = outs[-2:]
+
+        @pl.when(pl.program_id(0) == 0)
         def _init_stats():
             sp_ref[0, 0] = jnp.float32(0.0)
             su_ref[0, 0] = jnp.float32(0.0)
 
-    new_p, new_ms, new_mw, sp, su = _pass2_math(
-        g_ref[...], p_ref[...], [m[...] for m in m_refs],
-        mw_ref[...] if has_master else None,
-        _row_meta(cl_ref, fl_ref, i, rows),
-        _row_meta(cl_ref, lrs_ref, i, rows),
-        _row_meta(cl_ref, nw_ref, i, rows),
-        sc_ref, kind=kind, hp=hp, global_clip=global_clip,
-        clip_value=clip_value, with_stats=with_stats)
-    po_ref[...] = new_p
-    for mo, nm in zip(mo_refs, new_ms):
+    vals = _tail_masked(ins, rows, n_rows)
+    new_p, new_ms, new_mw, sp, su = _pass2_block(
+        vals[0], vals[1], vals[2:2 + n_moments],
+        vals[2 + n_moments] if has_master else None,
+        sc=sc_ref, with_stats=with_stats, **math_kw)
+    outs[0][...] = new_p
+    for mo, nm in zip(outs[1:1 + n_moments], new_ms):
         mo[...] = nm
     if has_master:
-        mwo_ref[...] = new_mw
+        outs[1 + n_moments][...] = new_mw
     if with_stats:
         sp_ref[0, 0] += sp
         su_ref[0, 0] += su
@@ -568,53 +472,42 @@ def _pass2_kernel(cl_ref, fl_ref, lrs_ref, nw_ref, sc_ref, *refs, kind,
 def _run_pass1(layout, grads, inv_scale, write_u, mode):
     """Per-bucket pass 1. Returns (unscaled store or None, sumsq f32
     scalar, found_inf f32 scalar). sumsq accumulates bucket-major then
-    chunk-major — the multi-tensor analogue of the tree path's
+    block-major — the multi-tensor analogue of the tree path's
     leaf-major sum (equal within reduction-order ulps)."""
-    C = layout.chunk
     sumsq = jnp.zeros((), _F32)
     found = jnp.zeros((), _F32)
     out_u = {} if write_u else None
     inv = jnp.asarray(inv_scale, _F32)
     for key, b in layout.buckets.items():
+        flags, _lrsc, nw = layout.bucket_meta(key)
+        weight = _norm_weight(flags, nw)
         if mode == "direct":
-            u, ss, fi = _pass1_direct(layout, key, grads[key], inv,
-                                      write_u)
-            if write_u:
-                out_u[key] = u
-            sumsq = sumsq + ss
-            found = jnp.maximum(found, fi)
-            continue
-        # Pallas path: buckets live 1-D and exact-sized; the padded
-        # chunk view exists only at the kernel boundary (a full read
-        # through a reshape is charged exactly)
-        g = _pad_to(grads[key], b.n_chunks * C).reshape(b.n_chunks, C)
-        interpret = mode == "interpret"
-        rows = b.n_chunks if interpret else 1
-        acc = pl.BlockSpec((1, 1), lambda i, *pf: (I0, I0))
-        row = pl.BlockSpec((rows, C), lambda i, *pf: (i, I0))
-        out_shape = [jax.ShapeDtypeStruct((1, 1), _F32),
-                     jax.ShapeDtypeStruct((1, 1), _F32)]
-        out_specs = [acc, acc]
-        if write_u:
-            out_shape.insert(0, jax.ShapeDtypeStruct(g.shape, g.dtype))
-            out_specs.insert(0, row)
-        res = pl.pallas_call(
-            functools.partial(_pass1_kernel, write_u=write_u,
-                              rows=rows),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=4,
-                grid=(b.n_chunks // rows,),
-                in_specs=[row],
-                out_specs=out_specs),
-            out_shape=out_shape,
-            interpret=interpret,
-        )(jnp.asarray(b.chunk_leaf), jnp.asarray(layout.leaf_flags),
-          jnp.asarray(layout.leaf_norm_weight), inv.reshape(1), g)
-        if write_u:
-            out_u[key] = res[0].reshape(-1)[:b.total]
-            ss, fi = res[1][0, 0], res[2][0, 0]
+            u, ss, fi = _pass1_block(grads[key], inv, write_u)
+            ss = jnp.float32(weight) * ss if weight else jnp.float32(0.0)
         else:
-            ss, fi = res[0][0, 0], res[1][0, 0]
+            # Pallas path: buckets live 1-D and exact-sized; the
+            # [rows, 128] view exists only at the kernel boundary (a
+            # full read through a reshape is charged exactly)
+            g = _as_rows(grads[key])
+            n_rows = g.shape[0]
+            rows = _block_rows(n_rows)
+            blk = pl.BlockSpec((rows, _LANES), lambda i: (i, I0))
+            res = pl.pallas_call(
+                functools.partial(_pass1_kernel, write_u=write_u,
+                                  weight=weight, rows=rows,
+                                  n_rows=n_rows),
+                grid=(pl.cdiv(n_rows, rows),),
+                in_specs=[_smem((1,)), blk],
+                out_specs=([blk] if write_u else []) + [_ACC, _ACC],
+                out_shape=([jax.ShapeDtypeStruct(g.shape, g.dtype)]
+                           if write_u else []) + [_ACC_SHAPE, _ACC_SHAPE],
+                name="fused_update_pass1",
+                interpret=mode == "interpret",
+            )(inv.reshape(1), g)
+            u = res[0].reshape(-1)[:b.total] if write_u else None
+            ss, fi = res[-2][0, 0], res[-1][0, 0]
+        if write_u:
+            out_u[key] = u
         sumsq = sumsq + ss
         found = jnp.maximum(found, fi)
     return out_u, sumsq, found
@@ -624,84 +517,58 @@ def _run_pass2(layout, spec, grads, params, moments, masters, scalars,
                with_stats, global_clip, clip_value, mode):
     """Per-bucket pass 2. Returns (new_params, new_moments, new_masters,
     stats) — stats is (param_sumsq, update_sumsq) f32 or None."""
-    C = layout.chunk
-    kind = spec["kind"]
     n_moments = spec["n_moments"]
     new_p, new_m, new_mw = {}, [dict() for _ in range(n_moments)], {}
     p_sq = jnp.zeros((), _F32)
     u_sq = jnp.zeros((), _F32)
     for key, b in layout.buckets.items():
         has_master = key in (masters or {})
+        ops = [grads[key], params[key]] + [m[key] for m in moments] \
+            + ([masters[key]] if has_master else [])
+        math_kw = dict(meta=layout.bucket_meta(key), kind=spec["kind"],
+                       hp=spec, global_clip=global_clip,
+                       clip_value=clip_value)
         if mode == "direct":
-            po, mos, mwo, sp, su = _pass2_direct(
-                layout, key, grads[key], params[key],
-                [m[key] for m in moments],
-                masters[key] if has_master else None, scalars,
-                kind=kind, hp=spec, global_clip=global_clip,
-                clip_value=clip_value, with_stats=with_stats)
-            new_p[key] = po
-            for j in range(n_moments):
-                new_m[j][key] = mos[j]
-            if has_master:
-                new_mw[key] = mwo
-            if with_stats:
-                p_sq = p_sq + sp
-                u_sq = u_sq + su
-            continue
-        shp = (b.n_chunks, C)
-        padded = b.n_chunks * C
-        p = _pad_to(params[key], padded).reshape(shp)
-        g = _pad_to(grads[key], padded).reshape(shp)
-        ms_2d = [_pad_to(m[key], padded).reshape(shp) for m in moments]
-        mw = _pad_to(masters[key], padded).reshape(shp) \
-            if has_master else None
-        interpret = mode == "interpret"
-        rows = b.n_chunks if interpret else 1
-        ops = [g, p] + ms_2d + ([mw] if has_master else [])
-        blk = pl.BlockSpec((rows, C), lambda i, *pf: (i, I0))
-        in_specs = [blk] * len(ops)
-        out_shape = [jax.ShapeDtypeStruct(shp, p.dtype)] \
-            + [jax.ShapeDtypeStruct(shp, m.dtype) for m in ms_2d] \
-            + ([jax.ShapeDtypeStruct(shp, _F32)]
-               if has_master else [])
-        out_specs = [blk] * len(out_shape)
-        n_alias = len(out_shape)
-        if with_stats:
-            for _ in range(2):
-                out_shape.append(jax.ShapeDtypeStruct((1, 1), _F32))
-                out_specs.append(pl.BlockSpec(
-                    (1, 1), lambda i, *pf: (I0, I0)))
-        # alias param/moment/master buffers in place: operand index
-        # counts the 5 scalar-prefetch args first; grads (input 5)
-        # are NOT aliased (pass 1 may still own that buffer)
-        aliases = {5 + 1 + j: j for j in range(n_alias)}
-        res = pl.pallas_call(
-            functools.partial(
-                _pass2_kernel, kind=kind, n_moments=n_moments,
-                has_master=has_master, with_stats=with_stats,
-                global_clip=global_clip, clip_value=clip_value,
-                hp=spec, rows=rows),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=5,
-                grid=(b.n_chunks // rows,),
-                in_specs=in_specs,
-                out_specs=out_specs),
-            out_shape=out_shape,
-            input_output_aliases=aliases,
-            interpret=interpret,
-        )(jnp.asarray(b.chunk_leaf), jnp.asarray(layout.leaf_flags),
-          jnp.asarray(layout.leaf_lr_scale),
-          jnp.asarray(layout.leaf_norm_weight), scalars, *ops)
-        po = res[0]
-        mos = [res[1 + j] for j in range(n_moments)]
-        mwo = res[1 + n_moments] if has_master else None
-        sp = res[-2][0, 0] if with_stats else None
-        su = res[-1][0, 0] if with_stats else None
-        new_p[key] = po.reshape(-1)[:b.total]
+            po, mos, mwo, sp, su = _pass2_block(
+                ops[0], ops[1], ops[2:2 + n_moments],
+                ops[2 + n_moments] if has_master else None,
+                sc=scalars, with_stats=with_stats, **math_kw)
+        else:
+            ops = [_as_rows(a) for a in ops]
+            n_rows = ops[0].shape[0]
+            rows = _block_rows(n_rows)
+            blk = pl.BlockSpec((rows, _LANES), lambda i: (i, I0))
+            # outputs mirror inputs 1.. (param, moments, master) and
+            # alias them in place; operand 0 is the scalars and grads
+            # (operand 1) are NOT aliased (pass 1 may still own that
+            # buffer)
+            n_alias = len(ops) - 1
+            res = pl.pallas_call(
+                functools.partial(
+                    _pass2_kernel, n_moments=n_moments,
+                    has_master=has_master, with_stats=with_stats,
+                    rows=rows, n_rows=n_rows, **math_kw),
+                grid=(pl.cdiv(n_rows, rows),),
+                in_specs=[_smem((4,))] + [blk] * len(ops),
+                out_specs=[blk] * n_alias
+                + ([_ACC, _ACC] if with_stats else []),
+                out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype)
+                           for a in ops[1:]]
+                + ([_ACC_SHAPE, _ACC_SHAPE] if with_stats else []),
+                input_output_aliases={2 + j: j for j in range(n_alias)},
+                name="fused_update_pass2",
+                interpret=mode == "interpret",
+            )(scalars, *ops)
+            flat = [r.reshape(-1)[:b.total] for r in res[:n_alias]]
+            po, mos = flat[0], flat[1:1 + n_moments]
+            mwo = flat[1 + n_moments] if has_master else None
+            sp = res[-2][0, 0] if with_stats else None
+            su = res[-1][0, 0] if with_stats else None
+        new_p[key] = po
         for j in range(n_moments):
-            new_m[j][key] = mos[j].reshape(-1)[:b.total]
+            new_m[j][key] = mos[j]
         if has_master:
-            new_mw[key] = mwo.reshape(-1)[:b.total]
+            new_mw[key] = mwo
         if with_stats:
             p_sq = p_sq + sp
             u_sq = u_sq + su

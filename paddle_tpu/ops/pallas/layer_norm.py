@@ -89,6 +89,7 @@ def _ln_fwd_impl(x2d, w, b, eps, interpret):
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
+        name="layer_norm_fwd",
         interpret=interpret,
     )(x2d, w, b)
     return out, mu, rstd
@@ -123,6 +124,7 @@ def _ln_bwd(eps, interpret, res, dout):
             jax.ShapeDtypeStruct((C,), jnp.float32),
             jax.ShapeDtypeStruct((C,), jnp.float32),
         ],
+        name="layer_norm_bwd",
         interpret=interpret,
     )(x2d, w, mu, rstd, dout)
     return dx, dw.astype(w.dtype), db.astype(w.dtype)

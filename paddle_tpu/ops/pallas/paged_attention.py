@@ -28,7 +28,11 @@ the training kernel):
 - the page walk is DOUBLE-BUFFERED DMA (pallas_guide.md pattern): the
   kernel copies page i+1 into the alternate VMEM slot while computing
   page i, so the HBM walk overlaps the MXU work. A q-block of pure pad
-  tokens has a zero slot count and issues NO copies at all.
+  tokens has a zero slot count and issues NO copies at all. One copy
+  brings a whole page — every kv head's [P, D] rows as one contiguous
+  [P, H_kv*D] slab — and one program per q-block serves all kv heads
+  from it (the chip's DMA refuses a single head's slice: it cuts inside
+  the (8, 128) tile of the pool's last two dimensions).
 
 The kernel still emits the per-token WORK counter (kv page blocks
 actually computed = ceil(bound/P), 0 for pads) — the ground truth
@@ -48,7 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import I0
+from .common import I0, I1, I2
 from . import attention_core as core
 
 __all__ = ["ragged_paged_attention", "ragged_work_plan",
@@ -125,81 +129,76 @@ def _block_plan_jnp(page_table, token_seq, bounds, page_size, q_block):
             jnp.sum(active.astype(jnp.int32), axis=1))
 
 
-def _kernel(bp_ref, bs_ref, bst_ref, bn_ref,      # scalar prefetch
-            seq_ref, bd_ref, q_ref,               # blocked VMEM inputs
-            k_hbm, v_hbm,                         # full pools (ANY)
-            o_ref, w_ref,                         # blocked outputs
-            kbuf, vbuf, ksem, vsem,               # DMA double buffers
-            *, page_size, scale, fold):
-    """One (q-block, kv-head) program: walk the block's planned kv
-    pages through the double buffer, online-softmax every page into
-    the folded [Bq*fold, D] accumulator under the per-token bounds."""
+def _kernel(bn_ref,                              # scalar prefetch
+            plan_ref,                            # [3, S] SMEM window
+            seq_ref, bd_ref, q_ref,              # blocked VMEM inputs
+            k_hbm, v_hbm,                        # full pools (HBM)
+            o_ref, w_ref,                        # blocked outputs
+            kbuf, vbuf, sem,                     # DMA double buffers
+            m_scr, l_scr, acc_scr,               # per-head softmax state
+            *, page_size, scale):
+    """One q-block program: walk the block's planned kv pages through
+    the double buffer — each page ONE contiguous [P, H_kv*D] copy that
+    serves every kv head — and online-softmax it into each head's
+    folded [M, D] accumulator under the per-row bounds. Rows are
+    (token, group-head) pairs already folded by the wrapper, so every
+    tile is 2-D and (8, 128)-tileable. Every index that reaches a DMA
+    slice is int32 — the package runs with x64 on, and Mosaic refuses
+    an i64 memref_slice operand."""
     qb = pl.program_id(0)
-    h = pl.program_id(1)
     n = bn_ref[qb]
-    Bq, f, D = q_ref.shape
-    M = Bq * fold
-
-    seq = seq_ref[:, 0]                           # [Bq] row per token
-    bd = bd_ref[:, 0]                             # [Bq] causal bounds
-    if fold == 1:
-        q = q_ref[:, 0, :].astype(jnp.float32)    # [M, D]
-        seq_f, bd_f = seq, bd
-    else:
-        q = q_ref[...].astype(jnp.float32).reshape(M, D)
-        brd = lambda a: jnp.broadcast_to(
-            a[:, None], (Bq, fold)).reshape(M)
-        seq_f, bd_f = brd(seq), brd(bd)
+    KVH, M, D = q_ref.shape
+    seq = seq_ref[...]                            # [M, 1] row per q row
+    bd = bd_ref[...]                              # [M, 1] causal bounds
+    m0, l0, acc0 = core.softmax_carry(M, D)
+    for h in range(KVH):
+        m_scr[h], l_scr[h], acc_scr[h] = m0, l0, acc0
+    w_ref[...] = jnp.zeros((M, 1), jnp.int32)
 
     def copies(i, slot):
-        page = bp_ref[qb, i]
-        return (pltpu.make_async_copy(k_hbm.at[page, :, h],
-                                      kbuf.at[slot], ksem.at[slot]),
-                pltpu.make_async_copy(v_hbm.at[page, :, h],
-                                      vbuf.at[slot], vsem.at[slot]))
-
-    @pl.when(h == 0)
-    def _zero_work():
-        w_ref[:, 0] = jnp.zeros((Bq,), jnp.int32)
+        page = plan_ref[0, i]
+        return (pltpu.make_async_copy(k_hbm.at[page], kbuf.at[slot],
+                                      sem.at[I0, slot]),
+                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[slot],
+                                      sem.at[I1, slot]))
 
     @pl.when(n > 0)
     def _warmup():                                # first page's DMA
-        for c in copies(0, 0):
+        for c in copies(I0, I0):
             c.start()
 
     def body(i, carry):
-        m, l, acc = carry
-        two = jnp.asarray(2, i.dtype)
-        slot = jax.lax.rem(i, two)
+        slot = jax.lax.rem(i, I2)
 
-        @pl.when(i + 1 < n)
+        @pl.when(i + I1 < n)
         def _prefetch():                          # overlap: next page
-            for c in copies(i + 1, jax.lax.rem(i + 1, two)):
+            for c in copies(i + I1, jax.lax.rem(i + I1, I2)):
                 c.start()
 
         for c in copies(i, slot):
             c.wait()
-        b = bs_ref[qb, i]
-        start = bst_ref[qb, i]
-        k = kbuf[slot].astype(jnp.float32)        # [P, D]
-        v = vbuf[slot].astype(jnp.float32)        # [P, D]
-        s = core.score_dot(q, k, scale)           # [M, P] — MXU-shaped
+        b = plan_ref[1, i]
+        start = plan_ref[2, i]
         pos = start + jax.lax.broadcasted_iota(
             jnp.int32, (M, page_size), 1)
-        valid = (seq_f == b)[:, None] & (pos < bd_f[:, None])
-        m, l, acc = core.softmax_update(m, l, acc, s, v, valid=valid)
+        valid = (seq == b) & (pos < bd)           # shared by all heads
+        kp = kbuf[slot].astype(jnp.float32)       # [P, H_kv*D]
+        vp = vbuf[slot].astype(jnp.float32)
+        for h in range(KVH):
+            lanes = slice(h * D, (h + 1) * D)
+            s = core.score_dot(q_ref[h].astype(jnp.float32),
+                               kp[:, lanes], scale)   # [M, P]
+            m_scr[h], l_scr[h], acc_scr[h] = core.softmax_update(
+                m_scr[h], l_scr[h], acc_scr[h], s, vp[:, lanes],
+                valid=valid)
+        # measured work, not an estimate
+        w_ref[...] += ((seq == b) & (start < bd)).astype(jnp.int32)
+        return carry
 
-        @pl.when(h == 0)
-        def _count():                             # measured work, not
-            w_ref[:, 0] += (                      # an estimate
-                (seq == b) & (start < bd)).astype(jnp.int32)
-
-        return m, l, acc
-
-    m, l, acc = jax.lax.fori_loop(
-        0, n, body, core.softmax_carry(M, D))
-    out, _ = core.softmax_finalize(m, l, acc)
-    o_ref[...] = out.reshape(Bq, fold, D).astype(o_ref.dtype)
+    jax.lax.fori_loop(I0, n, body, I0)
+    for h in range(KVH):
+        out, _ = core.softmax_finalize(m_scr[h], l_scr[h], acc_scr[h])
+        o_ref[h] = out.astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, token_seq,
@@ -222,8 +221,18 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, token_seq,
                 build_block_plan — the serving path precomputes it on
                 the host (PagedKVCache.plan_ragged); omitted, the same
                 plan is derived in-trace.
-    q_block:    rows per q-block; default attention_core.choose_q_block
-                (<= MXU_ROWS/fold, halved to divide T).
+    q_block:    rows per q-block; default
+                attention_core.choose_ragged_q_block (M = q_block*fold
+                <= MXU_ROWS and a multiple of the sublane tile).
+
+    Layout the chip's tiling accepts for every head grouping: the
+    wrapper folds q to [H_kv, T*fold, D] (token-major, group-head
+    minor), so the kernel's q/out tile is the 2-D [M, D] slab of one kv
+    head and the per-row sequence/bound/work columns are [M, 1] — M is
+    a multiple of 8 (or the whole axis) whatever `fold` is. The three
+    [QB, S] plan tables ride one [QB, 3, S] SMEM operand WINDOWED per
+    q-block (only blk_n is scalar-prefetched), so SMEM holds one
+    q-block's slots, not the whole plan.
 
     Returns [T, H, D] (and, with return_work, the per-token count of
     kv page blocks actually computed — ceil(bound/P), 0 for pads)."""
@@ -235,60 +244,67 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, token_seq,
     B, W = page_table.shape
     scale = core.default_scale(scale, D)
     interpret = core.default_interpret(interpret)
-    bq = int(q_block) if q_block else core.choose_q_block(
-        T, cap=max(core.MXU_ROWS // fold, 1))
+    bq = int(q_block) if q_block else core.choose_ragged_q_block(T, fold)
     if T % bq:
         raise ValueError(f"tokens {T} not divisible by q_block {bq}")
     QB = T // bq
+    M = bq * fold
     if block_plan is None:
         block_plan = _block_plan_jnp(page_table, token_seq, bounds,
                                      P, bq)
     bp, bs, bst, bn = (jnp.asarray(a, jnp.int32) for a in block_plan)
-    if bp.shape != (QB, B * W) or bn.shape != (QB,):
+    S = B * W
+    if bp.shape != (QB, S) or bn.shape != (QB,):
         raise ValueError(
             f"block plan shape {bp.shape}/{bn.shape} does not match "
             f"q_block={bq} over T={T}, B={B}, W={W}")
+    fold_col = lambda a: jnp.repeat(
+        a.astype(jnp.int32).reshape(T), fold).reshape(T * fold, 1)
+    qf = q.reshape(T, KVH, fold, D).transpose(1, 0, 2, 3).reshape(
+        KVH, T * fold, D)
+    col = pl.BlockSpec((M, 1), lambda qb, *_: (qb, I0))
+    slab = pl.BlockSpec((KVH, M, D), lambda qb, *_: (I0, qb, I0))
+    # a page is one contiguous [P, H_kv*D] slab of the pool (a free
+    # reshape): slicing a single kv head out of HBM would cut inside
+    # the (8, 128) tile, which the chip's DMA refuses
+    pool = lambda a: a.reshape(n_pages, P, KVH * D)
     out, work = pl.pallas_call(
-        functools.partial(_kernel, page_size=P, scale=scale, fold=fold),
+        functools.partial(_kernel, page_size=P, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(QB, KVH),
+            num_scalar_prefetch=1,
+            grid=(QB,),
             in_specs=[
-                pl.BlockSpec((bq, 1), lambda qb, h, *_: (qb, I0)),
-                pl.BlockSpec((bq, 1), lambda qb, h, *_: (qb, I0)),
-                pl.BlockSpec((bq, fold, D),
-                             lambda qb, h, *_: (qb, h, I0)),
+                pl.BlockSpec((None, 3, S), lambda qb, *_: (qb, I0, I0),
+                             memory_space=pltpu.SMEM),
+                col, col, slab,
                 # the pools stay in HBM; the kernel's double-buffered
                 # DMA walks exactly the planned pages
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=[
-                pl.BlockSpec((bq, fold, D),
-                             lambda qb, h, *_: (qb, h, I0)),
-                # work lives in a [T, 1] column: trailing (Bq, 1)
-                # blocks keep the revisited counter on one resident
-                # tile across the kv-head grid axis
-                pl.BlockSpec((bq, 1), lambda qb, h, *_: (qb, I0)),
-            ],
+            out_specs=[slab, col],
             scratch_shapes=[
-                pltpu.VMEM((2, P, D), k_pages.dtype),  # k double buffer
-                pltpu.VMEM((2, P, D), v_pages.dtype),  # v double buffer
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, P, KVH * D), k_pages.dtype),
+                pltpu.VMEM((2, P, KVH * D), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((KVH, M), jnp.float32),     # running max
+                pltpu.VMEM((KVH, M), jnp.float32),     # running sum
+                pltpu.VMEM((KVH, M, D), jnp.float32),  # accumulators
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((T, H, D), q.dtype),
-            jax.ShapeDtypeStruct((T, 1), jnp.int32),
+            jax.ShapeDtypeStruct((KVH, T * fold, D), q.dtype),
+            jax.ShapeDtypeStruct((T * fold, 1), jnp.int32),
         ],
+        name="ragged_paged_attention",
         interpret=interpret,
-    )(bp, bs, bst, bn,
-      token_seq.astype(jnp.int32).reshape(T, 1),
-      bounds.astype(jnp.int32).reshape(T, 1),
-      q, k_pages, v_pages)
+    )(bn, jnp.stack([bp, bs, bst], axis=1),
+      fold_col(token_seq), fold_col(bounds), qf,
+      pool(k_pages), pool(v_pages))
+    out = out.reshape(KVH, T, fold, D).transpose(1, 0, 2, 3).reshape(
+        T, H, D)
     if return_work:
-        return out, work[:, 0]
+        return out, work.reshape(T, fold)[:, 0]
     return out
 
 

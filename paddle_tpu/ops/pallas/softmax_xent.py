@@ -118,6 +118,7 @@ def _fwd_impl(x2d, lab2d, interpret):
             pltpu.VMEM((bn, 1), jnp.float32),
             pltpu.VMEM((bn, 1), jnp.float32),
         ],
+        name="softmax_xent_fwd",
         interpret=interpret,
     )(x2d, lab2d)
     return loss, lse
@@ -144,6 +145,7 @@ def _bwd(interpret, res, dloss):
         ],
         out_specs=pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, V), x2d.dtype),
+        name="softmax_xent_bwd",
         interpret=interpret,
     )(x2d, lab2d, lse, dloss.astype(jnp.float32))
     return dx, None

@@ -29,7 +29,8 @@ Ragged-batch mechanics:
 
 The grid tiles the channel dimension D; B/C/token_seq are broadcast to
 every tile and the [R, bd, N] state slab rides VMEM for the whole time
-loop. Shapes depend only on (T, R, D, N), so a serving executable
+loop (N sits on the lane axis, so it pads to 128 lanes there: the
+slab, not the activations, sets the tile size — choose_d_block). Shapes depend only on (T, R, D, N), so a serving executable
 keyed on the fixed-shape step signature stays one executable. On CPU
 (tier-1) the same kernel runs in Pallas interpret mode, so the serving
 engine exercises identical code on every backend.
@@ -39,24 +40,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .common import I0
 from . import attention_core as core
 
-__all__ = ["ssm_scan", "selective_scan_reference", "choose_d_block"]
+__all__ = ["ssm_scan", "selective_scan_reference", "choose_d_block",
+           "scan_tile_bytes"]
 
 
-def choose_d_block(d_inner, cap=256):
-    """Channels per grid tile: largest divisor of `d_inner` at most
-    `cap`, by halving (the model rounds d_inner to powers of two, so
-    buckets land on `cap` exactly). One tile holds [R, bd, N] state +
-    [T, bd] activations in VMEM — bd=256 with N=16, R<=8 f32 is ~a few
-    hundred KB, far under budget."""
-    bd = max(int(d_inner), 1)
-    cap = max(int(cap), 1)
-    while bd > cap and bd % 2 == 0:
-        bd //= 2
-    return bd
+_LANES = 128
+# Mosaic's default scoped-VMEM limit on the v5e; a tile over it needs
+# vmem_limit_bytes raised, and _VMEM_CEILING is as far as ssm_scan
+# raises it (the core has 128 MiB of VMEM)
+_VMEM_SCOPED_DEFAULT = 16 << 20
+_VMEM_CEILING = 96 << 20
+
+
+def scan_tile_bytes(bd, n_rows, n_tokens, d_state):
+    """VMEM one grid tile holds, as the chip's compiler reports it
+    (v5e, libtpu 0.0.34: 18.04 MB at bd=256, R=33, T=256, N=16; 17.00
+    MB at bd=128, R=65): the [R, bd, N] state pads N up to 128 lanes
+    and exists four times — h0 and h_out windows, each double-buffered
+    — plus the double-buffered [T, bd] x/dt/y windows. All f32."""
+    n_pad = -(-int(d_state) // _LANES) * _LANES
+    state = 4 * int(n_rows) * bd * n_pad * 4
+    acts = 2 * 3 * int(n_tokens) * bd * 4
+    return state + acts
+
+
+def choose_d_block(d_inner, n_rows, n_tokens, d_state,
+                   budget=_VMEM_SCOPED_DEFAULT - (3 << 20)):
+    """Channels per grid tile: the largest multiple of 128 that divides
+    `d_inner` and whose tile (scan_tile_bytes) fits `budget`; the
+    smallest such multiple when none fits (ssm_scan then raises the
+    kernel's VMEM limit); the full width when no multiple of 128
+    divides `d_inner` (the chip's tiling accepts a full dimension).
+    Halving from d_inner missed these: 1536 -> 192 though 128 and 256
+    divide it."""
+    d_inner = int(d_inner)
+    cands = [bd for bd in range(_LANES, d_inner + 1, _LANES)
+             if d_inner % bd == 0]
+    if not cands:
+        return d_inner
+    fit = [bd for bd in cands
+           if scan_tile_bytes(bd, n_rows, n_tokens, d_state) <= budget]
+    return max(fit) if fit else cands[0]
 
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, seq_ref, h0_ref,
@@ -105,7 +134,18 @@ def ssm_scan(x, dt, b, c, a, h0, token_seq, interpret=None):
     interpret = core.default_interpret(interpret)
     T, D = x.shape
     R, _, N = h0.shape
-    bd = choose_d_block(D)
+    bd = choose_d_block(D, R, T, N)
+    # over the default limit the compiler keeps two more copies of the
+    # state slab (24.96 MB reported where the formula says 17.0), so
+    # ask for half as much again
+    need = scan_tile_bytes(bd, R, T, N) + (2 << 20)
+    if need > _VMEM_SCOPED_DEFAULT:
+        need = need * 3 // 2
+    if need > _VMEM_CEILING:
+        raise ValueError(
+            f"ssm_scan: {R} state rows x {bd} channels x {N} states "
+            f"needs {need >> 20} MiB of VMEM per tile (ceiling "
+            f"{_VMEM_CEILING >> 20} MiB) — lower the engine's max_batch")
     seq2d = token_seq.astype(jnp.int32).reshape(T, 1)
     y, h_out = pl.pallas_call(
         functools.partial(_scan_kernel, n_tokens=T),
@@ -129,6 +169,9 @@ def ssm_scan(x, dt, b, c, a, h0, token_seq, interpret=None):
             jax.ShapeDtypeStruct((T, D), x.dtype),
             jax.ShapeDtypeStruct((R, D, N), h0.dtype),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(need, _VMEM_SCOPED_DEFAULT)),
+        name="ssm_scan",
         interpret=interpret,
     )(x, dt, b, c, a, seq2d, h0)
     return y, h_out

@@ -1,22 +1,35 @@
 """Native runtime bindings: build + load the C++ core via ctypes."""
 import ctypes
+import hashlib
 import os
 import subprocess
-import sys
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "cpp", "runtime_core.cpp")
+# build/ is in .gitignore: the library is built from the committed
+# source on first use, never shipped as a binary
 _BUILD = os.path.join(_HERE, "build")
-_SO = os.path.join(_BUILD, "libpaddle_tpu_runtime.so")
 
 _lib = None
 
 
-def _build():
+def _so_path():
+    """The library for THIS source: keyed by a hash of the source, not
+    by mtimes (a fresh copy of the tree has arbitrary mtimes)."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD, f"libpaddle_tpu_runtime-{digest}.so")
+
+
+def _build(so):
     os.makedirs(_BUILD, exist_ok=True)
+    # build beside the target and rename: concurrent first uses (test
+    # workers) never load a half-written library
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           _SRC, "-o", _SO]
+           _SRC, "-o", tmp]
     subprocess.run(cmd, check=True, capture_output=True)
+    os.replace(tmp, so)
 
 
 def get_lib():
@@ -26,10 +39,10 @@ def get_lib():
     if _lib is not None:
         return _lib
     try:
-        if not os.path.exists(_SO) or (
-                os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            _build()
-        lib = ctypes.CDLL(_SO)
+        so = _so_path()
+        if not os.path.exists(so):
+            _build(so)
+        lib = ctypes.CDLL(so)
         lib.rb_create.restype = ctypes.c_void_p
         lib.rb_create.argtypes = [ctypes.c_size_t]
         lib.rb_push.restype = ctypes.c_int

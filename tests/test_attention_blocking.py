@@ -241,6 +241,10 @@ class TestServingBucketFloor:
             # prompt 5 at page_size 4: chunk T=5->8, remainders
             # 4/2/1->8, decode 1->8; widths stay 2 — ONE signature
             assert sigs == {(8, 1, 2)}, sigs
+            # the engine's inspection view of the same executables
+            texts = eng.compiled_texts()
+            assert {s[:3] for s in texts} == sigs
+            assert all("HloModule" in t for t in texts.values())
         finally:
             eng.shutdown()
 

@@ -1,0 +1,199 @@
+"""AOT compiles of the main path's Pallas kernels for a DESCRIBED TPU v5e
+(no chip attached): what interpret mode cannot see — block shapes the
+tiling refuses, i64 operands Mosaic cannot legalize, SMEM/VMEM budgets —
+the chip's own compiler says here, at real widths, for no chip time
+(/opt/skills/guides/on-chip-measurement, section 2, third rehearsal).
+
+The topology is described inside a module-scoped fixture (only one
+process at a time may load the TPU's library, and every xdist worker
+imports every test file): never at import, in a skipif, in parametrize or
+in conftest.py. All these tests live in THIS one file so that one worker
+loads the library. A compile that passes is not a chip run:
+`python chip_smoke.py` on the chip is.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(topo):
+    """compile(fn, *shape_dtype_pairs) -> the compiled executable for
+    device 0 of the described topology, with the persistent compile
+    cache off around it (an entry written for a described device cannot
+    be read back without the chip, and warns)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def sds(spec):
+        return jax.ShapeDtypeStruct(spec[0], spec[1], sharding=one_chip)
+
+    def compile_(fn, *specs):
+        args = jax.tree.map(sds, list(specs),
+                            is_leaf=lambda x: isinstance(x, tuple))
+        return jax.jit(fn).lower(*args).compile()
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _custom_calls(compiled, name):
+    return sum(1 for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and name in line)
+
+
+def test_flash_attention_fwd_bwd_gpt_medium(compile_for_chip):
+    """8 x 1024 x 16 heads x 64, bf16, causal: forward and both
+    backward kernels."""
+    from paddle_tpu.ops.pallas.flash_attention import \
+        flash_attention_arrays
+    qkv = ((8, 1024, 16, 64), BF16)
+
+    def loss(q, k, v):
+        out = flash_attention_arrays(q, k, v, causal=True,
+                                     interpret=False)
+        return jnp.sum(out.astype(F32))
+
+    c = compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    for kernel in ("flash_attention_fwd", "flash_attention_dq",
+                   "flash_attention_dkv"):
+        assert _custom_calls(c, kernel) == 1, kernel
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim", [
+    (16, 16, 64),     # GPT-medium: fold 1
+    (32, 8, 128),     # grouped-query: fold 4
+    (20, 1, 128),     # multi-query, a head count that is no power of 2
+], ids=["fold1", "fold4", "mqa20"])
+def test_ragged_paged_attention_head_groupings(compile_for_chip, heads,
+                                               kv_heads, head_dim):
+    """256 tokens over 8 sequences x 64 pages of a 2048-page pool."""
+    from paddle_tpu.ops.pallas.paged_attention import \
+        ragged_paged_attention
+    T, B, W, n_pages, P = 256, 8, 64, 2048, 16
+    pool = ((n_pages, P, kv_heads, head_dim), BF16)
+    c = compile_for_chip(
+        lambda q, k, v, pt, seq, bd: ragged_paged_attention(
+            q, k, v, pt, seq, bd, interpret=False),
+        ((T, heads, head_dim), BF16), pool, pool, ((B, W), I32),
+        ((T,), I32), ((T,), I32))
+    assert _custom_calls(c, "ragged_paged_attention") == 1
+
+
+def test_ragged_block_plan_is_windowed_in_smem(compile_for_chip):
+    """64 sequences x 256 pages x 16 q-blocks: the whole plan would be
+    3 x 1 MB of SMEM (the chip has 1 MB); windowed per q-block it is
+    2 x 256 KB and compiles."""
+    from paddle_tpu.ops.pallas.paged_attention import \
+        ragged_paged_attention
+    T, B, W, n_pages, P = 2048, 64, 256, 16384, 16
+    pool = ((n_pages, P, 16, 64), BF16)
+    compile_for_chip(
+        lambda q, k, v, pt, seq, bd: ragged_paged_attention(
+            q, k, v, pt, seq, bd, interpret=False),
+        ((T, 16, 64), BF16), pool, pool, ((B, W), I32), ((T,), I32),
+        ((T,), I32))
+
+
+@pytest.mark.parametrize("d_inner", [1536, 5120])
+def test_ssm_scan_default_and_wide(compile_for_chip, d_inner):
+    """SSMConfig()'s own d_inner (1536 — halving used to land on 192)
+    and 5120 (160), with 33 state rows over 256 tokens."""
+    from paddle_tpu.ops.pallas.ssm_scan import choose_d_block, ssm_scan
+    T, R, N = 256, 33, 16
+    assert choose_d_block(d_inner, R, T, N) % 128 == 0
+    tok, coef = ((T, d_inner), F32), ((T, N), F32)
+    c = compile_for_chip(
+        lambda *a: ssm_scan(*a, interpret=False),
+        tok, tok, coef, coef, ((d_inner, N), F32),
+        ((R, d_inner, N), F32), ((T,), I32))
+    assert _custom_calls(c, "ssm_scan") == 1
+
+
+def _gpt_medium_buckets():
+    """GPT-medium's parameter tree by shape (24 layers, hidden 1024,
+    vocab 50,304): the bucket sizes the fused update sweeps."""
+    h, L, V = 1024, 24, 50304
+    shapes = {"wte.weight": (V, h), "wpe.weight": (1024, h),
+              "ln_f.weight": (h,), "ln_f.bias": (h,)}
+    for i in range(L):
+        for name, shape in (("ln_1.weight", (h,)), ("ln_1.bias", (h,)),
+                            ("qkv_proj.weight", (h, 3 * h)),
+                            ("qkv_proj.bias", (3 * h,)),
+                            ("out_proj.weight", (h, h)),
+                            ("out_proj.bias", (h,)),
+                            ("ln_2.weight", (h,)), ("ln_2.bias", (h,)),
+                            ("fc_in.weight", (h, 4 * h)),
+                            ("fc_in.bias", (4 * h,)),
+                            ("fc_out.weight", (4 * h, h)),
+                            ("fc_out.bias", (h,))):
+            shapes[f"h.{i}.{name}"] = shape
+    return shapes
+
+
+def test_fused_update_passes_gpt_medium_layout(compile_for_chip):
+    """Both passes, mode "pallas", over every bucket of a 355M-parameter
+    bf16 layout with f32 moments and masters, loss scaling and health
+    sums on: one kernel per bucket per pass."""
+    from paddle_tpu import optimizer as opt
+    from paddle_tpu.ops.pallas import fused_update as fu
+    shapes = _gpt_medium_buckets()
+    assert sum(int(np.prod(s)) for s in shapes.values()) > 350e6
+    lay = fu.BucketLayout([(k, s, BF16) for k, s in shapes.items()])
+    spec = opt.AdamW(learning_rate=1e-4).fused_spec()
+    n = len(lay.buckets)
+    grads = {k: (lay.bucket_shape(k), BF16) for k in lay.buckets}
+    state = {k: (lay.bucket_shape(k), F32) for k in lay.buckets}
+
+    c1 = compile_for_chip(
+        lambda g, inv: fu._run_pass1(lay, g, inv, True, "pallas"),
+        grads, ((), F32))
+    assert _custom_calls(c1, "fused_update_pass1") == n
+    c2 = compile_for_chip(
+        lambda g, p, m, v, mw, sc: fu._run_pass2(
+            lay, spec, g, p, [m, v], mw, sc, True, True, None, "pallas"),
+        grads, grads, state, state, state, ((4,), F32))
+    assert _custom_calls(c2, "fused_update_pass2") == n
+
+
+def test_fused_update_tail_block_and_per_shard_layout(compile_for_chip):
+    """A bucket that is neither a multiple of 128 nor of the row block
+    (the masked tail), and mp=2 / sharding=2 shards of GPT-medium's
+    leaves as HybridTrainStep packs them."""
+    from paddle_tpu import optimizer as opt
+    from paddle_tpu.ops.pallas import fused_update as fu
+    shapes = {"odd": (3000, 1333), "h.0.qkv_proj.weight": (512, 1536),
+              "h.1.qkv_proj.weight": (512, 1536), "wte": (12576, 1024)}
+    lay = fu.BucketLayout([(k, s, BF16) for k, s in shapes.items()])
+    spec = opt.AdamW(learning_rate=1e-4).fused_spec()
+    grads = {k: (lay.bucket_shape(k), BF16) for k in lay.buckets}
+    state = {k: (lay.bucket_shape(k), F32) for k in lay.buckets}
+    compile_for_chip(
+        lambda g, inv: fu._run_pass1(lay, g, inv, False, "pallas"),
+        grads, ((), F32))
+    compile_for_chip(
+        lambda g, p, m, v, mw, sc: fu._run_pass2(
+            lay, spec, g, p, [m, v], mw, sc, False, False, None,
+            "pallas"),
+        grads, grads, state, state, state, ((4,), F32))

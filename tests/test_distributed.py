@@ -219,7 +219,7 @@ class TestCollectivesAPI:
 
         def f(x):
             return psum(x, "dp")
-        from paddle_tpu.framework.jax_compat import shard_map
+        from jax import shard_map
         out = shard_map(f, mesh=mesh, in_specs=P("dp"),
                         out_specs=P())(jnp.arange(8.0))
         assert float(out[0]) == 28.0
@@ -254,32 +254,30 @@ class TestZeROStages:
     @pytest.mark.heavy
 
     def test_stage2_grads_constrained_sharded(self):
-        """Stage-2 pins gradients to the 'sharding' axis: the lowered
-        program must carry the sharding constraints (28 grad leaves), and
-        the compiled update must run on grad SHARDS (sliced shapes), with
-        the grad sync lowered as all-reduce+slice — the pair the TPU
+        """Stage-2 pins gradients to the 'sharding' axis: the compiled
+        update must run on grad SHARDS (sliced shapes), with the grad
+        sync lowered as all-reduce+slice — the pair the TPU
         ReduceScatterCreator pass fuses into reduce-scatter (the CPU
         pipeline keeps them separate, so we assert the pattern, not the
-        fused op name)."""
-        import jax.numpy as jnp
-        from paddle_tpu.framework.random import split_key
+        fused op name) — the optimizer state it leaves must STAY on the
+        'sharding' axis and the parameters come back at their own specs.
+        (Asserted on .sharding, not on the lowered text: the fused
+        epilogue takes the grads into a shard_map whose in_specs ARE the
+        zero specs, so jax 0.9 prints no separate constraint ops.)"""
         step = self._build(2)
         ids = paddle.to_tensor(
             np.random.RandomState(0).randint(0, 1024, size=(8, 16)))
-        arrays = [ids.value, ids.value]
-        lowered = step._jitted.lower(
-            step.params, step.opt_state, step.scaler_state, step.buffers,
-            split_key(), jnp.asarray(0.1, jnp.float32), 1, *arrays)
-        txt = lowered.as_text()
-        # jax >= 0.6 prints sharding_constraint ops; 0.4.x lowers the
-        # same constraint as a custom_call @Sharding
-        n_constraints = txt.count("sharding_constraint") + \
-            txt.count("@Sharding")
-        assert n_constraints >= 20, n_constraints
-        hlo = lowered.compile().as_text()
+        assert sum("sharding" in str(s)
+                   for s in step.zero_specs.values()) >= 20
+        hlo = step.compiled_text(ids, ids)
         # qkv grad [64,192] over sharding=2 -> update math sees [32,192]
         assert "f32[32,192]" in hlo, "update does not run on grad shards"
         assert ("reduce-scatter" in hlo) or ("all-reduce" in hlo)
+        step(ids, ids)
+        pk = "gpt.h.0.attn.qkv_proj.weight"
+        for leaf in jax.tree.leaves(step.opt_state[pk]):
+            assert "sharding" in str(leaf.sharding.spec), leaf.sharding
+        assert "sharding" not in str(step.params[pk].sharding.spec)
 
     @pytest.mark.heavy
 

@@ -205,9 +205,12 @@ def test_found_inf_skips_update_and_backs_off_scale():
         np.testing.assert_array_equal(
             m_before, np.asarray(jax.tree.leaves(st.opt_state)[0]))
         assert float(st.scaler_state["scale"]) == 2.0 ** 14
-    # both recover identically on a good batch
-    assert float(fused(x, y).item()) == float(tree(x, y).item())
-    _assert_state_equal(fused, tree, exact=True)
+    # both recover alike on a good batch (to rounding: under jax 0.9.0
+    # XLA:CPU fuses the unscale into the two epilogues differently, and
+    # one weight differs by an f32 ulp)
+    assert float(fused(x, y).item()) == \
+        pytest.approx(float(tree(x, y).item()), rel=1e-6)
+    _assert_state_equal(fused, tree, exact=False)
 
 
 def test_nan_without_scaler_still_updates_like_tree():
@@ -366,10 +369,10 @@ def test_nan_in_need_clip_masked_leaf_trips_health_found_inf():
 
 
 def test_pallas_interpret_mode_matches_direct():
-    """The Pallas kernel plumbing (grid, BlockSpecs, scalar prefetch,
-    chunk->leaf offset table) must compute exactly what the direct
-    off-TPU path computes — this is what validates the TPU kernels from
-    tier-1."""
+    """The Pallas kernel plumbing (grid, BlockSpecs, SMEM accumulators,
+    the same row blocking the chip gets) must compute exactly what the
+    direct off-TPU path computes — this is what validates the TPU
+    kernels from tier-1."""
     from paddle_tpu.ops.pallas.fused_update import (BucketLayout,
                                                     FusedEpilogue)
     rng = np.random.RandomState(3)
@@ -379,8 +382,7 @@ def test_pallas_interpret_mode_matches_direct():
     grads = {k: jnp.asarray(rng.randn(*v.shape) * 0.1, v.dtype)
              for k, v in params.items()}
     o = opt.AdamW(learning_rate=0.01)
-    lay = BucketLayout([(k, v.shape, v.dtype) for k, v in params.items()],
-                       chunk=128)
+    lay = BucketLayout([(k, v.shape, v.dtype) for k, v in params.items()])
     scaler = GradScaler(init_loss_scaling=2.0 ** 6)
     clip = ClipGradByGlobalNorm(0.5)
     outs = []

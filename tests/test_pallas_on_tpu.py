@@ -1,15 +1,18 @@
-"""Real-TPU Mosaic lowering proof for the Pallas kernels (interpret=False).
+"""Real-TPU Mosaic lowering AND results proof for three Pallas kernels
+(interpret=False): fwd/bwd of flash_attention, layer_norm and
+softmax_xent against their XLA references.
 
-The CPU suite runs the kernels with interpret=True; this file is the
-on-hardware counterpart. It must be run OUTSIDE the normal suite (the
-conftest pins tests to the CPU backend):
+The CPU suite runs the kernels with interpret=True, and
+tests/test_chip_compile.py compiles the main path's kernels for a
+described v5e without a chip; this file RUNS on the chip. In the normal
+suite (conftest pins the CPU backend) its one test skips from inside the
+test. On the chip, through the chip tool and as the only process:
 
-    JAX_PLATFORMS= python -m pytest tests/test_pallas_on_tpu.py --no-header \
-        -q -p no:cacheprovider --override-ini addopts= -c /dev/null
+    chiprun -- python tests/test_pallas_on_tpu.py
 
-or simply `python tests/test_pallas_on_tpu.py`. Skips unless the default
-backend is TPU. Verified green on v5e (2026-07-29): fwd/bwd of
-flash_attention, layer_norm, softmax_xent all lower and match XLA refs.
+which exits non-zero where there is no TPU (a run that proved nothing
+is not a pass). `python chip_smoke.py` is the wider proof: the whole
+train step and serving step, with these kernels in place.
 """
 import numpy as np
 
@@ -95,7 +98,7 @@ if __name__ == "__main__":
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), ".."))
     if not _on_tpu():
-        print("SKIP: not on TPU")
-    else:
-        run_all()
-        print("ok: all Pallas kernels lower and match on real TPU")
+        print("SKIP: not on TPU — nothing was proved")
+        raise SystemExit(2)
+    run_all()
+    print("ok: all Pallas kernels lower and match on real TPU")
