@@ -1,0 +1,5 @@
+"""Programs compiled (or loaded from the cache) inside the window."""
+
+
+def read(ctx):
+    return ctx["window"]["compiles"]
