@@ -133,15 +133,14 @@ def max_memory_allocated(device=None):
     returning sane nonzero values everywhere). Each query lands in the
     telemetry store (a "device.memory" span + the device.peak_bytes
     gauge), so Profiler.summary() carries the memory high-water mark."""
-    import time
     from ..profiler import statistic as _stat
     from ..profiler import monitor as _monitor
-    t0 = time.perf_counter()
-    peak = _memory_stats(device).get("peak_bytes_in_use", 0)
-    if not peak:
-        import resource
-        peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
-    _stat.record_span("device.memory", time.perf_counter() - t0)
+    with _stat.span("device.memory"):
+        peak = _memory_stats(device).get("peak_bytes_in_use", 0)
+        if not peak:
+            import resource
+            peak = resource.getrusage(
+                resource.RUSAGE_SELF).ru_maxrss * 1024
     _monitor.gauge("device.peak_bytes").set(int(peak))
     return int(peak)
 

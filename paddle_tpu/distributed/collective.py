@@ -94,7 +94,6 @@ def _instrumented(fn=None, *, payload=None):
     if fn is None:
         return lambda f: _instrumented(f, payload=payload)
     import functools
-    import time
     kind = fn.__name__
 
     @functools.wraps(fn)
@@ -104,12 +103,11 @@ def _instrumented(fn=None, *, payload=None):
         sel = payload(args) if payload else args
         nbytes = _payload_bytes(sel)
         traced = _any_traced(sel)
-        t0 = time.perf_counter()
+        _stat.begin_span(f"collective.{kind}")
         try:
             return fn(*args, **kwargs)
         finally:
-            dt = time.perf_counter() - t0
-            _stat.record_span(f"collective.{kind}", dt)
+            dt = _stat.end_span()
             _monitor.counter(f"collective.{kind}.calls").inc()
             _monitor.counter(f"collective.{kind}.bytes").inc(nbytes)
             _dobs.record_collective(kind, _group_label(args, kwargs),

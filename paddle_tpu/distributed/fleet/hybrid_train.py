@@ -545,18 +545,46 @@ class HybridTrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                 for k in self.opt_state}
 
     def __call__(self, *batch):
+        """One hybrid-parallel optimizer step. On the host the call is
+        one `fleet.hybrid_step` span with TrainStep.__call__'s children
+        under TrainStep's names: `train.step.prep`, `train.step.probe`,
+        `train.step.dispatch`, `train.step.telemetry`."""
         self._step_i += 1
-        if _fault.active():  # fault drills only; two dict reads when off
-            batch = fire_step_faults(self, batch)
-        sig, args = self._prep(batch, self._step_i)
-        probe = device_probe_open(self, self._step_i)
+        with _stat.span("fleet.hybrid_step", step_num=self._step_i):
+            with _stat.span("train.step.prep"):
+                if _fault.active():  # fault drills only; two dict reads when off
+                    batch = fire_step_faults(self, batch)
+                sig, args = self._prep(batch, self._step_i)
+            probe = device_probe_open(self, self._step_i)
+            out, info, compiled_now, dispatch_s = self._dispatch(
+                sig, args, len(batch))
+            health = None
+            if self.monitor_health:
+                loss, health, self.params, self.opt_state, \
+                    self.scaler_state = out
+            else:
+                loss, self.params, self.opt_state, self.scaler_state = out
+            device_probe_close(self, self._step_i, probe, loss, info,
+                               compiled_now=compiled_now)
+            with _stat.span("train.step.telemetry"):
+                if health is not None:
+                    self._queue_health(self._step_i, health)
+                export_step_metrics(self, dispatch_s, info, compiled_now)
+                # non-blocking handle (see jit/deferred.py): the fit
+                # loop keeps dispatching while the loss streams back
+                return DeferredLoss(loss)
+
+    def _dispatch(self, sig, args, n_batch):
+        """Executable-cache lookup, a joined or inline compile on a
+        miss, and the timed dispatch, under `train.step.dispatch`.
+        Returns (outputs, info, compiled_now, dispatch_s)."""
         _flight.heartbeat(self._step_i)  # watchdog liveness pulse
-        _stat.begin_span("fleet.hybrid_step")
+        _stat.begin_span("train.step.dispatch")
         try:
             entry = self._exec.get(sig)
             compiled_now = entry is None
             if compiled_now:
-                entry = self._warm_submit(sig, args, len(batch),
+                entry = self._warm_submit(sig, args, n_batch,
                                           inline=True).result()
             compiled, info = entry
             count_train_use(self, info)
@@ -598,20 +626,9 @@ class HybridTrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                         "the step donates its buffers; build with "
                         "donate=False to localize)") from e
                 raise
-            if self.monitor_health:
-                loss, health, self.params, self.opt_state, \
-                    self.scaler_state = out
-                self._queue_health(self._step_i, health)
-            else:
-                loss, self.params, self.opt_state, self.scaler_state = out
         finally:
             dispatch_s = _stat.end_span()
-        device_probe_close(self, self._step_i, probe, loss, info,
-                           compiled_now=compiled_now)
-        export_step_metrics(self, dispatch_s, info, compiled_now)
-        # non-blocking handle (see jit/deferred.py): the fit loop keeps
-        # dispatching while the loss streams back
-        return DeferredLoss(loss)
+        return out, info, compiled_now, dispatch_s
 
     def cost_analysis(self, *batch):
         """XLA cost report for this batch signature's SPMD executable

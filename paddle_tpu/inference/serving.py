@@ -1389,7 +1389,8 @@ class GenerationEngine(_SchedulerLifecycle):
                     and not self._prefilling and not self._adopted:
                 if self._stopping:
                     return False
-                self._cv.wait(0.05)  # idle: wait for work
+                with _stat.span("serve.idle"):
+                    self._cv.wait(0.05)  # idle: wait for work
                 if not self._pending and not self._active \
                         and not self._prefilling and not self._adopted:
                     return True  # still idle: let the runner drop its ref
@@ -1401,14 +1402,21 @@ class GenerationEngine(_SchedulerLifecycle):
             return False
         try:
             if self.ragged:
-                self._drain_adopted()
-                self._admit_ragged()
-                if self._active or self._prefilling:
-                    self._ragged_step()
-                else:
+                # one scheduler turn is one `serve.step` span on this
+                # thread; its children (serve.step.admit here, the rest
+                # in _ragged_step) cover it
+                with _stat.span("serve.step"):
+                    with _stat.span("serve.step.admit"):
+                        self._drain_adopted()
+                        self._admit_ragged()
+                    stepped = bool(self._active or self._prefilling)
+                    if stepped:
+                        self._ragged_step()
+                if not stepped:
                     with self._cv:
                         if self._pending and not self._stopping:
-                            self._cv.wait(0.01)
+                            with _stat.span("serve.idle"):
+                                self._cv.wait(0.01)
                 return True
             self._admit()
             if self._active:
@@ -2112,219 +2120,229 @@ class GenerationEngine(_SchedulerLifecycle):
         is an on-device argmax (or the seeded per-position draw); the
         host reads back one int32 per row — per TOKEN when verifying
         drafts — through a copy launched at dispatch."""
-        for s in list(self._prefilling):  # cancelled mid-prefill: evict
-            if s.handle.future.cancelled():
-                with self.cache.lock:
-                    self.cache.free_sequence(s.sid)
-                self._free_draft(s)
-                self._prefilling.remove(s)  # lint-ok[unlocked-shared-state]: scheduler-thread-owned list; readers take GIL-atomic list() snapshots, remove() is C-level atomic
+        with _stat.span("serve.step.plan"):
+            for s in list(self._prefilling):  # cancelled mid-prefill: evict
+                if s.handle.future.cancelled():
+                    with self.cache.lock:
+                        self.cache.free_sequence(s.sid)
+                    self._free_draft(s)
+                    self._prefilling.remove(s)  # lint-ok[unlocked-shared-state]: scheduler-thread-owned list; readers take GIL-atomic list() snapshots, remove() is C-level atomic
+                    if s.handle.trace is not None:
+                        s.handle.trace.finish("cancelled")
+                    s.handle._close()
+            spec_on = self._draft_cache is not None
+            drafts = self._spec_propose() if spec_on else {}
+            rows, metas = [], []
+            for s in self._active:
+                d = drafts.get(s.sid)
+                if d:
+                    # verify row: the anchor token (whose KV the target
+                    # hasn't written yet) + the draft's proposals, one
+                    # prefill-shaped row — its k+1 <= MIN_Q_TOKENS tokens
+                    # pad into the same bucket a 1-token decode row does
+                    rows.append((s.sid, [s.last] + d))
+                    metas.append(("verify", s, 1 + len(d)))
+                else:
+                    rows.append((s.sid, [s.last]))
+                    metas.append(("decode", s, 1))
+            budget = self.prefill_chunk
+            # shortest-remaining-first: a short chat's 4 tokens must not
+            # queue behind a long document's 15 chunks — the short one
+            # finishes its prefill (and streams its first token) within a
+            # step or two while the long one keeps absorbing the leftover
+            # budget each step
+            order = sorted(self._prefilling,
+                           key=lambda s: s.handle.prompt.size - s.filled)
+            for s in order:
+                if budget <= 0:
+                    break
+                n = min(budget, s.handle.prompt.size - s.filled)
+                rows.append((s.sid, s.handle.prompt[s.filled:s.filled + n]))
+                metas.append(("prefill", s, n))
                 if s.handle.trace is not None:
-                    s.handle.trace.finish("cancelled")
-                s.handle._close()
-        spec_on = self._draft_cache is not None
-        drafts = self._spec_propose() if spec_on else {}
-        rows, metas = [], []
-        for s in self._active:
-            d = drafts.get(s.sid)
-            if d:
-                # verify row: the anchor token (whose KV the target
-                # hasn't written yet) + the draft's proposals, one
-                # prefill-shaped row — its k+1 <= MIN_Q_TOKENS tokens
-                # pad into the same bucket a 1-token decode row does
-                rows.append((s.sid, [s.last] + d))
-                metas.append(("verify", s, 1 + len(d)))
+                    s.handle.trace.note_chunk()
+                budget -= n
+            if not rows:
+                return
+            t_real = sum(n for _, _, n in metas)
+            b_real = len(rows)
+            # the token bucket floors at MIN_Q_TOKENS so every q-block the
+            # kernel forms reaches the MXU's 8-row sublane tile (a pure-
+            # decode step of 1-3 rows would otherwise dispatch the old
+            # [1, D] VPU-shaped dots); the extra slots carry bound 0 and
+            # compute NOTHING — they ride sublanes the narrow dot wasted
+            from ..ops.pallas.attention_core import MIN_Q_TOKENS
+            pad_t = max(self._pow2(t_real), MIN_Q_TOKENS)
+            pad_b = min(self._pow2(b_real), self._pow2(self.max_batch))
+            # slot-accurate accounting (pre-dispatch: lengths advance in
+            # the step): each token computes exactly ceil(bound/page)
+            # pages of score slots — pad slots compute NOTHING (kernel
+            # predicate), so the only waste is the intra-page remainder.
+            # ragged_work_plan is the kernel's own work formula: the
+            # metric and the in-kernel counter cannot diverge
+            if self.cache_strategy == "recurrent":
+                # no kv pages to walk: the scan kernel's time loop runs
+                # pad_t constant-cost state updates, of which t_real are
+                # real tokens — THAT is the strategy's pad overhead
+                computed = int(pad_t)
+                useful = int(t_real)
             else:
-                rows.append((s.sid, [s.last]))
-                metas.append(("decode", s, 1))
-        budget = self.prefill_chunk
-        # shortest-remaining-first: a short chat's 4 tokens must not
-        # queue behind a long document's 15 chunks — the short one
-        # finishes its prefill (and streams its first token) within a
-        # step or two while the long one keeps absorbing the leftover
-        # budget each step
-        order = sorted(self._prefilling,
-                       key=lambda s: s.handle.prompt.size - s.filled)
-        for s in order:
-            if budget <= 0:
-                break
-            n = min(budget, s.handle.prompt.size - s.filled)
-            rows.append((s.sid, s.handle.prompt[s.filled:s.filled + n]))
-            metas.append(("prefill", s, n))
-            if s.handle.trace is not None:
-                s.handle.trace.note_chunk()
-            budget -= n
-        if not rows:
-            return
-        t_real = sum(n for _, _, n in metas)
-        b_real = len(rows)
-        # the token bucket floors at MIN_Q_TOKENS so every q-block the
-        # kernel forms reaches the MXU's 8-row sublane tile (a pure-
-        # decode step of 1-3 rows would otherwise dispatch the old
-        # [1, D] VPU-shaped dots); the extra slots carry bound 0 and
-        # compute NOTHING — they ride sublanes the narrow dot wasted
-        from ..ops.pallas.attention_core import MIN_Q_TOKENS
-        pad_t = max(self._pow2(t_real), MIN_Q_TOKENS)
-        pad_b = min(self._pow2(b_real), self._pow2(self.max_batch))
-        # slot-accurate accounting (pre-dispatch: lengths advance in
-        # the step): each token computes exactly ceil(bound/page)
-        # pages of score slots — pad slots compute NOTHING (kernel
-        # predicate), so the only waste is the intra-page remainder.
-        # ragged_work_plan is the kernel's own work formula: the
-        # metric and the in-kernel counter cannot diverge
-        if self.cache_strategy == "recurrent":
-            # no kv pages to walk: the scan kernel's time loop runs
-            # pad_t constant-cost state updates, of which t_real are
-            # real tokens — THAT is the strategy's pad overhead
-            computed = int(pad_t)
-            useful = int(t_real)
-        else:
-            from ..ops.pallas.paged_attention import ragged_work_plan
-            P = self.cache.page_size
-            bounds = np.concatenate(
-                [self.cache.length(sid) + np.arange(1, len(toks) + 1)
-                 for sid, toks in rows])
-            computed = int(ragged_work_plan(bounds, P).sum()) * P
-            useful = int(bounds.sum())
-        self._attn_computed += computed  # lint-ok[unlocked-shared-state]: loop-thread-owned monotonic counter (ragged site), same contract as the bucketed decode site
-        self._attn_useful += useful  # lint-ok[unlocked-shared-state]: paired with _attn_computed above — same single-writer telemetry counter
-        # per-row sampling config, [pad_b]-shaped like the row axis so
-        # the compiled signature still keys on (T, B, W) only: pad and
-        # greedy rows carry temperature 0 (the bit-exact argmax lane),
-        # sampled rows their request's temperature/top-k/top-p and the
-        # per-SEQUENCE base key (the step folds in the token position)
-        temps = np.zeros((pad_b,), np.float32)
-        top_ks = np.zeros((pad_b,), np.int32)
-        top_ps = np.ones((pad_b,), np.float32)
-        keys = np.zeros((pad_b, 2), np.uint32)
-        for i, (_, s, _) in enumerate(metas):
-            sp = s.sampling
-            if sp is not None and not sp.greedy:
-                temps[i] = sp.temperature
-                top_ks[i] = sp.top_k or 0
-                top_ps[i] = 1.0 if sp.top_p is None else sp.top_p
-                keys[i] = s.key
-        try:
+                from ..ops.pallas.paged_attention import ragged_work_plan
+                P = self.cache.page_size
+                bounds = np.concatenate(
+                    [self.cache.length(sid) + np.arange(1, len(toks) + 1)
+                     for sid, toks in rows])
+                computed = int(ragged_work_plan(bounds, P).sum()) * P
+                useful = int(bounds.sum())
+            self._attn_computed += computed  # lint-ok[unlocked-shared-state]: loop-thread-owned monotonic counter (ragged site), same contract as the bucketed decode site
+            self._attn_useful += useful  # lint-ok[unlocked-shared-state]: paired with _attn_computed above — same single-writer telemetry counter
+            # per-row sampling config, [pad_b]-shaped like the row axis so
+            # the compiled signature still keys on (T, B, W) only: pad and
+            # greedy rows carry temperature 0 (the bit-exact argmax lane),
+            # sampled rows their request's temperature/top-k/top-p and the
+            # per-SEQUENCE base key (the step folds in the token position)
+            temps = np.zeros((pad_b,), np.float32)
+            top_ks = np.zeros((pad_b,), np.int32)
+            top_ps = np.ones((pad_b,), np.float32)
+            keys = np.zeros((pad_b, 2), np.uint32)
+            for i, (_, s, _) in enumerate(metas):
+                sp = s.sampling
+                if sp is not None and not sp.greedy:
+                    temps[i] = sp.temperature
+                    top_ks[i] = sp.top_k or 0
+                    top_ps[i] = 1.0 if sp.top_p is None else sp.top_p
+                    keys[i] = s.key
+        # the step's sizes ride on the event, as run and as padded; an
+        # inline compile nests as jit.trace_lower / jit.compile
+        with _stat.span("serve.step.dispatch", tokens=t_real, rows=b_real,
+                        bucket_tokens=pad_t, bucket_rows=pad_b):
+            try:
+                if spec_on:
+                    # same executable — the jitted step always computes the
+                    # per-token sample lane; return_per_token only changes
+                    # which Python-level outputs we keep
+                    _, nxt, nxt_tok = self.model.paged_ragged_step(
+                        self.cache, rows, pad_to_tokens=pad_t,
+                        pad_to_rows=pad_b,
+                        sampling=(temps, top_ks, top_ps, keys),
+                        return_per_token=True)
+                    nxt_tok.copy_to_host_async()  # overlap with bookkeeping
+                else:
+                    _, nxt = self.model.paged_ragged_step(
+                        self.cache, rows, pad_to_tokens=pad_t,
+                        pad_to_rows=pad_b,
+                        sampling=(temps, top_ks, top_ps, keys))
+                    nxt.copy_to_host_async()  # overlap with the bookkeeping
+            except RuntimeError as e:
+                if _mobs.is_oom(e):
+                    # allocator exhaustion mid-decode: dump mem_state.json
+                    # forensics (the kv pool is usually the top holder)
+                    # before the scheduler's crash path sees it
+                    raise _mobs.oom_error(e, site="serve.ragged_step") from e
+                raise
+        with _stat.span("serve.step.telemetry"):
+            self._sync_retraces()
+            now = time.perf_counter()
+            prefill_toks = sum(n for k, _, n in metas if k == "prefill")
+            _monitor.histogram("serve.batch_size").observe(b_real)
+            if prefill_toks:
+                _monitor.counter("serve.chunked_prefill_tokens").inc(
+                    prefill_toks)
+            shared = self.cache.shared_page_count()
+            _monitor.gauge("serve.shared_pages").set(shared)
+            hits, self._step_prefix_hits = self._step_prefix_hits, 0
+            rec = {"engine": self.name, "requests": b_real,
+                   "batch_size": b_real, "bucket_batch": int(pad_b),
+                   "tokens": int(t_real), "bucket_tokens": int(pad_t),
+                   "cache_strategy": self.cache_strategy,
+                   "queue_depth": len(self._pending),
+                   # pad SLOTS exist (pad_t - t_real) but carry bound 0: the
+                   # kernel computes zero attention blocks for them, so the
+                   # compute-bearing pad count — what serve.pad_tokens has
+                   # always measured — is 0 by construction on this path,
+                   # and the slot fraction is only the intra-page remainder
+                   "pad_tokens": 0,
+                   "pad_token_fraction": max(0.0, 1.0 - useful / computed)
+                   if computed else 0.0,
+                   "pad_slots": int(pad_t - t_real),
+                   "prefix_hits": hits, "shared_pages": shared,
+                   "chunked_prefill_tokens": prefill_toks,
+                   "latency_s": sum(now - s.handle.t_submit
+                                    for _, s, _ in metas) / b_real}
+        with _stat.span("serve.step.fetch"):
             if spec_on:
-                # same executable — the jitted step always computes the
-                # per-token sample lane; return_per_token only changes
-                # which Python-level outputs we keep
-                _, nxt, nxt_tok = self.model.paged_ragged_step(
-                    self.cache, rows, pad_to_tokens=pad_t,
-                    pad_to_rows=pad_b,
-                    sampling=(temps, top_ks, top_ps, keys),
-                    return_per_token=True)
-                nxt_tok.copy_to_host_async()  # overlap with bookkeeping
+                per_tok = jax.device_get(nxt_tok)  # hot-sync-ok: the step's one sync — t_real int32s (the per-token verify lane), copy launched at dispatch
             else:
-                _, nxt = self.model.paged_ragged_step(
-                    self.cache, rows, pad_to_tokens=pad_t,
-                    pad_to_rows=pad_b,
-                    sampling=(temps, top_ks, top_ps, keys))
-                nxt.copy_to_host_async()  # overlap with the bookkeeping
-        except RuntimeError as e:
-            if _mobs.is_oom(e):
-                # allocator exhaustion mid-decode: dump mem_state.json
-                # forensics (the kv pool is usually the top holder)
-                # before the scheduler's crash path sees it
-                raise _mobs.oom_error(e, site="serve.ragged_step") from e
-            raise
-        self._sync_retraces()
-        now = time.perf_counter()
-        prefill_toks = sum(n for k, _, n in metas if k == "prefill")
-        _monitor.histogram("serve.batch_size").observe(b_real)
-        if prefill_toks:
-            _monitor.counter("serve.chunked_prefill_tokens").inc(
-                prefill_toks)
-        shared = self.cache.shared_page_count()
-        _monitor.gauge("serve.shared_pages").set(shared)
-        hits, self._step_prefix_hits = self._step_prefix_hits, 0
-        rec = {"engine": self.name, "requests": b_real,
-               "batch_size": b_real, "bucket_batch": int(pad_b),
-               "cache_strategy": self.cache_strategy,
-               "queue_depth": len(self._pending),
-               # pad SLOTS exist (pad_t - t_real) but carry bound 0: the
-               # kernel computes zero attention blocks for them, so the
-               # compute-bearing pad count — what serve.pad_tokens has
-               # always measured — is 0 by construction on this path,
-               # and the slot fraction is only the intra-page remainder
-               "pad_tokens": 0,
-               "pad_token_fraction": max(0.0, 1.0 - useful / computed)
-               if computed else 0.0,
-               "pad_slots": int(pad_t - t_real),
-               "prefix_hits": hits, "shared_pages": shared,
-               "chunked_prefill_tokens": prefill_toks,
-               "latency_s": sum(now - s.handle.t_submit
-                                for _, s, _ in metas) / b_real}
-        if spec_on:
-            per_tok = jax.device_get(nxt_tok)  # hot-sync-ok: the step's one sync — t_real int32s (the per-token verify lane), copy launched at dispatch
-        else:
-            toks = jax.device_get(nxt)  # hot-sync-ok: the step's one sync — b_real int32s, copy launched at dispatch
-        step_prop = step_acc = 0
-        i = off = 0
-        for kind, s, n in metas:
-            row0 = off
-            off += n
-            tok = int(per_tok[row0 + n - 1]) if spec_on else int(toks[i])
-            i += 1
-            if kind == "verify":
-                d = drafts[s.sid]
-                samples = [int(per_tok[row0 + j]) for j in range(n)]
-                m = accept_length(d, samples)
-                k_eff = n - 1
-                step_prop += k_eff
-                step_acc += m - 1
-                # roll back BOTH write cursors BEFORE emitting: an
-                # eos/max_new finish inside the emit loop frees the
-                # sequence, and the cursors must already sit at the
-                # accepted boundary when prefix registration walks the
-                # pages. Target wrote k_eff+1 tokens, m were real;
-                # the draft consumed k_eff-1 proposals, m-1 were real
-                # (a fully-accepted row needs no draft rollback — the
-                # bonus token leaves a 2-token catch-up lag instead).
-                with self.cache.lock:
-                    self.cache.rollback(s.sid, (k_eff + 1) - m)
-                if s.draft_sid is not None:
-                    over = max(k_eff - m, 0)
-                    if over:
-                        with self._draft_cache.lock:
-                            self._draft_cache.rollback(s.draft_sid, over)
-                        s.dlen -= over
-                if s.handle.trace is not None:
-                    s.handle.trace.note_speculation(k_eff, m - 1)
-                for t in samples[:m]:
-                    self._emit(s, int(t))
-                    if s not in self._active:
-                        break  # finished/cancelled mid-acceptance
-                continue
-            if kind == "decode":
+                toks = jax.device_get(nxt)  # hot-sync-ok: the step's one sync — b_real int32s, copy launched at dispatch
+        with _stat.span("serve.step.emit"):
+            step_prop = step_acc = 0
+            i = off = 0
+            for kind, s, n in metas:
+                row0 = off
+                off += n
+                tok = int(per_tok[row0 + n - 1]) if spec_on else int(toks[i])
+                i += 1
+                if kind == "verify":
+                    d = drafts[s.sid]
+                    samples = [int(per_tok[row0 + j]) for j in range(n)]
+                    m = accept_length(d, samples)
+                    k_eff = n - 1
+                    step_prop += k_eff
+                    step_acc += m - 1
+                    # roll back BOTH write cursors BEFORE emitting: an
+                    # eos/max_new finish inside the emit loop frees the
+                    # sequence, and the cursors must already sit at the
+                    # accepted boundary when prefix registration walks the
+                    # pages. Target wrote k_eff+1 tokens, m were real;
+                    # the draft consumed k_eff-1 proposals, m-1 were real
+                    # (a fully-accepted row needs no draft rollback — the
+                    # bonus token leaves a 2-token catch-up lag instead).
+                    with self.cache.lock:
+                        self.cache.rollback(s.sid, (k_eff + 1) - m)
+                    if s.draft_sid is not None:
+                        over = max(k_eff - m, 0)
+                        if over:
+                            with self._draft_cache.lock:
+                                self._draft_cache.rollback(s.draft_sid, over)
+                            s.dlen -= over
+                    if s.handle.trace is not None:
+                        s.handle.trace.note_speculation(k_eff, m - 1)
+                    for t in samples[:m]:
+                        self._emit(s, int(t))
+                        if s not in self._active:
+                            break  # finished/cancelled mid-acceptance
+                    continue
+                if kind == "decode":
+                    self._emit(s, tok)
+                    continue
+                s.filled += n
+                if s.filled < s.handle.prompt.size:
+                    continue  # mid-prompt chunk: sampled token is not real
+                # prompt complete: stream the first token, then either join
+                # the local decode batch or — prefill role — hand the chain
+                # to the decode engine (prefix registration waits for
+                # EVICTION either way: a still-generating sequence
+                # registering its partial tail page would copy-on-write its
+                # own next decode token, an extra page draw its admission
+                # reservation never counted)
+                self._prefilling.remove(s)  # lint-ok[unlocked-shared-state]: scheduler-thread-owned list; promote-to-active handoff stays on the one loop thread
+                _monitor.histogram("serve.ttft_s").observe(
+                    now - s.handle.t_submit)
+                if self._handoff_fn is not None:
+                    self._handoff_seq(s, tok)
+                    continue
+                self._active.append(s)  # lint-ok[unlocked-shared-state]: scheduler-thread-owned list; readers take GIL-atomic list() snapshots (load_report)
                 self._emit(s, tok)
-                continue
-            s.filled += n
-            if s.filled < s.handle.prompt.size:
-                continue  # mid-prompt chunk: sampled token is not real
-            # prompt complete: stream the first token, then either join
-            # the local decode batch or — prefill role — hand the chain
-            # to the decode engine (prefix registration waits for
-            # EVICTION either way: a still-generating sequence
-            # registering its partial tail page would copy-on-write its
-            # own next decode token, an extra page draw its admission
-            # reservation never counted)
-            self._prefilling.remove(s)  # lint-ok[unlocked-shared-state]: scheduler-thread-owned list; promote-to-active handoff stays on the one loop thread
-            _monitor.histogram("serve.ttft_s").observe(
-                now - s.handle.t_submit)
-            if self._handoff_fn is not None:
-                self._handoff_seq(s, tok)
-                continue
-            self._active.append(s)  # lint-ok[unlocked-shared-state]: scheduler-thread-owned list; readers take GIL-atomic list() snapshots (load_report)
-            self._emit(s, tok)
-        self._spec_proposed += step_prop  # lint-ok[unlocked-shared-state]: loop-thread-owned monotonic counters, same contract as _attn_computed
-        self._spec_accepted += step_acc  # lint-ok[unlocked-shared-state]: paired with _spec_proposed above
-        # the serve record is exported AFTER the verdict so it can
-        # carry this step's speculation outcome (zeros when off)
-        rec["proposed_tokens"] = int(step_prop)
-        rec["accepted_tokens"] = int(step_acc)
-        rec["accept_rate"] = (step_acc / step_prop) if step_prop else 0.0
-        _monitor.export_step(rec, kind="serve")
-        self._note_kv_step()
+            self._spec_proposed += step_prop  # lint-ok[unlocked-shared-state]: loop-thread-owned monotonic counters, same contract as _attn_computed
+            self._spec_accepted += step_acc  # lint-ok[unlocked-shared-state]: paired with _spec_proposed above
+        with _stat.span("serve.step.telemetry"):
+            # the serve record is exported AFTER the verdict so it can
+            # carry this step's speculation outcome (zeros when off)
+            rec["proposed_tokens"] = int(step_prop)
+            rec["accepted_tokens"] = int(step_acc)
+            rec["accept_rate"] = (step_acc / step_prop) if step_prop else 0.0
+            _monitor.export_step(rec, kind="serve")
+            self._note_kv_step()
 
     def _note_kv_step(self):
         """Per-step pool bookkeeping (loop thread, lint-fenced): track

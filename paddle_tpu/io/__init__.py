@@ -13,7 +13,6 @@ import math
 import os
 import queue
 import threading
-import time
 
 import numpy as np
 
@@ -421,13 +420,13 @@ class DataLoader:
             inner = device_prefetch_iterator(inner, self.prefetch_to_device,
                                              self._batch_sharding_fn)
         while True:
-            t0 = time.perf_counter()
+            _stat.begin_span("dataloader.next")
             try:
                 batch = next(inner)
             except StopIteration:
                 return
-            dt = time.perf_counter() - t0
-            _stat.record_span("dataloader.next", dt)
+            finally:
+                dt = _stat.end_span()
             _monitor.histogram("dataloader.wait_s").observe(dt)
             _monitor.counter("dataloader.batches").inc()
             yield batch

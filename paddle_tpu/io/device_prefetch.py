@@ -25,7 +25,6 @@ data-bound, not compute-bound).
 """
 import queue
 import threading
-import time
 
 import numpy as np
 import jax
@@ -109,10 +108,8 @@ class DevicePrefetchRing:
                     batch = next(it)
                 except StopIteration:
                     break
-                t0 = time.perf_counter()
-                staged = _stage(batch, self._sharding_fn)
-                _stat.record_span("prefetch.h2d",
-                                  time.perf_counter() - t0)
+                with _stat.span("prefetch.h2d"):
+                    staged = _stage(batch, self._sharding_fn)
                 # memory-observatory attribution: per-array weakrefs to
                 # the staged leaves — when the consumer drops the batch
                 # the tag's bytes fall to zero by themselves

@@ -13,6 +13,7 @@ forward runs under trace, with the tape disabled (jax.grad provides
 differentiation on this path).
 """
 import collections
+import contextlib
 import functools
 import threading
 import time
@@ -165,10 +166,11 @@ def device_probe_open(step_obj, step_i):
     if prev is None:
         return None  # first step: nothing to drain against; next cadence
     t_drain0 = time.perf_counter()
-    try:
-        jax.block_until_ready(prev)  # hot-sync-ok: cadence-gated device-time probe drain (PADDLE_TPU_DEVICE_TIME_EVERY; docs/OBSERVABILITY.md)
-    except (RuntimeError, TypeError):
-        return None
+    with _stat.span("train.step.probe"):
+        try:
+            jax.block_until_ready(prev)  # hot-sync-ok: cadence-gated device-time probe drain (PADDLE_TPU_DEVICE_TIME_EVERY; docs/OBSERVABILITY.md)
+        except (RuntimeError, TypeError):
+            return None
     t0 = time.perf_counter()
     # drain_s is the probe's ARTIFICIAL wait: export_step_metrics
     # subtracts it from the probed step's inter-dispatch interval so
@@ -187,14 +189,15 @@ def device_probe_close(step_obj, step_i, window, out_leaf, info,
     step_obj._probe_prev_out = out_leaf
     if window is None or compiled_now:
         return None
-    try:
-        jax.block_until_ready(out_leaf)  # hot-sync-ok: cadence-gated device-time probe window close (the ONE deliberate measured sync; lint-fenced)
-    except (RuntimeError, TypeError):
-        return None
-    t0, wait0, drain_s = window
-    return _dobs.record_device_time(step_obj, step_i,
-                                    time.perf_counter() - t0, info,
-                                    coll_wait0=wait0, drain_s=drain_s)
+    with _stat.span("train.step.probe"):
+        try:
+            jax.block_until_ready(out_leaf)  # hot-sync-ok: cadence-gated device-time probe window close (the ONE deliberate measured sync; lint-fenced)
+        except (RuntimeError, TypeError):
+            return None
+        t0, wait0, drain_s = window
+        return _dobs.record_device_time(step_obj, step_i,
+                                        time.perf_counter() - t0, info,
+                                        coll_wait0=wait0, drain_s=drain_s)
 
 
 def export_step_metrics(step, dispatch_s, info, compiled_now):
@@ -268,8 +271,7 @@ def export_step_metrics(step, dispatch_s, info, compiled_now):
     # traffic of the two update passes (ops/pallas/fused_update.py
     # bytes_per_step); epilogue_share relates it to the executable's
     # cost_analysis bytes (clamped — interpret-mode cost analysis counts
-    # kernel loop bodies once). The update.epilogue span attributes the
-    # same share of the step's wall time for the profiler summary.
+    # kernel loop bodies once).
     eb = int(getattr(step, "_epilogue_bytes", 0) or 0)
     if eb:
         total_b = float(info.get("bytes", 0.0))
@@ -277,8 +279,6 @@ def export_step_metrics(step, dispatch_s, info, compiled_now):
         rec["epilogue_bytes"] = eb
         rec["epilogue_share"] = float(share)
         _monitor.gauge("train.epilogue_share").set(float(share))
-        if steady:
-            _stat.record_span("update.epilogue", step_time * share)
     # measured device time (the sampled probe, dist_observatory): the
     # probe that closed on THIS step leaves its numbers here — the
     # step record carries measured time next to the cost-analysis MFU
@@ -494,6 +494,11 @@ class StaticFunction:
             jitted = self._compile(sig, arrays)
             _monitor.counter("jit.retraces").inc()
         key = split_key()
+        # jax.jit compiles lazily on a new program's first dispatch; the
+        # call is trace+compile (dispatch returns right after compile
+        # under async execution)
+        first_call = _stat.span("jit.compile") if new_program \
+            else contextlib.nullcontext()
         if self._is_layer:
             named = list(self._obj.named_parameters())
             buffers = {k: b.value for k, b in self._obj.named_buffers()}
@@ -515,16 +520,11 @@ class StaticFunction:
                                for a in args]
                 return apply_op(fn, *[p for _, p in named], *tensor_args)
             params = {k: p.value for k, p in named}
-            t0 = time.perf_counter()
-            out = jitted(params, buffers, key, *arrays)
+            with first_call:
+                out = jitted(params, buffers, key, *arrays)
         else:
-            t0 = time.perf_counter()
-            out = jitted(key, *arrays)
-        if new_program:
-            # jax.jit compiles lazily on this first dispatch; the elapsed
-            # time is trace+compile (dispatch returns right after compile
-            # under async execution)
-            _stat.record_span("jit.compile", time.perf_counter() - t0)
+            with first_call:
+                out = jitted(key, *arrays)
         return jax.tree.map(Tensor, out)
 
     def __get__(self, instance, owner=None):
@@ -1037,13 +1037,18 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                 aux["nonfinite"] = nonfin
         return loss, new_params, new_state, new_scaler_state, aux
 
-    def _dispatch(self, cache, sig, make_jitted, args, span,
-                  max_entries=None, static=None, arg_names=None):
+    def _dispatch(self, cache, sig, make_jitted, args, tag,
+                  max_entries=None, static=None, arg_names=None,
+                  span=None):
         """The ONE dispatch path every TrainStep program flavor
         (per-step / scanned steps / scanned accumulation) goes through:
         executable-cache lookup with optional LRU bound, AOT compile on
         miss, retrace accounting, timed dispatch. `static`/`arg_names`
-        feed the compilation observatory's signature + forensics.
+        feed the compilation observatory's signature + forensics. `tag`
+        names the executable (compile ledger, OOM site, NaN bundle);
+        the host span is `span`, or `tag` where none is given (a joined
+        or inline compile nests under it as jit.trace_lower /
+        jit.compile).
 
         A miss goes through the warm pipeline's single-flight table
         (jit/warm.py): if `warm()`/`warm_run_steps()`/`warm_accumulate()`
@@ -1053,7 +1058,7 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
         ledger record. Returns (outputs, info, compiled_now,
         dispatch_s)."""
         _flight.heartbeat(self._step_i)  # watchdog liveness pulse
-        _stat.begin_span(span)
+        _stat.begin_span(span or tag)
         try:
             entry = cache.get(sig)
             compiled_now = entry is None
@@ -1065,7 +1070,7 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                 # behind unrelated background warms; if a warm already
                 # has this executable in flight, join it instead
                 entry = self._warm_submit(
-                    cache, sig, make_jitted, span, args, static=static,
+                    cache, sig, make_jitted, tag, args, static=static,
                     arg_names=arg_names, inline=True).result()
             else:  # LRU: re-insert so cycling signatures don't thrash
                 cache[sig] = cache.pop(sig)
@@ -1096,7 +1101,7 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                 if _mobs.is_oom(e):
                     # allocator exhaustion: dump mem_state.json forensics
                     # and re-raise naming the top holders
-                    raise _mobs.oom_error(e, site=span) from e
+                    raise _mobs.oom_error(e, site=tag) from e
                 # jax_debug_nans (framework.debug.enable_jit_nan_checks)
                 # found a non-finite value: flight-record it and write a
                 # debug bundle (ring tail + this executable's HLO +
@@ -1111,7 +1116,7 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                     and "deleted" in str(e))
                 if isinstance(e, RuntimeError) and not donated_rerun:
                     raise
-                _flight.record_event("nan_detected", where=span,
+                _flight.record_event("nan_detected", where=tag,
                                      step=int(self._step_i),
                                      error=str(e)[:300])
                 _flight.dump("nan", exc=e)
@@ -1122,11 +1127,11 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                     # FloatingPointError it is
                     raise FloatingPointError(
                         "jax_debug_nans detected a non-finite value in "
-                        f"the compiled {span} program: {e}") from e
+                        f"the compiled {tag} program: {e}") from e
                 if donated_rerun:
                     raise FloatingPointError(
                         "jax_debug_nans detected a non-finite value in "
-                        f"the compiled {span} program (the op-level "
+                        f"the compiled {tag} program (the op-level "
                         "re-run could not localize it because the step "
                         "donates its buffers; build with donate=False "
                         "to localize)") from e
@@ -1398,27 +1403,38 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                                  arg_names=_step_arg_names(len(arrays)))
 
     def __call__(self, *batch):
+        """One optimizer step. On the host the call is one `train.step`
+        span whose children cover it: `train.step.prep`,
+        `train.step.probe` (only on a step the device-time probe is
+        due), `train.step.dispatch` and `train.step.telemetry`."""
         self._step_i += 1
-        if _fault.active():  # fault drills only; two dict reads when off
-            batch = fire_step_faults(self, batch)
-        sig, args = self._prep(batch, self._step_i)
-        probe = device_probe_open(self, self._step_i)
-        out, info, compiled_now, dispatch_s = self._dispatch(
-            self._exec, sig, lambda: self._jitted, args, "train.step",
-            arg_names=_step_arg_names(len(batch)))
-        if self.monitor_health:
-            loss, health, self._params_store, self._opt_store, \
-                self.scaler_state = out
-            self._queue_health(self._step_i, health)
-        else:
-            loss, self._params_store, self._opt_store, \
-                self.scaler_state = out
-        device_probe_close(self, self._step_i, probe, loss, info,
-                           compiled_now=compiled_now)
-        export_step_metrics(self, dispatch_s, info, compiled_now)
-        # non-blocking handle: dispatch has already returned; the host
-        # copy streams in the background and resolves on first read
-        return DeferredLoss(loss)
+        with _stat.span("train.step", step_num=self._step_i):
+            with _stat.span("train.step.prep"):
+                if _fault.active():  # fault drills only; two dict reads when off
+                    batch = fire_step_faults(self, batch)
+                sig, args = self._prep(batch, self._step_i)
+            probe = device_probe_open(self, self._step_i)
+            out, info, compiled_now, dispatch_s = self._dispatch(
+                self._exec, sig, lambda: self._jitted, args, "train.step",
+                arg_names=_step_arg_names(len(batch)),
+                span="train.step.dispatch")
+            health = None
+            if self.monitor_health:
+                loss, health, self._params_store, self._opt_store, \
+                    self.scaler_state = out
+            else:
+                loss, self._params_store, self._opt_store, \
+                    self.scaler_state = out
+            device_probe_close(self, self._step_i, probe, loss, info,
+                               compiled_now=compiled_now)
+            with _stat.span("train.step.telemetry"):
+                if health is not None:
+                    self._queue_health(self._step_i, health)
+                export_step_metrics(self, dispatch_s, info, compiled_now)
+                # non-blocking handle: dispatch has already returned; the
+                # host copy streams in the background and resolves on
+                # first read
+                return DeferredLoss(loss)
 
     def cost_analysis(self, *batch):
         """XLA's analytical cost report for THIS batch signature's

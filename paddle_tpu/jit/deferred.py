@@ -20,7 +20,6 @@ The hapi fit loop holds these handles unresolved until a `log_freq`
 boundary or epoch end; `tools/check_no_hot_sync.py` lints the hot paths
 so a blocking read can't sneak back in.
 """
-import time
 
 import numpy as np
 
@@ -48,10 +47,11 @@ class DeferredLoss(Tensor):
 
     def numpy(self):
         if self._resolved is None:
-            t0 = time.perf_counter()
-            out = np.asarray(self.value)
-            dt = time.perf_counter() - t0
-            _stat.record_span("host.block", dt)
+            _stat.begin_span("host.block")
+            try:
+                out = np.asarray(self.value)
+            finally:
+                dt = _stat.end_span()
             _monitor.histogram("host.blocked_s").observe(dt)
             self._resolved = out
         return self._resolved

@@ -240,24 +240,23 @@ class Profiler:
 
 
 class RecordEvent:
-    """Named region: annotates the device trace
-    (jax.profiler.TraceAnnotation) AND records a nested host span into
-    the in-process statistics store, so `Profiler.summary()` can render
-    real aggregated tables without a trace viewer."""
+    """Named region: one span of the in-process statistics store
+    (statistic.begin_span / end_span), which annotates the device trace
+    (jax.profiler.TraceAnnotation) AND records the nested host span, so
+    `Profiler.summary()` can render real aggregated tables without a
+    trace viewer."""
 
     def __init__(self, name, event_type=None):
         self.name = name
-        self._ctx = None
+        self._open = False
 
     def begin(self):
         statistic.begin_span(self.name)
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
-        self._ctx.__enter__()
+        self._open = True
 
     def end(self):
-        if self._ctx is not None:
-            self._ctx.__exit__(None, None, None)
-            self._ctx = None
+        if self._open:
+            self._open = False
             statistic.end_span()
 
     def __enter__(self):

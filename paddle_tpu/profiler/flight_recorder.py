@@ -63,8 +63,8 @@ import time
 import traceback
 import weakref
 
-__all__ = ["record_span_event", "record_sample", "record_record",
-           "record_event", "register_executable",
+__all__ = ["record_span_event", "span_events", "record_sample",
+           "record_record", "record_event", "register_executable",
            "register_state_provider", "heartbeat",
            "snapshot", "reset", "dump", "install", "auto_install",
            "Watchdog", "perf_to_wall"]
@@ -113,6 +113,12 @@ def record_span_event(name, t0_perf, dur_s, thread_ident, depth=0):
     already-measured duration is recorded). t0_perf is the span's start
     on the perf_counter clock."""
     _spans.append((name, t0_perf, dur_s, thread_ident, depth))
+
+
+def span_events():
+    """The span ring, oldest first: (name, t0_perf, dur_s, thread_ident,
+    depth) of each closed span it still holds."""
+    return list(_spans)
 
 
 def record_sample(name, kind, value):
@@ -217,7 +223,7 @@ def snapshot():
     """The rings as plain JSON-serializable dicts (spans carry wall ts)."""
     spans = [{"name": n, "ts": perf_to_wall(t0), "dur_s": d,
               "tid": tid, "depth": depth}
-             for (n, t0, d, tid, depth) in list(_spans)]
+             for (n, t0, d, tid, depth) in span_events()]
     samples = [{"ts": ts, "name": n, "kind": k, "value": v}
                for (ts, n, k, v) in list(_samples)]
     return {"spans": spans, "samples": samples,

@@ -6,8 +6,12 @@ nested per-name tables; here `RecordEvent` (and every instrumented
 framework hot path — jit compile, train step, DataLoader, collectives,
 memory queries) reports into this module's in-process recorder, and
 `Profiler.summary()` renders the aggregated table. The device-side story
-stays with jax.profiler (XLA op timelines in TensorBoard/Perfetto); this
-module is the always-on, zero-dependency host view.
+stays with jax.profiler (XLA op timelines in TensorBoard/Perfetto), and
+every span opened here is also a `jax.profiler.TraceAnnotation`: in any
+jax.profiler trace it appears in the `/host:CPU` plane, on the thread
+that did the work and on the clock the device ops are on, so an idle
+gap of the device can be put down to the span that covers it. With no
+profiler session the annotation is jax's own no-op.
 
 Spans nest: a span that begins while another is open on the same thread
 becomes its child, and the summary table indents children under their
@@ -22,8 +26,8 @@ import time
 from . import flight_recorder
 
 __all__ = ["SpanNode", "span", "begin_span", "end_span", "record_span",
-           "reset_statistics", "snapshot", "summary_table", "get_events",
-           "SortedKeys"]
+           "closed_spans", "reset_statistics", "snapshot", "summary_table",
+           "get_events", "SortedKeys"]
 
 
 class SortedKeys:
@@ -86,9 +90,16 @@ def _stack():
     return st
 
 
-def begin_span(name):
-    """Open a span on this thread; nested begins become children."""
-    _stack().append((name, time.perf_counter()))
+def begin_span(name, **args):
+    """Open a span on this thread; nested begins become children. The
+    span is also entered as a jax.profiler.TraceAnnotation carrying
+    `args` (a StepTraceAnnotation where `step_num` is among them), held
+    on the thread's stack until end_span leaves it."""
+    import jax  # on first use, not when this module is imported
+    ann = (jax.profiler.StepTraceAnnotation if "step_num" in args
+           else jax.profiler.TraceAnnotation)(name, **args)
+    ann.__enter__()
+    _stack().append((name, time.perf_counter(), ann))
 
 
 def end_span():
@@ -96,19 +107,32 @@ def end_span():
     st = _stack()
     if not st:
         return 0.0
-    name, t0 = st.pop()
+    name, t0, ann = st.pop()
     dt = time.perf_counter() - t0
-    _record(name, dt, [n for n, _ in st], t0)
+    ann.__exit__(None, None, None)
+    _record(name, dt, [e[0] for e in st], t0)
     return dt
 
 
 def record_span(name, seconds):
     """Record an already-measured duration as a span nested under this
-    thread's currently-open spans (used by instrumentation that times a
-    region itself, e.g. the DataLoader batch wait)."""
+    thread's currently-open spans. Handed over after the fact, it cannot
+    be an annotation in a profiler trace: code that times a real region
+    uses `span` instead."""
     seconds = float(seconds)
-    _record(name, seconds, [n for n, _ in _stack()],
+    _record(name, seconds, [e[0] for e in _stack()],
             time.perf_counter() - seconds)
+
+
+def closed_spans():
+    """The recorder's tail of closed spans, in the order they closed:
+    dicts of name, start_s (time.perf_counter clock), dur_s, thread
+    (ident) and depth (open spans above it on its thread). A parent
+    follows its children; the ring holds the newest
+    flight_recorder.SPAN_RING."""
+    return [{"name": n, "start_s": t0, "dur_s": d, "thread": tid,
+             "depth": depth}
+            for n, t0, d, tid, depth in flight_recorder.span_events()]
 
 
 def _record(name, seconds, parent_names, t0=None):
@@ -129,11 +153,12 @@ def _record(name, seconds, parent_names, t0=None):
 class span:
     """Context manager: `with statistic.span("phase"): ...`"""
 
-    def __init__(self, name):
+    def __init__(self, name, **args):
         self.name = name
+        self.args = args
 
     def __enter__(self):
-        begin_span(self.name)
+        begin_span(self.name, **self.args)
         return self
 
     def __exit__(self, *exc):

@@ -196,7 +196,8 @@ def test_summary_contains_framework_spans():
     prof.step()
     prof.stop()
     text = prof.summary()
-    for span_name in ("train.step", "jit.trace_lower", "jit.compile",
+    for span_name in ("train.step", "train.step.dispatch",
+                      "jit.trace_lower", "jit.compile",
                       "dataloader.next", "collective.all_reduce",
                       "device.memory"):
         assert span_name in text, f"summary missing {span_name}:\n{text}"

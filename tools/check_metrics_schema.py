@@ -28,9 +28,7 @@ Schema (documented in docs/OBSERVABILITY.md):
                                        two fused update passes
                   epilogue_share number in [0, 1] — epilogue_bytes over
                                        the executable's cost_analysis
-                                       bytes (the `update.epilogue` span
-                                       attributes the same share of the
-                                       step's wall time)
+                                       bytes
   kind == "serve" (one record per dispatched serving batch —
                   paddle_tpu/inference/serving.py) additionally requires:
                   engine       str     emitting engine's name (non-empty;
@@ -56,6 +54,8 @@ Schema (documented in docs/OBSERVABILITY.md):
                                        the intra-page remainder; the
                                        pad_tokens COUNTER is what the
                                        ragged path zeroes)
+                  tokens, bucket_tokens int  >= 0 tokens of a ragged
+                                       step, and the bucket they padded to
                   prefix_hits  int     >= 0 prompt tokens served from the
                                        refcounted prefix cache
                   shared_pages int     >= 0 KV pages with > 1 holder
@@ -866,7 +866,7 @@ def validate_line(line, where="<line>"):
                 "the rows it padded")
         # ragged-serving fields (optional, typed+ranged when present)
         for key in ("prefix_hits", "shared_pages",
-                    "chunked_prefill_tokens"):
+                    "chunked_prefill_tokens", "tokens", "bucket_tokens"):
             if key in rec:
                 v = rec[key]
                 if not isinstance(v, int) or isinstance(v, bool) \

@@ -50,7 +50,8 @@ HOT_REGIONS = {
     "paddle_tpu/hapi/model.py": [
         "Model.fit", "Model._fit_epochs", "Model._dispatch_micro"],
     "paddle_tpu/distributed/fleet/hybrid_train.py": [
-        "HybridTrainStep.__call__", "HybridTrainStep._prep"],
+        "HybridTrainStep.__call__", "HybridTrainStep._prep",
+        "HybridTrainStep._dispatch"],
     # the async checkpoint enqueue path: save() snapshots on device and
     # hands off to the writer thread — any host<->device sync here
     # would put checkpointing back on the step loop's critical path.
