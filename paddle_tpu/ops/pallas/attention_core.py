@@ -9,7 +9,10 @@ same blocking with different gather patterns):
   of mixed prefill+decode tokens (heads folded into the row dimension
   for grouped-query models) against double-buffered kv pages;
 - ops/pallas/flash_attention.py — the fused TRAINING kernel: q-blocks
-  of one sequence's tokens against contiguous kv blocks, custom VJP.
+  of one sequence's tokens against contiguous kv blocks, custom VJP;
+  grid blocks in VMEM, taken in strips that compute only what the
+  causal triangle leaves visible (choose_flash_blocks, causal_kv_tiles
+  below).
 
 The policy both enforce: every score dot is [M, D] x [D, Bk] with
 M >= MIN_DOT_ROWS (the f32 sublane tile — anything narrower leaves the
@@ -23,6 +26,7 @@ Both kernels run the SAME code in Pallas interpret mode on CPU (tier-1)
 — `default_interpret` is the one switch.
 """
 import math
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -70,18 +74,55 @@ def choose_ragged_q_block(n_tokens, fold=1):
     return bq
 
 
+class FlashBlocks(typing.NamedTuple):
+    """What choose_flash_blocks returns: the grid block the pipeline
+    moves between HBM and VMEM, and per kernel (tq, tk): the strip its
+    body takes at a time (tq rows in fwd and dq, tk columns in dkv) and
+    the step of the visible extent along the other axis."""
+    block_q: int
+    block_k: int
+    fwd: tuple
+    dq: tuple
+    dkv: tuple
+
+
+# per kernel, the strip a body takes at a time (q rows, kv columns): the
+# best of 128 / 256 / 512 by device time of the bare kernels on the v5e at
+# [128, 1024, 64] and [32, 2048, 128] bf16 (PERF.md section 6, PR 26;
+# tools/sweep_flash_tiles.py). The backward kernels are bound by the MXU,
+# so the finest strip, which skips most, wins; the forward pays a softmax
+# row statistic per strip
+SUB_TILE_CAPS = {"fwd": (256, 256), "dq": (128, 128), "dkv": (128, 128)}
+
+
+def sub_tile(block, cap):
+    """Rows (or columns) of a sub-tile inside a grid block of `block`:
+    the widest multiple of MXU_ROWS at most `cap` that divides it — a
+    slice the chip's tiling takes at any offset — else the whole block
+    as ONE tile (short or odd lengths: tier-1's T = 32, T = 1000)."""
+    t = cap - cap % MXU_ROWS
+    while t >= MXU_ROWS:
+        if block % t == 0:
+            return t
+        t -= MXU_ROWS
+    return block
+
+
 def choose_flash_blocks(t_q, t_k, d):
-    """(block_q, block_k) for the training kernel. Biggest blocks win
-    decisively on real TPU (measured on [128, 1024, 64] bf16: 1024x1024
-    runs fwd 1.9x / fwd+bwd 1.5x faster than 512x512; small bk is the
-    worst axis to shrink). 1024x1024 puts the f32 [bq, bk] score+prob
-    tiles at ~8 MB of VMEM — about the ceiling once q/k/v/do/acc tiles
-    are added, so the cap is the VMEM budget; round down to divisors of
-    the seq lens. The dkv backward holds ~3 concurrent f32 [bq, bk]
-    tiles plus q/k/v/do tiles that scale with d — shrink bk for head
-    dims > 64 to stay inside the same budget the d=64 measurement
-    validated. bk seeds at a power of two so the halving loop lands on
-    a divisor of a power-of-two t_k instead of collapsing to 1."""
+    """Two-level blocking of the training kernel, a function of shapes
+    alone. GRID block (block_q, block_k): what one grid step holds in
+    VMEM — up to 1024 x 1024, so a step pays its DMA, init and finalize
+    once per 1024 rows; halved down to divisors of the sequence lengths.
+    block_k halves per doubling of the head dim beyond 64, as the k, v
+    blocks and their f32 copies grow with d. bk seeds at a power of two
+    so the halving lands on a divisor of a power-of-two t_k instead of
+    collapsing to 1. SUB-TILE (tq, tk) per kernel: the forward and dq
+    bodies take the q block in strips of tq rows, dkv takes the kv block
+    in strips of tk columns; in a square block on the causal diagonal a
+    strip computes only the part of the other axis that the triangle
+    leaves visible, in steps of the other number (causal_kv_tiles /
+    causal_q_tiles below) — so the f32 score tile is [tq, <= block_k],
+    and about half the square is never computed."""
     bq = min(1024, t_q)
     while t_q % bq:
         bq //= 2
@@ -90,7 +131,51 @@ def choose_flash_blocks(t_q, t_k, d):
     bk = min(seed, t_k)
     while t_k % bk:
         bk //= 2
-    return max(bq, 1), max(bk, 1)
+    bq, bk = max(bq, 1), max(bk, 1)
+    tiles = {name: (sub_tile(bq, cq), sub_tile(bk, ck))
+             for name, (cq, ck) in SUB_TILE_CAPS.items()}
+    return FlashBlocks(bq, bk, **tiles)
+
+
+def _tiles_in(x, t, n, up=False):
+    """How many whole tiles of width t fit in x (`up`: are touched by
+    x), held to [0, n]."""
+    return min(max(x + (t - 1) * up, 0), n * t) // t
+
+
+def causal_kv_tiles(row0, tq, tk, n):
+    """(n_full, n_visit) for a strip of query rows [row0, row0 + tq)
+    against the n kv tiles [j*tk, (j+1)*tk), Python ints, under the
+    top-left-aligned causal mask (row >= column): tiles j < n_full hold
+    no masked element, tiles n_full <= j < n_visit are crossed by the
+    diagonal, the rest are wholly masked. The forward and dq kernels
+    compute a strip against tiles [0, n_visit) and mask
+    [n_full, n_visit): these ARE their extents."""
+    return _tiles_in(row0 + 1, tk, n), _tiles_in(row0 + tq, tk, n, up=True)
+
+
+def causal_q_tiles(col0, tk, tq, n):
+    """(first, first_full) for a strip of kv columns [col0, col0 + tk)
+    against the n q tiles [i*tq, (i+1)*tq): tiles i < first are wholly
+    masked, first <= i < first_full are crossed by the diagonal, the
+    rest hold no masked element — the dkv kernel's extents, the
+    transpose of causal_kv_tiles."""
+    return _tiles_in(col0, tq, n), _tiles_in(col0 + tk - 1, tq, n, up=True)
+
+
+def visited_tile_share(t_q, t_k, tiles, causal):
+    """Share of the [t_q, t_k] score matrix's (tq, tk) sub-tiles that
+    the kernels compute: 1.0 without a mask; under the causal mask what
+    causal_kv_tiles visits — (n + 1) / (2n) for n square tiles a side.
+    Where the grid blocks are not square the kernels skip by the block,
+    and `tiles` is the grid block."""
+    tq, tk = tiles
+    nq, nk = t_q // tq, t_k // tk
+    if not causal:
+        return 1.0
+    visited = sum(causal_kv_tiles(i * tq, tq, tk, nk)[1]
+                  for i in range(nq))
+    return visited / float(nq * nk)
 
 
 def default_interpret(interpret):
@@ -106,46 +191,57 @@ def default_scale(scale, head_dim):
     return 1.0 / math.sqrt(head_dim) if scale is None else float(scale)
 
 
-def softmax_carry(m_rows, d, dtype=jnp.float32):
+def softmax_carry(m_rows, d, dtype=jnp.float32, column=False):
     """Fresh (m, l, acc) accumulators for one q-block: running max,
-    running sum, unnormalized output — f32 regardless of input dtype."""
-    return (jnp.full((m_rows,), NEG_INF, dtype),
-            jnp.zeros((m_rows,), dtype),
+    running sum, unnormalized output — f32 regardless of input dtype.
+    `column`: m and l as [M, 1] (see _col), not [M]."""
+    stat = (m_rows, 1) if column else (m_rows,)
+    return (jnp.full(stat, NEG_INF, dtype), jnp.zeros(stat, dtype),
             jnp.zeros((m_rows, d), dtype))
+
+
+def _col(x):
+    """Row statistics against an [M, ...] tile: the serving kernel keeps
+    them [M], the training kernel [M, 1] (the score tile's own layout,
+    which costs no relayout against the tile)."""
+    return x if x.ndim == 2 else x[:, None]
 
 
 def softmax_update(m, l, acc, s, v, valid=None):
     """ONE online-softmax block update, shared by both kernels.
 
-    m [M] running max, l [M] running sum, acc [M, D] unnormalized
-    accumulator; s [M, Bk] this block's raw scores (pre-mask); v
-    [Bk, D] values. `valid` [M, Bk] masks scores out entirely — and,
-    unlike plain NEG_INF substitution, zeroes p explicitly, so a row
-    with NO valid column in this block (a ragged q-block row whose
-    sequence doesn't own the kv page, a causal row above the block
-    diagonal) contributes exactly nothing: m stays, alpha = 1, l and
-    acc unchanged. NEG_INF is finite (-1e30), so exp never produces
-    NaN even for rows nothing has touched yet."""
+    m running max, l running sum (both [M] or both [M, 1]), acc [M, D]
+    unnormalized accumulator; s [M, Bk] this block's raw scores
+    (pre-mask); v [Bk, D] values. `valid` [M, Bk] masks scores out
+    entirely — and, unlike plain NEG_INF substitution, zeroes p
+    explicitly, so a row with NO valid column in this block (a ragged
+    q-block row whose sequence doesn't own the kv page, a causal row
+    above the block diagonal) contributes exactly nothing: m stays,
+    alpha = 1, l and acc unchanged. NEG_INF is finite (-1e30), so exp
+    never produces NaN even for rows nothing has touched yet.
+    valid=None emits no iota, compare or select at all."""
+    keep = m.ndim == 2
     if valid is not None:
         s = jnp.where(valid, s, jnp.float32(NEG_INF))
-    m_new = jnp.maximum(m, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=keep))
+    p = jnp.exp(s - _col(m_new))
     if valid is not None:
         p = jnp.where(valid, p, jnp.float32(0.0))
     alpha = jnp.exp(m - m_new)
-    l_new = l * alpha + jnp.sum(p, axis=1)
-    acc_new = acc * alpha[:, None] + jax.lax.dot_general(
+    l_new = l * alpha + jnp.sum(p, axis=1, keepdims=keep)
+    acc_new = acc * _col(alpha) + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     return m_new, l_new, acc_new
 
 
 def softmax_finalize(m, l, acc):
-    """(out [M, D], lse [M]) from the final carry. A row no block ever
-    touched (bound-0 pad token) divides 0 by the floor and comes out
-    exactly zero — garbage by construction, sliced off by the caller."""
+    """(out [M, D], lse shaped as m) from the final carry. A row no
+    block ever touched (bound-0 pad token) divides 0 by the floor and
+    comes out exactly zero — garbage by construction, sliced off by the
+    caller."""
     l_safe = jnp.maximum(l, jnp.float32(1e-30))
-    return acc / l_safe[:, None], m + jnp.log(l_safe)
+    return acc / _col(l_safe), m + jnp.log(l_safe)
 
 
 def score_dot(q, k, scale):
@@ -157,11 +253,12 @@ def score_dot(q, k, scale):
     return s * jnp.float32(scale)
 
 
-def causal_valid(iq, ik, block_q, block_k):
-    """[block_q, block_k] bool: query row >= kv column (absolute
-    positions from the block indices) — the training kernel's mask."""
-    rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return rows >= cols
+def causal_valid(row0, col0, shape, row_axis=0):
+    """`shape` bool tile of the causal mask, query row >= kv column,
+    for a tile whose first row and column stand at absolute positions
+    row0, col0; rows run along `row_axis` (1 for the dkv kernel's
+    transposed [columns, rows] tiles). The iota difference is the same
+    for every tile of a shape, so a tile costs one compare."""
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, row_axis)
+             - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - row_axis))
+    return ahead >= col0 - row0

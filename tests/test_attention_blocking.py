@@ -165,54 +165,231 @@ class TestBlockedKernelEquality:
         assert core.choose_q_block(1) == 1      # eager single token
 
 
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (list, tuple)) else (v,)):
+            x = getattr(x, "jaxpr", x)          # ClosedJaxpr -> Jaxpr
+            if hasattr(x, "eqns"):
+                yield x
+
+
+def _count(jaxpr, name):
+    """How often primitive `name` stands under `jaxpr`, nested calls
+    (jnp.where is one) included."""
+    return sum((eqn.primitive.name == name)
+               + sum(_count(sub, name) for sub in _sub_jaxprs(eqn))
+               for eqn in jaxpr.eqns)
+
+
+def _kernels(jaxpr):
+    """{kernel name: its body's jaxpr} of every pallas_call below."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = eqn.params["jaxpr"]
+        else:
+            for sub in _sub_jaxprs(eqn):
+                found.update(_kernels(sub))
+    return found
+
+
 class TestFlashKernel:
     def _ref(self, q, k, v, causal):
-        B, T, H, D = q.shape
+        D = q.shape[-1]
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
         if causal:
-            mask = np.tril(np.ones((T, T), bool))
+            mask = np.tril(np.ones(s.shape[-2:], bool))
             s = jnp.where(mask[None, None], s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
-    @pytest.mark.parametrize("causal", [False, True])
-    def test_forward_and_grad_match_einsum(self, causal):
+    def _check(self, B, Tq, Tk, H, D, causal, dtype):
         from paddle_tpu.ops.pallas.flash_attention import \
             flash_attention_arrays
         rng = np.random.default_rng(0)
-        B, T, H, D = 2, 32, 2, 8
-        q, k, v = (jnp.asarray(
-            rng.standard_normal((B, T, H, D)).astype(np.float32))
-            for _ in range(3))
-
-        def loss_flash(q, k, v):
-            out = flash_attention_arrays(q, k, v, causal=causal,
-                                         interpret=True)
-            return jnp.sum(out * jnp.cos(out))
-
-        def loss_ref(q, k, v):
-            out = self._ref(q, k, v, causal)
-            return jnp.sum(out * jnp.cos(out))
-
+        q, k, v = (jnp.asarray(rng.standard_normal((B, T, H, D)), dtype)
+                   for T in (Tq, Tk, Tk))
+        flash = lambda q, k, v: flash_attention_arrays(
+            q, k, v, causal=causal, interpret=True).astype(jnp.float32)
+        ref = lambda q, k, v: self._ref(q, k, v, causal)
+        loss = lambda f: (lambda q, k, v: jnp.sum(
+            f(q, k, v) * jnp.cos(f(q, k, v))))
+        # a bf16-sized tolerance: four roundings (2^-8) of the largest value
+        f32 = dtype == jnp.float32
+        out = ref(q, k, v)
         np.testing.assert_allclose(
-            np.asarray(flash_attention_arrays(q, k, v, causal=causal,
-                                              interpret=True)),
-            np.asarray(self._ref(q, k, v, causal)), atol=2e-5)
-        g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+            np.asarray(flash(q, k, v)), np.asarray(out),
+            atol=2e-5 if f32 else 2.0 ** -6 * float(jnp.abs(out).max()))
+        g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
         for name, a, b in zip("qkv", g_flash, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=3e-4, err_msg=f"d{name}")
+            assert a.dtype == dtype
+            np.testing.assert_allclose(
+                np.asarray(a.astype(jnp.float32)), np.asarray(b),
+                atol=3e-4 if f32 else 2.0 ** -6 * float(jnp.abs(b).max()),
+                err_msg=f"d{name}")
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("T", [32, 512, 2048],
+                             ids=["one_tile", "sub_tiles", "grid_blocks"])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_forward_and_grad_match_einsum(self, causal, T, dtype):
+        b = core.choose_flash_blocks(T, T, 8)
+        strips = max(b.block_q // b.fwd[0], b.block_q // b.dq[0],
+                     b.block_k // b.dkv[1])
+        assert T // b.block_q == {32: 1, 512: 1, 2048: 2}[T]
+        assert (strips > 1) == (T > 32)
+        self._check(2 if T == 32 else 1, T, T, 2 if T == 32 else 1, 8,
+                    causal, dtype)
+
+    @pytest.mark.parametrize("Tq,Tk,D,causal", [
+        (256, 512, 8, False),    # Tq != Tk: a strip sees two kv tiles
+        (512, 256, 8, False),
+        (384, 384, 8, True),     # 3 x 128: a strip count that is odd
+        (96, 96, 8, True),       # no multiple of 128 divides it: one tile
+        (2048, 2048, 256, True),  # head dim 256: 1024 x 256 grid blocks,
+                                  # the crossed ones whole under the mask
+        (64, 1024, 8, True),     # one q block of 64 rows; most columns
+                                 # see no row and get dk = dv = 0
+        (512, 1024, 8, True),    # the same inside ONE 512 x 1024 block
+        (1024, 2048, 8, True),   # a kv GRID block wholly above the
+                                 # diagonal, with one q block: its
+                                 # dk, dv are zeros all the same
+        (2048, 1024, 8, True),   # q blocks wholly below the last kv block
+    ])
+    def test_unequal_and_odd_lengths(self, Tq, Tk, D, causal):
+        dtype = jnp.bfloat16 if D == 256 else jnp.float32
+        self._check(1, Tq, Tk, 1, D, causal, dtype)
+
+    @pytest.mark.parametrize("t_q,t_k,tiles", [
+        (1024, 1024, (256, 256)), (1024, 1024, (128, 128)),
+        (1024, 1024, (256, 512)), (1024, 1024, (512, 128)),
+        (2048, 2048, (256, 256)), (512, 1024, (128, 256)),
+        (1024, 512, (256, 128)), (96, 96, (96, 96)),
+    ])
+    def test_visited_share_is_the_brute_force_count(self, t_q, t_k, tiles):
+        """The extents the kernels run ARE causal_kv_tiles /
+        causal_q_tiles: hold both to a count over the mask itself, tile
+        by tile."""
+        tq, tk = tiles
+        nq, nk = t_q // tq, t_k // tk
+        keep = np.tril(np.ones((t_q, t_k), bool))
+        kinds = np.zeros((nq, nk), int)                 # 0 / 1 / 2
+        for i in range(nq):
+            for j in range(nk):
+                t = keep[i * tq:(i + 1) * tq, j * tk:(j + 1) * tk]
+                kinds[i, j] = 2 if t.all() else int(t.any())
+        assert core.visited_tile_share(t_q, t_k, tiles, True) == \
+            (kinds > 0).mean()
+        assert core.visited_tile_share(t_q, t_k, tiles, False) == 1.0
+        for i in range(nq):
+            full, visit = core.causal_kv_tiles(i * tq, tq, tk, nk)
+            assert list(kinds[i]) == ([2] * full + [1] * (visit - full)
+                                      + [0] * (nk - visit))
+        for j in range(nk):
+            first, first_full = core.causal_q_tiles(j * tk, tk, tq, nq)
+            assert list(kinds[:, j]) == ([0] * first
+                                         + [1] * (first_full - first)
+                                         + [2] * (nq - first_full))
+        if tq == tk and t_q == t_k:
+            assert (kinds > 0).mean() == (nq + 1) / (2 * nq)
+
+    @pytest.mark.parametrize("T,D", [(1024, 8), (2048, 128)],
+                             ids=["square_blocks", "d128_blocks"])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_only_diagonal_tiles_are_masked(self, causal, T, D):
+        """What each kernel lowers to is what the bounds functions say:
+        per grid-block position (wholly below the diagonal, or crossed
+        by it) a body with one set of dots per strip — and ONE select
+        per strip of a crossed block: over the crossed part alone where
+        the block is square, over the strip where it is not. Without a
+        mask there is one body and no select or iota at all."""
+        from paddle_tpu.ops.pallas.flash_attention import \
+            flash_attention_arrays
+        x = jax.ShapeDtypeStruct((1, T, 1, D), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention_arrays(
+                q, k, v, causal=causal, interpret=True)
+                .astype(jnp.float32)), argnums=(0, 1, 2)))(x, x, x)
+        b = core.choose_flash_blocks(T, T, D)
+        square = D == 8
+        assert (b.block_q, b.block_k) == ((T, T) if square else (1024, 512))
+        dots = {"flash_attention_fwd": 2, "flash_attention_dq": 3,
+                "flash_attention_dkv": 4}
+        kernels = _kernels(jaxpr.jaxpr)
+        assert sorted(kernels) == sorted(dots)
+        for name, body in kernels.items():
+            tq, tk = getattr(b, name[len("flash_attention_"):])
+            n = b.block_k // tk if name.endswith("dkv") else b.block_q // tq
+            assert n > 1
+            if not causal:
+                assert _count(body, "dot_general") == dots[name] * n
+                assert _count(body, "select_n") == 0
+                assert _count(body, "iota") == 0
+                continue
+            got = sorted(
+                (_count(br.jaxpr, "dot_general"), _count(br.jaxpr, "select_n"))
+                for eqn in body.eqns if eqn.primitive.name == "cond"
+                for br in eqn.params["branches"]
+                if _count(br.jaxpr, "dot_general"))
+            # wholly below the diagonal: no select; crossed: one a strip
+            assert got == [(dots[name] * n, 0), (dots[name] * n, n)], name
+        if square:
+            # and what a strip on the diagonal sees is the bounds'
+            t = b.dq[0]
+            assert b.dq == (t, t)
+            seen = [core.causal_kv_tiles(i * t, t, t, T // t)
+                    for i in range(T // t)]
+            assert seen == [(i, i + 1) for i in range(T // t)]
 
     def test_blocks_share_the_core_policy(self):
         # one source of truth: the kernel module re-exports nothing of
-        # its own — block choice and the MXU floor live in the core
+        # its own — grid block, sub-tile and the MXU floor live in the
+        # core, as a function of shapes alone
         from paddle_tpu.ops.pallas import flash_attention as fa
         assert fa.core is core
-        bq, bk = core.choose_flash_blocks(2048, 2048, 64)
-        assert bq == 1024 and bk == 1024
-        bq, bk = core.choose_flash_blocks(2048, 2048, 128)
-        assert bk == 512  # head dim scales the VMEM budget down
+        b = core.choose_flash_blocks(1024, 1024, 64)
+        assert (b.block_q, b.block_k) == (1024, 1024)  # one grid step
+        for name in ("fwd", "dq", "dkv"):
+            tq, tk = getattr(b, name)
+            assert (tq, tk) == core.SUB_TILE_CAPS[name]
+            assert 128 <= tq <= 512 and 128 <= tk <= 512
+            assert tq % core.MXU_ROWS == 0 and tk % core.MXU_ROWS == 0
+            assert core.visited_tile_share(1024, 1024, (tq, tk), True) \
+                <= 0.75
+        b = core.choose_flash_blocks(2048, 2048, 64)
+        assert (b.block_q, b.block_k) == (1024, 1024)
+        # wider heads scale the k, v blocks down; such
+        # blocks are skipped whole or computed whole
+        b = core.choose_flash_blocks(2048, 2048, 128)
+        assert (b.block_q, b.block_k) == (1024, 512)
+        assert core.visited_tile_share(
+            2048, 2048, (b.block_q, b.block_k), True) == 0.75
+        assert core.choose_flash_blocks(2048, 2048, 256).block_k == 256
+        assert b.block_k % b.dkv[1] == 0 and b.block_q % b.dkv[0] == 0
+        # lengths no multiple of 128 divides run as one tile, as before
+        assert core.choose_flash_blocks(32, 32, 8).fwd == (32, 32)
+        assert core.choose_flash_blocks(1000, 1000, 64).dq == (1000, 1000)
+        assert core.choose_flash_blocks(768, 768, 64).fwd == (256, 256)
+        assert core.sub_tile(384, 256) == 128
+
+    def test_softmax_carry_forms(self):
+        # the serving kernel's [M] statistics and the training kernel's
+        # [M, 1]: one constructor, and the update keeps either form
+        for column in (False, True):
+            m, l, acc = core.softmax_carry(8, 4, column=column)
+            assert m.shape == l.shape == ((8, 1) if column else (8,))
+            assert acc.shape == (8, 4) and float(m[0].max()) < -1e29
+            s = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
+            m2, l2, acc2 = core.softmax_update(m, l, acc, s, jnp.eye(4))
+            assert m2.shape == m.shape and l2.shape == l.shape
+            out, lse = core.softmax_finalize(m2, l2, acc2)
+            np.testing.assert_allclose(np.asarray(out),
+                                       np.asarray(jax.nn.softmax(s, -1)),
+                                       atol=1e-6)
+            assert lse.shape == m.shape
 
 
 class TestServingBucketFloor:

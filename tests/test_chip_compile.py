@@ -81,6 +81,41 @@ def test_flash_attention_fwd_bwd_gpt_medium(compile_for_chip):
         assert _custom_calls(c, kernel) == 1, kernel
 
 
+@pytest.mark.parametrize("shape,t_k,causal,dtype", [
+    ((2, 2048, 16, 128), 2048, True, BF16),  # gpt_1p3b: 2 x 4 grid blocks
+                                             # a head, 1024 x 512, crossed
+                                             # ones whole under the mask
+    ((8, 1024, 16, 64), 1024, False, BF16),  # nn.MultiHeadAttention: the
+                                             # full square, no mask at all
+    ((2, 768, 12, 64), 768, True, BF16),     # gpt_small width at 3 x 256:
+                                             # an odd count of strips
+    ((2, 1024, 16, 64), 1024, True, F32),    # float32 inputs stay float32:
+                                             # the same blocks, twice the
+                                             # bytes
+    ((2, 1024, 16, 64), 2048, True, BF16),   # causal with Tk > Tq: dkv
+                                             # keeps its sums, to write
+                                             # the zeros of unseen columns
+], ids=["d128_t2048", "non_causal", "t768", "f32", "tk_2tq"])
+def test_flash_attention_shapes_without_a_cell(compile_for_chip, shape, t_k,
+                                               causal, dtype):
+    """The strips' VMEM and slices, for the configurations that share
+    the kernels with the benchmark's cell and have none."""
+    from paddle_tpu.ops.pallas.flash_attention import \
+        flash_attention_arrays
+    b, _, h, d = shape
+    q, kv = (shape, dtype), ((b, t_k, h, d), dtype)
+
+    def loss(q, k, v):
+        out = flash_attention_arrays(q, k, v, causal=causal,
+                                     interpret=False)
+        return jnp.sum(out.astype(F32))
+
+    c = compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    for kernel in ("flash_attention_fwd", "flash_attention_dq",
+                   "flash_attention_dkv"):
+        assert _custom_calls(c, kernel) == 1, kernel
+
+
 @pytest.mark.parametrize("heads,kv_heads,head_dim", [
     (16, 16, 64),     # GPT-medium: fold 1
     (32, 8, 128),     # grouped-query: fold 4
