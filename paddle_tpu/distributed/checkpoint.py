@@ -717,7 +717,8 @@ def load_train_state(step_obj, path):
     hybrid (meshed) step every array is restored DIRECTLY into its live
     dp/mp/ZeRO sharding — the shardings tree is passed through to
     orbax, so no rank materializes the full unsharded state."""
-    target = {"params": step_obj.params, "opt_state": step_obj.opt_state,
+    target = {"params": step_obj.params,
+              "opt_state": dict(step_obj.opt_state),
               "step": np.asarray(step_obj._step_i)}
     shardings = None
     if hasattr(step_obj, "mesh"):
@@ -728,7 +729,7 @@ def load_train_state(step_obj, path):
             target, is_leaf=lambda x: hasattr(x, "dtype"))
     restored = load_sharded(path, target, shardings)
     opt_state = jax.tree.map(
-        lambda cur, new: new, step_obj.opt_state, restored["opt_state"],
+        lambda cur, new: new, target["opt_state"], restored["opt_state"],
         is_leaf=lambda x: hasattr(x, "dtype"))
     if hasattr(step_obj, "set_tree_state"):
         # params/opt_state are per-leaf VIEWS (the donated truth may be

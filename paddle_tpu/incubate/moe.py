@@ -12,6 +12,12 @@ shapes, no host routing: the whole layer jits into one program.
                                num_experts=8, top_k=2)
     y = moe(x)           # [B, T, D] -> [B, T, D]
     loss = task_loss + 0.01 * moe.aux_loss()   # load-balancing loss
+
+`DroplessMoE` beside it is the expert layer of the DeepSeek-V3 / GLM-4.x
+form for ONE member of an expert-parallel group: it is told which
+experts it holds (`local_experts`), routes over all of them, drops
+nothing, and computes its own experts' part of the result as grouped
+matmuls over the assignments sorted by expert (jax.lax.ragged_dot).
 """
 import functools
 import math
@@ -19,10 +25,10 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..framework.core import apply_op
+from ..framework.core import Tensor, apply_op
 from .. import nn
 
-__all__ = ["MoELayer"]
+__all__ = ["MoELayer", "DroplessMoE"]
 
 
 def _moe_forward(x2d, gate_w, w1, b1, w2, b2, *, top_k, capacity,
@@ -164,3 +170,233 @@ class MoELayer(nn.Layer):
                 "was already added inside the compiled program "
                 "(aux_loss_weight); call aux_loss() only in eager loops")
         return self._last_aux
+
+
+# ---------------------------------------------------------------------
+# the dropless expert layer
+# ---------------------------------------------------------------------
+STEP_COUNTERS = ("moe.assignments", "moe.local_assignments",
+                 "moe.expert_load_max", "moe.dropped")
+
+
+def route_tokens(x, router_w, bias, top_k, scale, norm_topk_prob):
+    """Sigmoid routing with a selection bias (DeepSeek-V3, "auxiliary-
+    loss-free" balancing): s = sigmoid(x Wr) in float32 over ALL experts;
+    chosen = top-k of s + bias; weight = s[chosen], renormalised over the
+    chosen (+1e-20) where `norm_topk_prob`, times `scale`. The bias
+    decides who is chosen and never enters a weight. Returns (chosen
+    [N, k] int32, weights [N, k] float32)."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    if norm_topk_prob:
+        picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), picked * scale
+
+
+def dispatch_plan(chosen, first, n_local):
+    """Where every assignment to a HELD expert stands in the sorted
+    buffer: a stable sort of the held assignments by expert, the groups
+    contiguous from row 0 (what jax.lax.ragged_dot takes). The buffer has
+    N * min(k, n_local) rows, the worst case. chosen [N, k]. Returns
+      sizes [n_local]   assignments to each held expert
+      pos [N, k]        the row of each assignment (n_rows: not held)
+      src [n_rows]      the assignment (n * k + j) of each row (N * k:
+                        the row holds none)."""
+    N, k = chosen.shape
+    A, n_rows = N * k, N * min(k, n_local)
+    flat = chosen.reshape(A)
+    held = (flat >= first) & (flat < first + n_local)
+    key = jnp.where(held, flat - first, n_local).astype(jnp.int32)
+    onehot = key[:, None] == jnp.arange(n_local, dtype=jnp.int32)[None]
+    sizes = onehot.sum(0).astype(jnp.int32)
+    starts = jnp.cumsum(sizes, dtype=jnp.int32) - sizes
+    # rank among the assignments of its expert, in assignment order
+    own = jnp.minimum(key, n_local - 1)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0, dtype=jnp.int32),
+                               own[:, None], axis=1)[:, 0] - 1
+    pos = jnp.where(held, starts[own] + rank, n_rows).reshape(N, k)
+    # the stable sort puts the held assignments first, by expert, in order
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)[:n_rows]
+    src = jnp.where(jnp.arange(n_rows, dtype=jnp.int32) < sizes.sum(),
+                    order, A)
+    return sizes, pos, src
+
+
+@jax.custom_vjp
+def _dispatch(x, src, pos):
+    """xs [rows, D]: row r holds token src[r] // k of x [N, D], zero
+    where it holds none. Backward: a gather by `pos`, no scatter."""
+    N, k = pos.shape
+    tok = jnp.minimum(src, N * k - 1) // k
+    return jnp.where((src < N * k)[:, None], x[tok], jnp.zeros((), x.dtype))
+
+
+def _dispatch_fwd(x, src, pos):
+    return _dispatch(x, src, pos), (src, pos)
+
+
+def _dispatch_bwd(res, dxs):
+    src, pos = res
+    rows = dxs.shape[0]
+    got = dxs[jnp.minimum(pos, rows - 1)].astype(jnp.float32)   # [N, k, D]
+    dx = jnp.where((pos < rows)[..., None], got, 0.0).sum(1)
+    return dx.astype(dxs.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, w, src, pos):
+    """y [N, D] = sum over a token's held assignments of w[n, j] *
+    ys[pos[n, j]] (float32 sum). Backward: a gather by `src`."""
+    rows = ys.shape[0]
+    got = ys[jnp.minimum(pos, rows - 1)].astype(jnp.float32)    # [N, k, D]
+    wl = jnp.where(pos < rows, w, 0.0)
+    return (got * wl[..., None]).sum(1).astype(ys.dtype)
+
+
+def _combine_fwd(ys, w, src, pos):
+    return _combine(ys, w, src, pos), (ys, w, src, pos)
+
+
+def _combine_bwd(res, dy):
+    ys, w, src, pos = res
+    rows = ys.shape[0]
+    N, k = pos.shape
+    a = jnp.minimum(src, N * k - 1)
+    w_row = jnp.where(src < N * k, w.reshape(-1)[a], 0.0)
+    dys = (dy[a // k].astype(jnp.float32) * w_row[:, None]).astype(ys.dtype)
+    got = ys[jnp.minimum(pos, rows - 1)].astype(jnp.float32)
+    dw = jnp.where(pos < rows,
+                   (got * dy.astype(jnp.float32)[:, None, :]).sum(-1), 0.0)
+    return dys, dw.astype(w.dtype), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, first):
+    """The held experts' part of the layer's result, for tokens x [N, D]
+    routed as (chosen, weights) [N, k]: stable sort of the held
+    assignments by expert -> gather -> grouped matmul x3 (SiLU-gated) ->
+    weight -> sum per token. Every assignment to a held expert (indices
+    first .. first + E_local - 1) is computed, however they fall: the
+    buffer holds the worst case. The grouped matmul is the compiler's
+    jax.lax.ragged_dot (rows past the groups come out zero and get no
+    gradient); what a Pallas kernel gave against it on the chip is in
+    PERF.md section 6 (PR 27). Returns (y [N, D], counters [4] int32:
+    STEP_COUNTERS)."""
+    N, k = chosen.shape
+    with jax.named_scope("moe.route"):
+        sizes, pos, src = dispatch_plan(chosen, first, w_gate.shape[0])
+
+    def mm(rows, w):
+        return jax.lax.ragged_dot(rows, w.astype(rows.dtype), sizes)
+
+    with jax.named_scope("moe.experts"):
+        xs = _dispatch(x, src, pos)
+        h = jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)
+        ys = mm(h, w_down)
+    with jax.named_scope("moe.combine"):
+        y = _combine(ys, weights.astype(jnp.float32), src, pos)
+    local = sizes.sum()
+    counters = jnp.stack([jnp.int32(N * k), local, sizes.max(),
+                          local - (src < N * k).sum()]).astype(jnp.int32)
+    return y, counters
+
+
+class DroplessMoE(nn.Layer):
+    """Expert layer of the DeepSeek-V3 / GLM-4.x form, for one member of
+    an expert-parallel group.
+
+        y = shared(x) + sum over the token's chosen experts HELD HERE of
+            w_e * E_e(x)
+
+    `num_experts` is the router's width (all routed experts of the
+    model); `local_experts`, a range, says which of them this layer
+    holds (all by default) and is data of the layer. Routing
+    (`route_tokens`) is over all experts: sigmoid scores, a selection
+    bias (the buffer `e_score_correction_bias`, zero unless set),
+    renormalised top-k, `routed_scaling_factor`. Assignments to experts
+    held elsewhere add nothing here; every assignment to a held expert
+    is computed — no capacity, no drop (`dropless_experts`). Experts and
+    the `n_shared_experts` shared ones are SiLU-gated feed-forwards of
+    width `d_expert`. On one chip the layer runs without its exchange;
+    under a mesh with an 'ep' axis the expert stacks shard over it
+    (`sharding_spec()`, MoELayer's convention).
+
+    Each forward records STEP_COUNTERS as one int32 vector
+    (`step_counter_names`, `_step_counters`): jit.TrainStep carries it
+    out of the compiled step and folds it into profiler.monitor. A
+    container that calls this layer under jax.checkpoint or lax.scan
+    must hand the vector out of that inner trace itself
+    (jit.api.take_step_counters; models/decoder.py does)."""
+
+    step_counter_names = STEP_COUNTERS
+
+    def __init__(self, d_model, d_expert, num_experts, top_k,
+                 local_experts=None, n_shared_experts=0,
+                 routed_scaling_factor=1.0, norm_topk_prob=True,
+                 weight_attr=None, name=None):
+        super().__init__()
+        local = range(num_experts) if local_experts is None \
+            else local_experts
+        if local.step != 1 or local.start < 0 or local.stop > num_experts \
+                or len(local) < 1:
+            raise ValueError(f"local_experts={local!r} is no contiguous "
+                             f"range of the {num_experts} experts")
+        if not 1 <= top_k <= num_experts:
+            raise ValueError(f"top_k={top_k} out of range for "
+                             f"{num_experts} experts")
+        self.num_experts, self.top_k, self.local_experts = \
+            num_experts, top_k, local
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        init = weight_attr or nn.initializer.Normal(0.0, 0.02)
+        self.router = nn.Linear(d_model, num_experts, weight_attr=init,
+                                bias_attr=False)
+        self.register_buffer("e_score_correction_bias", Tensor(
+            jnp.zeros((num_experts,), jnp.float32)))
+        E = len(local)
+        self.experts_gate = self.create_parameter(
+            [E, d_model, d_expert], default_initializer=init)
+        self.experts_up = self.create_parameter(
+            [E, d_model, d_expert], default_initializer=init)
+        self.experts_down = self.create_parameter(
+            [E, d_expert, d_model], default_initializer=init)
+        self.shared = nn.GatedMLP(d_model, d_expert * n_shared_experts,
+                                  weight_attr=init) \
+            if n_shared_experts else None
+        self._step_counters = None
+
+    def sharding_spec(self):
+        from jax.sharding import PartitionSpec as P
+        return {"experts_gate": P("ep", None, None),
+                "experts_up": P("ep", None, None),
+                "experts_down": P("ep", None, None),
+                "router.weight": P()}
+
+    def forward(self, x):
+        shape = x.shape
+        D = shape[-1]
+
+        def fn(xa, rw, bias, wg, wu, wd):
+            x2 = xa.reshape(-1, D)
+            with jax.named_scope("moe.route"):
+                chosen, weights = route_tokens(
+                    x2, rw, bias, self.top_k, self.routed_scaling_factor,
+                    self.norm_topk_prob)
+            y, counters = dropless_experts(
+                x2, chosen, weights, wg, wu, wd, self.local_experts.start)
+            return y.reshape(xa.shape), counters
+
+        y, counters = apply_op(
+            fn, x, self.router.weight, self.e_score_correction_bias,
+            self.experts_gate, self.experts_up, self.experts_down,
+            n_outputs=2)
+        self._step_counters = counters.value
+        return y if self.shared is None else y + self.shared(x)

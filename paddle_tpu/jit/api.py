@@ -411,11 +411,35 @@ def not_to_static(fn):
 
 
 def reset_aux_losses(model):
-    """Drop any stale per-layer auxiliary-loss records (e.g. a tracer
-    leaked from a previous trace) before a fresh forward."""
+    """Drop any stale per-layer auxiliary-loss and step-counter records
+    (e.g. a tracer leaked from a previous trace) before a fresh forward."""
     for layer in model.sublayers(include_self=True):
         if hasattr(layer, "_last_aux"):
             layer._last_aux = None
+        if hasattr(layer, "_step_counters"):
+            layer._step_counters = None
+
+
+def take_step_counters(model):
+    """(names, vector) of the in-graph step counters that sublayers
+    recorded during the forward just run under the CURRENT trace, or
+    None. A layer records by setting `_step_counters` to one small
+    integer vector whose entries its `step_counter_names` names (e.g.
+    incubate.moe.DroplessMoE's routing load); layers that record the same
+    names are summed. The records are cleared, so a container that runs
+    sublayers under jax.checkpoint or lax.scan calls this INSIDE that
+    trace, returns the vector from it, and records the sum itself."""
+    found = {}
+    for layer in model.sublayers(include_self=True):
+        vec = getattr(layer, "_step_counters", None)
+        if vec is None:
+            continue
+        layer._step_counters = None
+        names = tuple(layer.step_counter_names)
+        found[names] = vec if names not in found else found[names] + vec
+    if not found:
+        return None
+    return (sum(found, ()), jnp.concatenate(list(found.values())))
 
 
 def collect_aux_losses(model):
@@ -587,6 +611,7 @@ class HealthMonitorMixin:
     def _init_health(self, monitor_health):
         self.monitor_health = bool(monitor_health)
         self._health_pending = collections.deque()
+        self._counters_pending = collections.deque()
         self.last_health = None
         if self.monitor_health:
             from ..profiler.health import AnomalyDetector
@@ -677,6 +702,30 @@ class HealthMonitorMixin:
         if self.anomalies is not None:
             self.anomalies.observe(step_i, h, retraces=self.retraces)
 
+    # -- in-graph step counters (take_step_counters) ---------------------
+    def _queue_step_counters(self, names, vec):
+        """The health queue's pattern for the model's own counter vector:
+        start the D2H copy, fold vectors that have LANDED into
+        profiler.monitor counters of their names; never a host wait."""
+        try:
+            vec.copy_to_host_async()
+        except (AttributeError, RuntimeError):
+            pass
+        self._counters_pending.append((names, vec))
+        self.flush_step_counters(block=False)
+
+    def flush_step_counters(self, block=True):
+        """Fold the pending step-counter vectors into profiler.monitor
+        (`block=False`: only those already on the host)."""
+        while self._counters_pending:
+            names, vec = self._counters_pending[0]
+            ready = getattr(vec, "is_ready", None)
+            if not block and ready is not None and not ready():
+                return
+            self._counters_pending.popleft()
+            for name, v in zip(names, np.asarray(vec)):  # hot-sync-ok: vector already landed (is_ready-gated or explicit flush)
+                _monitor.counter(name).inc(int(v))
+
     def flush_health(self):
         """Blocking drain of the pending health vectors (epoch end,
         shutdown, tests). Returns the most recent resolved health dict
@@ -704,7 +753,7 @@ class CheckpointSnapshotMixin:
 
     def tree_state(self):
         return {"params": self.params,
-                "opt_state": self.opt_state,
+                "opt_state": dict(self.opt_state),
                 "scaler_state": self.scaler_state}
 
     def snapshot_state(self):
@@ -857,25 +906,38 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                 loss, grads, params, opt_state, scaler_state, lr, step_i)
             return loss, new_params, new_state, new_scaler
 
-        def step_fn_health(params, opt_state, scaler_state, buffers, key,
+        def step_fn_single(params, opt_state, scaler_state, buffers, key,
                            lr, step_i, *batch):
-            loss, grads = jax.value_and_grad(
-                lambda ps: self._objective(ps, scaler_state, buffers, key,
-                                           batch))(params)
+            """The per-step program: the plain step, plus the health
+            vector (monitor_health) and the model's own counter vector
+            where a layer records one — a model that records none gives
+            the auxiliary output no leaf, and the program is the plain
+            one."""
+            def objective(ps):
+                l = self._objective(ps, scaler_state, buffers, key, batch)
+                counters = take_step_counters(self.model)
+                self._counter_names = counters[0] if counters else ()
+                return l, counters[1] if counters else None
+
+            (loss, counters), grads = jax.value_and_grad(
+                objective, has_aux=True)(params)
             out_loss, new_params, new_state, new_scaler, aux = \
                 self._finish(loss, grads, params, opt_state, scaler_state,
-                             lr, step_i, want_health=True)
-            health = self._health_vec(out_loss, aux)
-            return out_loss, health, new_params, new_state, new_scaler
+                             lr, step_i, want_health=self.monitor_health)
+            out = (new_params, new_state, new_scaler)
+            if self.monitor_health:
+                out = (self._health_vec(out_loss, aux),) + out
+            return (out_loss,) + out + (() if counters is None
+                                        else (counters,))
 
         donate_argnums = (0, 1, 2) if donate else ()
         self._donate = donate
+        self._counter_names = ()
         # the plain flavor stays: run_steps scans it (the scanned path
-        # keeps the 4-tuple carry; health rides the per-step programs)
+        # keeps the 4-tuple carry; health and the counters ride the
+        # per-step programs)
         self._step_fn = step_fn
-        self._jitted = jax.jit(
-            step_fn_health if self.monitor_health else step_fn,
-            donate_argnums=donate_argnums)
+        self._jitted = jax.jit(step_fn_single, donate_argnums=donate_argnums)
         # AOT executables keyed by batch signature (aot_compile): phases
         # timed, persistent-cache hit observed, cost_analysis free
         self._exec = {}
@@ -927,9 +989,15 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
     @property
     def opt_state(self):
         """Per-leaf optimizer-state view ({name: tuple | {"master",
-        "state"}}), state_dict-compatible on both epilogue layouts."""
+        "state"}}), state_dict-compatible on both epilogue layouts. The
+        tree path gives a dict; the fused path a read-only Mapping
+        (fused_update.LeafStateView) that slices a leaf out of the flat
+        buffers when it is read, since all leaves at once are a second
+        optimizer state on the device. `dict(step.opt_state)` is the
+        same plain tree on both paths; the Mapping itself is a pytree
+        node of its own, not a dict's treedef."""
         if self._fused is not None:
-            return self._fused.state_view(self._opt_store)
+            return self._fused.lazy_state_view(self._opt_store)
         return self._opt_store
 
     def set_tree_state(self, params=None, opt_state=None):
@@ -1418,18 +1486,17 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                 self._exec, sig, lambda: self._jitted, args, "train.step",
                 arg_names=_step_arg_names(len(batch)),
                 span="train.step.dispatch")
-            health = None
-            if self.monitor_health:
-                loss, health, self._params_store, self._opt_store, \
-                    self.scaler_state = out
-            else:
-                loss, self._params_store, self._opt_store, \
-                    self.scaler_state = out
+            loss, *rest = out
+            health = rest.pop(0) if self.monitor_health else None
+            self._params_store, self._opt_store, self.scaler_state, \
+                *extra = rest
             device_probe_close(self, self._step_i, probe, loss, info,
                                compiled_now=compiled_now)
             with _stat.span("train.step.telemetry"):
                 if health is not None:
                     self._queue_health(self._step_i, health)
+                if extra:
+                    self._queue_step_counters(self._counter_names, extra[0])
                 export_step_metrics(self, dispatch_s, info, compiled_now)
                 # non-blocking handle: dispatch has already returned; the
                 # host copy streams in the background and resolves on

@@ -6,7 +6,8 @@ from . import functional
 from .layer import loss
 from .layer.layers import Layer
 from .layer.container import Sequential, LayerList, ParameterList, LayerDict
-from .layer.common import (Identity, Linear, Embedding, Flatten, Dropout,
+from .layer.common import (Identity, Linear, GatedMLP, RotaryEmbedding,
+                           Embedding, Flatten, Dropout,
                            Dropout2D, Dropout3D, AlphaDropout, Upsample,
                            UpsamplingNearest2D, UpsamplingBilinear2D, Pad1D,
                            Pad2D, Pad3D, ZeroPad2D, CosineSimilarity,
@@ -14,7 +15,7 @@ from .layer.common import (Identity, Linear, Embedding, Flatten, Dropout,
 from .layer.conv import (Conv1D, Conv2D, Conv3D, Conv1DTranspose,
                          Conv2DTranspose, Conv3DTranspose)
 from .layer.norm import (BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D,
-                         SyncBatchNorm, LayerNorm, GroupNorm,
+                         SyncBatchNorm, LayerNorm, RMSNorm, GroupNorm,
                          InstanceNorm1D, InstanceNorm2D, InstanceNorm3D,
                          LocalResponseNorm, SpectralNorm)
 from .layer.pooling import (AvgPool1D, AvgPool2D, AvgPool3D, MaxPool1D,

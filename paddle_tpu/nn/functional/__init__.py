@@ -6,7 +6,7 @@ from .common import (linear, dropout, dropout2d, dropout3d, alpha_dropout,
                      interpolate, upsample, unfold, fold, label_smooth)
 from .conv import (conv1d, conv2d, conv3d, conv1d_transpose,
                    conv2d_transpose, conv3d_transpose)
-from .norm import (normalize, layer_norm, batch_norm, instance_norm,
+from .norm import (normalize, layer_norm, rms_norm, batch_norm, instance_norm,
                    group_norm, local_response_norm)
 from .pooling import (avg_pool1d, avg_pool2d, avg_pool3d, max_pool1d,
                       max_pool2d, max_pool3d, adaptive_avg_pool1d,
@@ -25,7 +25,8 @@ from .input import one_hot, embedding
 from .vision import (pixel_shuffle, pixel_unshuffle, channel_shuffle,
                      affine_grid, grid_sample)
 from .extension import sequence_mask, temporal_shift, diag_embed
-from .attention import scaled_dot_product_attention, sparse_attention
+from .attention import (scaled_dot_product_attention, sparse_attention,
+                        rotary_embedding)
 from .misc_gap import (elu_, tanh_, max_unpool1d, max_unpool3d,
                        dice_loss, hsigmoid_loss, log_loss,
                        margin_cross_entropy, gather_tree,

@@ -63,6 +63,34 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     return apply_op(fn, *args)
 
 
+def rotary_embedding(x, theta=10000.0, position_ids=None, name=None):
+    """Rotary position embedding (Su et al., 2021) over the whole last
+    axis of x [batch, seq, ..., dim]: position t rotates the pair
+    (x[i], x[i + dim/2]) — the two halves of the axis — by the angle
+    t * theta^(-2i/dim). `position_ids` [seq] or [batch, seq] (default
+    0..seq-1). Angles, sines and the rotation are float32; the result is
+    in x's dtype. The tables are computed under the trace from the
+    sequence length, so no maximum length is baked into a layer."""
+    def fn(a, *rest):
+        half = a.shape[-1] // 2
+        inv = jnp.float32(theta) ** (
+            -jnp.arange(half, dtype=jnp.float32) * 2.0 / (2 * half))
+        pos = rest[0].astype(jnp.float32) if rest \
+            else jnp.arange(a.shape[1], dtype=jnp.float32)
+        ang = pos[..., None] * inv                  # [(batch,) seq, half]
+        if ang.ndim == 2:
+            ang = ang[None]
+        ang = ang.reshape(ang.shape[:2] + (1,) * (a.ndim - 3) + (half,))
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        a32 = a.astype(jnp.float32)
+        lo, hi = a32[..., :half], a32[..., half:]
+        return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
+                               axis=-1).astype(a.dtype)
+
+    return apply_op(fn, x, *([position_ids] if position_ids is not None
+                             else []))
+
+
 def sparse_attention(query, key, value, sparse_csr_offset=None,
                      sparse_csr_columns=None, key_padding_mask=None,
                      attn_mask=None, name=None):

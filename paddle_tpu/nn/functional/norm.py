@@ -60,6 +60,21 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
     return apply_op(fn, x, *args)
 
 
+def rms_norm(x, weight=None, epsilon=1e-05, name=None):
+    """x / sqrt(mean(x^2) + epsilon) * weight over the last axis (Zhang &
+    Sennrich, 2019): no centring, no bias. The statistic and the scaling
+    are float32 whatever x's dtype; the result is in x's dtype."""
+    def fn(a, *rest):
+        a32 = a.astype(jnp.float32)
+        out = a32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(a32), axis=-1, keepdims=True) + epsilon)
+        if rest:
+            out = out * rest[0].astype(jnp.float32)
+        return out.astype(a.dtype)
+
+    return apply_op(fn, x, *([weight] if weight is not None else []))
+
+
 def batch_norm(x, running_mean, running_var, weight=None, bias=None,
                training=False, momentum=0.9, epsilon=1e-05,
                data_format="NCHW", use_global_stats=None, name=None):

@@ -7,7 +7,7 @@ from .. import functional as F
 from .. import initializer as I
 from .layers import Layer
 
-__all__ = ["Identity", "Linear", "Embedding", "Flatten", "Dropout",
+__all__ = ["Identity", "Linear", "GatedMLP", "RotaryEmbedding", "Embedding", "Flatten", "Dropout",
            "Dropout2D", "Dropout3D", "AlphaDropout", "Upsample",
            "UpsamplingNearest2D", "UpsamplingBilinear2D", "Pad1D", "Pad2D",
            "Pad3D", "ZeroPad2D", "CosineSimilarity", "Bilinear", "Unfold",
@@ -43,6 +43,51 @@ class Linear(Layer):
     def extra_repr(self):
         return f"in_features={self._in_features}, " \
                f"out_features={self._out_features}"
+
+
+class GatedMLP(Layer):
+    """SiLU-gated feed-forward (Shazeer, 2020, "GLU variants"):
+    down(act(gate(x)) * up(x)), three bias-free Linear layers `gate_proj`,
+    `up_proj` [hidden_size, intermediate_size] and `down_proj`
+    [intermediate_size, hidden_size]. `activation` names a function of
+    nn.functional ("silu" by default)."""
+
+    def __init__(self, hidden_size, intermediate_size, activation="silu",
+                 weight_attr=None, name=None):
+        super().__init__()
+        self.gate_proj = Linear(hidden_size, intermediate_size,
+                                weight_attr=weight_attr, bias_attr=False)
+        self.up_proj = Linear(hidden_size, intermediate_size,
+                              weight_attr=weight_attr, bias_attr=False)
+        self.down_proj = Linear(intermediate_size, hidden_size,
+                                weight_attr=weight_attr, bias_attr=False)
+        self._act = getattr(F, activation)
+
+    def forward(self, x):
+        return self.down_proj(self._act(self.gate_proj(x)) * self.up_proj(x))
+
+
+class RotaryEmbedding(Layer):
+    """Rotary position embedding over the whole last axis of its input
+    [batch, seq, ..., dim] (see nn.functional.rotary_embedding: the pair
+    of dim i is dim i + dim/2). Holds no parameter and no table: the
+    angles follow the sequence length of each call."""
+
+    def __init__(self, dim, theta=10000.0, name=None):
+        super().__init__()
+        if dim % 2:
+            raise ValueError(f"rotary dim must be even, got {dim}")
+        self._dim = dim
+        self._theta = float(theta)
+
+    def forward(self, x, position_ids=None):
+        if x.shape[-1] != self._dim:
+            raise ValueError(f"RotaryEmbedding({self._dim}) got a last "
+                             f"axis of {x.shape[-1]}")
+        return F.rotary_embedding(x, self._theta, position_ids)
+
+    def extra_repr(self):
+        return f"dim={self._dim}, theta={self._theta}"
 
 
 class Embedding(Layer):

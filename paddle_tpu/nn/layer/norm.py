@@ -7,7 +7,7 @@ from .. import functional as F
 from .. import initializer as I
 from .layers import Layer
 
-__all__ = ["BatchNorm1D", "BatchNorm2D", "BatchNorm3D", "SyncBatchNorm",
+__all__ = ["RMSNorm", "BatchNorm1D", "BatchNorm2D", "BatchNorm3D", "SyncBatchNorm",
            "LayerNorm", "GroupNorm", "InstanceNorm1D", "InstanceNorm2D",
            "InstanceNorm3D", "LocalResponseNorm", "SpectralNorm",
            "BatchNorm"]
@@ -117,6 +117,28 @@ class LayerNorm(Layer):
 
     def extra_repr(self):
         return f"normalized_shape={self._normalized_shape}"
+
+
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis: x / sqrt(mean(x^2) +
+    epsilon) * weight — no centring and no bias (Zhang & Sennrich, 2019).
+    Statistics are float32 whatever the input's dtype. `weight`
+    [hidden_size] starts at 1."""
+
+    def __init__(self, hidden_size, epsilon=1e-05, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self._hidden_size = hidden_size
+        self._epsilon = epsilon
+        self.weight = self.create_parameter(
+            [hidden_size], attr=weight_attr,
+            default_initializer=I.Constant(1.0))
+
+    def forward(self, input):
+        return F.rms_norm(input, self.weight, self._epsilon)
+
+    def extra_repr(self):
+        return f"hidden_size={self._hidden_size}, epsilon={self._epsilon}"
 
 
 class GroupNorm(Layer):

@@ -52,6 +52,7 @@ CPU through Pallas interpret mode with the SAME blocking the chip gets,
 so the kernel plumbing itself — grid, BlockSpecs, tail mask, SMEM
 accumulators — is exercised by tests too.
 """
+import collections.abc
 import functools
 import os
 
@@ -106,6 +107,38 @@ def _scan_group_order(named_leaves):
         entries.append((groups[gkey], idx, pos, (name, shape, dtype)))
     entries.sort(key=lambda t: (t[0], t[1], t[2]))
     return [(e[0], e[3]) for e in entries]
+
+
+class LeafStateView(collections.abc.Mapping):
+    """{leaf name: its optimizer state}, each leaf sliced out of the flat
+    store when it is read and not kept: `{k: s["master"] for k, s in
+    view.items()}` holds one copy of the masters, not of the moments too.
+    Read-only, and NOT a dict: `dict(view)` is the plain dict
+    FusedEpilogue.state_view gives (and holds every leaf at once). As a
+    pytree it is a node of its own around that dict: jax.tree.leaves and
+    a one-tree jax.tree.map see the dict's leaves (the map returns the
+    plain dict), but its treedef is not a dict's, so to map it against a
+    dict tree take `dict(view)` first."""
+
+    def __init__(self, epilogue, opt_store):
+        self._epilogue, self._store = epilogue, opt_store
+        self._names = [leaf.name for _, leaf in epilogue.layout.leaf_order]
+
+    def __getitem__(self, name):
+        if name not in self._epilogue.layout._by_name:
+            raise KeyError(name)
+        return self._epilogue.leaf_state(self._store, name)
+
+    def __iter__(self):
+        return iter(self._names)
+
+    def __len__(self):
+        return len(self._names)
+
+
+jax.tree_util.register_pytree_node(
+    LeafStateView, lambda view: ((dict(view),), None),
+    lambda _, children: children[0])
 
 
 class _Leaf:
@@ -646,24 +679,30 @@ class FusedEpilogue:
             keys=master_keys) if master_keys else {}
         return {"moments": moments, "masters": masters}
 
+    def leaf_state(self, opt_store, name):
+        """One leaf's optimizer state out of the flat store: tuple(moments)
+        or {"master": f32, "state": tuple}, as Optimizer.init_leaf_state
+        lays it out."""
+        lay = self.layout
+        moments = tuple(lay.leaf_view(m, name) for m in opt_store["moments"])
+        if lay._by_name[name][0] in opt_store["masters"]:
+            return {"master": lay.leaf_view(opt_store["masters"], name),
+                    "state": moments}
+        return moments
+
     def state_view(self, opt_store):
         """Per-leaf optimizer-state VIEW of the flat store — {name:
         tuple(moments) | {"master": f32, "state": tuple}} — mirroring
         Optimizer.init_leaf_state's tree layout exactly, so state_dict
         round-trips and tests see the same structure on both paths."""
-        lay = self.layout
-        out = {}
-        for key, leaf in lay.leaf_order:
-            moments = tuple(lay.leaf_view(m, leaf.name)
-                            for m in opt_store["moments"])
-            if key in opt_store["masters"]:
-                out[leaf.name] = {
-                    "master": lay.leaf_view(opt_store["masters"],
-                                            leaf.name),
-                    "state": moments}
-            else:
-                out[leaf.name] = moments
-        return out
+        return {leaf.name: self.leaf_state(opt_store, leaf.name)
+                for _, leaf in self.layout.leaf_order}
+
+    def lazy_state_view(self, opt_store):
+        """state_view as a Mapping that slices a leaf out only when it is
+        asked for (every view of a flat buffer is a copy: all of them at
+        once are a second optimizer state on the device)."""
+        return LeafStateView(self, opt_store)
 
     def bytes_per_step(self, scaling, need_norm, master_keys=()):
         """Analytic HBM traffic of the epilogue passes (the

@@ -95,7 +95,11 @@ def test_flash_attention_fwd_bwd_gpt_medium(compile_for_chip):
     ((2, 1024, 16, 64), 2048, True, BF16),   # causal with Tk > Tq: dkv
                                              # keeps its sums, to write
                                              # the zeros of unseen columns
-], ids=["d128_t2048", "non_causal", "t768", "f32", "tk_2tq"])
+    ((2, 4096, 20, 256), 4096, True, BF16),  # latent attention's core in
+                                             # glm-4.7-flash-ep8's cell:
+                                             # 1024 x 256 blocks, 4 x 16
+                                             # a head
+], ids=["d128_t2048", "non_causal", "t768", "f32", "tk_2tq", "d256_t4096"])
 def test_flash_attention_shapes_without_a_cell(compile_for_chip, shape, t_k,
                                                causal, dtype):
     """The strips' VMEM and slices, for the configurations that share
@@ -114,6 +118,19 @@ def test_flash_attention_shapes_without_a_cell(compile_for_chip, shape, t_k,
     for kernel in ("flash_attention_fwd", "flash_attention_dq",
                    "flash_attention_dkv"):
         assert _custom_calls(c, kernel) == 1, kernel
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)],
+                         ids=["gate_up", "down"])
+def test_ragged_dot_fwd_bwd_expert_shapes(compile_for_chip, k, n):
+    """The dropless expert layer's products in glm-4.7-flash-ep8's cell:
+    8 groups in the worst-case buffer of 8,192 tokens x top-4, bf16,
+    forward and both backward products of the compiler's own op."""
+    def loss(lhs, rhs, sizes):
+        return jnp.sum(jax.lax.ragged_dot(lhs, rhs, sizes).astype(F32))
+
+    compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1)),
+                     ((8192 * 4, k), BF16), ((8, k, n), BF16), ((8,), I32))
 
 
 @pytest.mark.parametrize("heads,kv_heads,head_dim", [

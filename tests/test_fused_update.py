@@ -76,7 +76,11 @@ def _assert_state_equal(a, b, exact=True, rtol=2e-6, atol=1e-7):
         else:
             np.testing.assert_allclose(x, y, rtol=rtol, atol=atol,
                                        err_msg=f"param {k}")
-    sa, sb = a.opt_state, b.opt_state
+    # the property differs by path (test_opt_state_as_users_see_it):
+    # a dict on the tree path, a lazy Mapping on the fused one. As dicts
+    # they are one structure
+    assert list(a.opt_state) == list(b.opt_state)
+    sa, sb = dict(a.opt_state), dict(b.opt_state)
     assert jax.tree.structure(sa) == jax.tree.structure(sb)
     for la, lb in zip(jax.tree.leaves(sa), jax.tree.leaves(sb)):
         x, y = np.asarray(la, np.float32), np.asarray(lb, np.float32)
@@ -186,6 +190,48 @@ def test_fused_bf16_master_weights_bitwise():
     assert isinstance(leaf, dict) and "master" in leaf
     assert leaf["master"].dtype == jnp.float32
     assert fused.params["0.weight"].dtype == jnp.bfloat16
+
+
+def test_opt_state_as_users_see_it():
+    """TrainStep.opt_state by path: a dict on the tree path; on the fused
+    path a read-only Mapping that slices each leaf out when it is read.
+    dict() of either is one plain tree; the Mapping is a pytree node of
+    its own, so it does not map against a dict tree."""
+    import collections.abc
+    fused, tree = _pair(
+        lambda m: opt.AdamW(learning_rate=0.05,
+                            parameters=m.parameters(),
+                            multi_precision=True),
+        bf16=True)
+    x, y = _batch(bf16=True)
+    fused(x, y), tree(x, y)
+    assert type(tree.opt_state) is dict
+    view = fused.opt_state
+    assert isinstance(view, collections.abc.Mapping)
+    assert not isinstance(view, dict)
+    assert list(view) == list(tree.opt_state) and len(view) == len(
+        tree.opt_state)
+    assert "0.weight" in view and "no.such.leaf" not in view
+    with pytest.raises(KeyError):
+        view["no.such.leaf"]
+    with pytest.raises(TypeError):
+        view["0.weight"] = None
+    # a leaf is sliced anew at each read, equal to the tree path's
+    for name, leaf in view.items():
+        assert leaf["master"] is not view[name]["master"]
+        np.testing.assert_array_equal(
+            np.asarray(leaf["master"]),
+            np.asarray(tree.opt_state[name]["master"]))
+    plain = dict(view)
+    assert jax.tree.structure(plain) == jax.tree.structure(tree.opt_state)
+    # as a pytree: the dict's leaves under a node of its own
+    assert len(jax.tree.leaves(view)) == len(jax.tree.leaves(plain))
+    assert type(jax.tree.map(lambda a: a, view)) is dict
+    assert jax.tree.structure(view) != jax.tree.structure(plain)
+    with pytest.raises(ValueError):
+        jax.tree.map(lambda a, b: a, view, plain)
+    # checkpoint code takes dict() first (tree_state)
+    assert type(fused.tree_state()["opt_state"]) is dict
 
 
 def test_found_inf_skips_update_and_backs_off_scale():
