@@ -16,9 +16,10 @@ Parameter names follow the published module tree (`model.embed_tokens`,
 in two lists: `model.lead.<i>`, the leading layers that differ from the
 rest, and `model.h.<i>`, the uniform run that ends the stack. Under a
 trace every layer is rematerialised in the backward pass
-(jax.checkpoint), and a uniform run of more than one layer is one
-lax.scan over its stacked parameters, traced and compiled once whatever
-its length.
+(jax.checkpoint) from its input and the flash kernel's output (`out`,
+`lse`), so that the kernel runs once a layer, and a uniform run of more
+than one layer is one lax.scan over its stacked parameters, traced and
+compiled once whatever its length.
 
 Training only: no decode cache yet (ROADMAP C9: a latent paged cache with
 an absorbed decode path).
@@ -189,6 +190,13 @@ MIXERS = {"mla": LatentAttention}
 FFNS = {"dense": _dense_ffn, "moe": _moe_ffn}
 NORMS = {"rms": _rms}
 
+# what a layer keeps for its backward pass beside its input: the flash
+# kernel's output and row statistics (named in ops/pallas/
+# flash_attention.py), so that the backward pass rebuilds the
+# projections and the feed-forward but does not run the kernel again
+_REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    "flash_out", "flash_lse")
+
 
 class DecoderLayer(nn.Layer):
     def __init__(self, cfg, spec):
@@ -257,7 +265,8 @@ class DecoderStack(nn.Layer):
         scans = len(self.h) > 1
         for layer in [*self.lead, *([] if scans else self.h)]:
             h, c = jax.checkpoint(
-                lambda hv, layer=layer: _call_layer(layer, hv, names))(h)
+                lambda hv, layer=layer: _call_layer(layer, hv, names),
+                policy=_REMAT_POLICY)(h)
             found.append(c)
         if scans:
             h, c = self._scan(h, names)
@@ -286,8 +295,9 @@ class DecoderStack(nn.Layer):
             finally:
                 _restore(saved)
 
-        return jax.lax.scan(jax.checkpoint(step, prevent_cse=False), h,
-                            stacked)
+        return jax.lax.scan(
+            jax.checkpoint(step, prevent_cse=False, policy=_REMAT_POLICY),
+            h, stacked)
 
 
 class DecoderForCausalLM(nn.Layer):

@@ -206,16 +206,25 @@ def sampling_key_data(seed):
 def _remat_policy(scan_remat):
     """Map cfg.scan_remat to a jax.checkpoint policy. True → full
     recompute (policy None). "dots" → save non-batch matmul outputs.
-    "names" → save exactly the three big per-block matmul outputs (qkv,
-    attn out, ffn up — tagged via checkpoint_name below), recompute the
-    cheap rest; unlike "dots" this skips the flash-attention internals
-    and keeps HBM bounded at ~10*B*T*H bf16 per block."""
+    "names" → save exactly the named points of a block and recompute
+    the cheap rest: the qkv and ffn-up matmul outputs (gpt_qkv,
+    gpt_ffn_in, tagged below) and the attention core's output — from
+    the flash kernel the residuals it names inside its custom_vjp,
+    flash_out ([B, T, H], the value out_proj consumes) and flash_lse
+    ([B*heads, 1, T] float32), so the backward pass runs dq and dkv on
+    what the forward made and the forward kernel runs once a layer;
+    from ring attention gpt_attn_out (the plain composition, off the
+    chip or under dropout, names nothing and is recomputed). 8*B*T*H
+    bf16 + 4*B*heads*T bytes a block: 134.7 MB at 8 x 1024 x 1024 with
+    16 heads. True and "dots" know no names and run the forward kernel
+    again."""
     import jax
     if scan_remat == "dots":
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     if scan_remat == "names":
         return jax.checkpoint_policies.save_only_these_names(
-            "gpt_qkv", "gpt_attn_out", "gpt_ffn_in")
+            "gpt_qkv", "flash_out", "flash_lse", "gpt_attn_out",
+            "gpt_ffn_in")
     return None
 
 
@@ -264,12 +273,16 @@ class GPTAttention(nn.Layer):
             cache = (k, v)
         if self.sequence_parallel and cache is None:
             from ..ops.ring_attention import ring_attention
-            out = ring_attention(q, k, v, causal=True)
+            out = _ckpt_name(
+                ring_attention(q, k, v, causal=True).reshape([B, T, H]),
+                "gpt_attn_out")
         else:
+            # the flash kernel names its own save points (flash_out,
+            # flash_lse): naming the same bytes here would save them twice
             out = F.scaled_dot_product_attention(
                 q, k, v, is_causal=True,
-                dropout_p=self.dropout if self.training else 0.0)
-        out = _ckpt_name(out.reshape([B, T, H]), "gpt_attn_out")
+                dropout_p=self.dropout if self.training else 0.0
+            ).reshape([B, T, H])
         out = self.out_proj(out)
         return (out, cache) if cache is not None else out
 

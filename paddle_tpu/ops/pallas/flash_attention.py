@@ -38,15 +38,25 @@ accumulators are f32.
 
 Backward is the standard two-pass flash backward (dq pass, then dk/dv
 pass) via jax.custom_vjp, recomputing probabilities from the saved lse.
+The forward rule names the two residuals the kernel makes as remat save
+points, "flash_out" and "flash_lse" (jax.ad_checkpoint.checkpoint_name):
+a caller whose jax.checkpoint policy saves them (models/gpt.py's
+scan_remat="names", models/decoder.py) runs the forward kernel once a
+layer; under any other policy, or none, the names are identity ops.
 
 Layout contract: q, k, v are [batch, seq, heads, head_dim] (the
 framework's fused-attention layout); internally folded to [B*H, T, D].
+The output is un-folded INSIDE the custom_vjp, so the saved `out` is the
+[B, T, H*D] value the model consumes: full lanes at any head dim (a
+[B*H, T, 64] bf16 array is stored with its lanes padded to 128, twice
+the bytes) and nothing to transpose when the layer is recomputed.
 The causal mask is top-left aligned (row >= column).
 """
 import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -289,10 +299,27 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
             dv_ref[0] = acc_refs[1][:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, scale, interpret):
+def _fold(x, heads):
+    """[B, T, H*D] (or [B, T, H, D]) -> the kernels' [B*H, T, D]."""
+    B, T = x.shape[:2]
+    x = x.reshape(B, T, heads, -1)
+    return jnp.swapaxes(x, 1, 2).reshape(B * heads, T, x.shape[-1])
+
+
+def _unfold(x, heads):
+    """The kernels' [B*H, T, D] -> [B, T, H*D]."""
+    BH, T, D = x.shape
+    x = x.reshape(BH // heads, heads, T, D)
+    return jnp.swapaxes(x, 1, 2).reshape(BH // heads, T, heads * D)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, heads, causal, scale, interpret):
+    """q, k, v folded [B*H, T, D] -> out [B, Tq, H*D]: the un-fold is
+    inside the rule, so that the residual a remat policy saves is the
+    value the model consumes, with full lanes at any head dim."""
     out, _ = _flash_fwd_impl(q, k, v, causal, scale, interpret)
-    return out
+    return _unfold(out, heads)
 
 
 def _flash_fwd_impl(q, k, v, causal, scale, interpret):
@@ -330,13 +357,21 @@ def _flash_fwd_impl(q, k, v, causal, scale, interpret):
     return out, lse
 
 
-def _flash_fwd(q, k, v, causal, scale, interpret):
+def _flash_fwd(q, k, v, heads, causal, scale, interpret):
     out, lse = _flash_fwd_impl(q, k, v, causal, scale, interpret)
+    # named save points: a caller's remat policy that saves "flash_out"
+    # and "flash_lse" keeps this kernel out of its backward pass. The
+    # PRIMAL comes from the named value too: tagged only as a residual,
+    # the layer's own recomputation asks for `out` again (the next
+    # matmul's weight gradient needs its input) and runs the kernel twice
+    out = checkpoint_name(_unfold(out, heads), "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, interpret, res, dout):
+def _flash_bwd(heads, causal, scale, interpret, res, dout):
     q, k, v, out, lse = res
+    out, dout = _fold(out, heads), _fold(dout, heads)
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     blocks = core.choose_flash_blocks(Tq, Tk, D)
@@ -400,9 +435,9 @@ def flash_attention_arrays(q, k, v, causal=False, scale=None,
     """Array-level entry: q,k,v [B, T, H, D] → out [B, T, H, D]."""
     B, Tq, H, D = q.shape
     scale = core.default_scale(scale, D)
-    fold = lambda x: jnp.swapaxes(x, 1, 2).reshape(B * H, x.shape[1], D)
-    out = _flash(fold(q), fold(k), fold(v), causal, scale, interpret)
-    return jnp.swapaxes(out.reshape(B, H, Tq, D), 1, 2)
+    out = _flash(_fold(q, H), _fold(k, H), _fold(v, H), H, causal, scale,
+                 interpret)
+    return out.reshape(B, Tq, H, D)
 
 
 def _per_shard(fn, mesh, batch_axis, head_axis, q_shape):

@@ -34,3 +34,51 @@ assert jax.device_count() == 8, "expected 8 virtual CPU devices"
 # compiles, and the CPU AOT entries reload with machine-feature
 # mismatch warnings (potential SIGILL per cpu_aot_loader). The wall-
 # clock answer is the two-tier gate in pytest.ini instead.
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def flash_interpret(monkeypatch):
+    """F.scaled_dot_product_attention takes the Pallas flash kernels, in
+    interpret mode (on the chip they are the default attention)."""
+    import paddle_tpu.ops as ops
+    monkeypatch.setattr(ops, "_FLASH_ENV", "interpret")
+    assert ops.flash_attention_available()
+
+
+def _kernel_calls(jaxpr, name):
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += eqn.params["name"] == name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)     # ClosedJaxpr
+                if hasattr(sub, "eqns"):
+                    n += _kernel_calls(sub, name)
+    return n
+
+
+@pytest.fixture
+def flash_kernel_calls():
+    """count(fn, *args) -> how many pallas_call equations of each flash
+    kernel the jaxpr of fn(*args) holds, bodies of scans, checkpoints
+    and calls included: (forward, dq, dkv)."""
+    def count(fn, *args):
+        jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+        return tuple(_kernel_calls(jaxpr, f"flash_attention_{k}")
+                     for k in ("fwd", "dq", "dkv"))
+    return count
+
+
+@pytest.fixture
+def remat_saved():
+    """saved(fn, *args) -> [(aval, why)] of what the jax.checkpoint-ed
+    fn keeps for its backward pass beside constants and arguments."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    def saved(fn, *args):
+        return [(str(aval), why) for aval, why in saved_residuals(fn, *args)
+                if "constant" not in why and "argument" not in why]
+    return saved

@@ -53,6 +53,7 @@ def compile_for_chip(topo):
                             is_leaf=lambda x: isinstance(x, tuple))
         return jax.jit(fn).lower(*args).compile()
 
+    compile_.sds = sds
     yield compile_
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
@@ -61,6 +62,12 @@ def compile_for_chip(topo):
 def _custom_calls(compiled, name):
     return sum(1 for line in compiled.as_text().splitlines()
                if "tpu_custom_call" in line and name in line)
+
+
+def _flash_kernel_counts(compiled):
+    """(forward, dq, dkv) Mosaic calls in the module."""
+    return tuple(_custom_calls(compiled, f"flash_attention_{k}")
+                 for k in ("fwd", "dq", "dkv"))
 
 
 def test_flash_attention_fwd_bwd_gpt_medium(compile_for_chip):
@@ -76,9 +83,7 @@ def test_flash_attention_fwd_bwd_gpt_medium(compile_for_chip):
         return jnp.sum(out.astype(F32))
 
     c = compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
-    for kernel in ("flash_attention_fwd", "flash_attention_dq",
-                   "flash_attention_dkv"):
-        assert _custom_calls(c, kernel) == 1, kernel
+    assert _flash_kernel_counts(c) == (1, 1, 1)
 
 
 @pytest.mark.parametrize("shape,t_k,causal,dtype", [
@@ -115,9 +120,89 @@ def test_flash_attention_shapes_without_a_cell(compile_for_chip, shape, t_k,
         return jnp.sum(out.astype(F32))
 
     c = compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    for kernel in ("flash_attention_fwd", "flash_attention_dq",
-                   "flash_attention_dkv"):
-        assert _custom_calls(c, kernel) == 1, kernel
+    assert _flash_kernel_counts(c) == (1, 1, 1)
+
+
+@pytest.fixture
+def chip_branches(monkeypatch):
+    """The program's backend switches take their chip branch while a
+    test traces: attention goes to the flash kernels, not interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _gpt_two_blocks():
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    return GPTForCausalLM(GPTConfig(
+        vocab_size=512, hidden_size=1024, num_layers=2, num_heads=16,
+        max_position_embeddings=1024, dropout=0.0,
+        scan_remat="names")), (8, 1024)
+
+
+def _glm_two_expert_layers():
+    from paddle_tpu.models.decoder import (DecoderForCausalLM,
+                                           glm_4_7_flash_ep8)
+    return DecoderForCausalLM(glm_4_7_flash_ep8(
+        vocab_size=512, num_hidden_layers=2,
+        first_k_dense_replace=0)), (2, 4096)
+
+
+@pytest.mark.parametrize("build", [_gpt_two_blocks, _glm_two_expert_layers],
+                         ids=["gpt_names_8x1024x16x64",
+                              "decoder_2x4096x20x256"])
+def test_scanned_stack_gradient_runs_flash_forward_once(
+        compile_for_chip, chip_branches, build):
+    """The gradient of a two-layer scanned stack under the stack's own
+    remat policy, at the benchmark cells' attention shapes: the policy
+    saves the kernel's named out and lse, so the module holds ONE forward
+    kernel beside one dq and one dkv (two forwards before PR 28)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.jit.api import functional_call, state_arrays
+    paddle.seed(0)
+    model, ids_shape = build()
+    model.bfloat16()
+    params, buffers = state_arrays(model)
+
+    def loss(p, ids):
+        logits = functional_call(model, p, buffers, (ids,), training=True)
+        return jnp.mean(logits.astype(F32))
+
+    c = compile_for_chip(
+        jax.grad(loss), {k: (v.shape, v.dtype) for k, v in params.items()},
+        (ids_shape, I32))
+    assert _flash_kernel_counts(c) == (1, 1, 1)
+
+
+def test_glm_flash_ep8_step_fits_the_chip(compile_for_chip, chip_branches):
+    """The whole TrainStep of glm-4.7-flash-ep8's cell (2 x 4096 ids,
+    AdamW with float32 masters, the fused update): what the compiler
+    says it needs stays under the 15.75 GB a v5e leaves a program, with
+    the flash residuals saved; one forward kernel for the leading layer
+    and one for the scan, as many as dq and dkv."""
+    import paddle_tpu as paddle
+    import paddle_tpu.nn.functional as Fn
+    from paddle_tpu import optimizer as opt
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models.decoder import (DecoderForCausalLM,
+                                           glm_4_7_flash_ep8)
+    paddle.seed(0)
+    model = DecoderForCausalLM(glm_4_7_flash_ep8())
+    model.bfloat16()
+    step = TrainStep(
+        model, lambda logits, y: Fn.cross_entropy(
+            logits.reshape([-1, logits.shape[-1]]), y.reshape([-1])),
+        opt.AdamW(learning_rate=1e-4, weight_decay=0.01,
+                  parameters=model.parameters(), multi_precision=True))
+    ids = paddle.to_tensor(np.zeros((2, 4096), np.int32))
+    _, args = step._prep((ids, ids), 1)
+    specs = jax.tree.map(
+        lambda a: compile_for_chip.sds((jnp.shape(a), jnp.result_type(a))),
+        args)
+    c = step._jitted.lower(*specs).compile()
+    m = c.memory_analysis()
+    needs = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert needs < 15.75e9, needs
+    assert _flash_kernel_counts(c) == (2, 2, 2)
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)],

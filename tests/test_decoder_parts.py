@@ -156,3 +156,76 @@ def test_eager_backward_reaches_every_parameter():
     model.loss(ids, ids).backward()
     missing = [n for n, p in model.named_parameters() if p.grad is None]
     assert missing == []
+
+
+def _flash_stack(n_layers):
+    """A dense leading layer, then expert layers, at a width and length
+    the flash kernels take (head dim 32, value heads as wide)."""
+    from paddle_tpu.jit.api import functional_call, state_arrays
+    paddle.seed(0)
+    model = DecoderForCausalLM(DecoderConfig(
+        vocab_size=64, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=n_layers, num_attention_heads=2, q_lora_rank=32,
+        kv_lora_rank=16, qk_nope_head_dim=24, qk_rope_head_dim=8,
+        v_head_dim=32, moe_intermediate_size=48, n_routed_experts=4,
+        first_k_dense_replace=1, n_shared_experts=1))
+    params, buffers = state_arrays(model)
+    ids = jnp.asarray(np.random.RandomState(0).randint(0, 64, (2, 128)),
+                      jnp.int32)
+
+    def loss(p):
+        logits = functional_call(model, p, buffers, (ids,))
+        return jnp.mean(jax.nn.logsumexp(logits.astype(jnp.float32), -1))
+
+    return model, params, loss
+
+
+@pytest.mark.parametrize("n_layers, bodies", [(2, 2), (4, 2)],
+                         ids=["lead_and_one", "lead_and_scan"])
+def test_backward_runs_the_flash_forward_once_a_layer(
+        flash_interpret, flash_kernel_calls, n_layers, bodies):
+    """Every layer is rematerialised from its input AND the flash
+    kernel's named out and lse: one forward kernel for each dq / dkv
+    pair in the gradient (each traced body counts once: the leading
+    layer, and the uniform run as a single call or one scan)."""
+    _, params, loss = _flash_stack(n_layers)
+    assert flash_kernel_calls(jax.grad(loss), params) == (bodies,) * 3
+
+
+def test_flash_forward_runs_twice_without_the_policy(
+        flash_interpret, flash_kernel_calls, monkeypatch):
+    """What the names buy: the same stack under a policy-free
+    jax.checkpoint runs the forward kernel again in the backward pass."""
+    from paddle_tpu.models import decoder
+    monkeypatch.setattr(decoder, "_REMAT_POLICY", None)
+    _, params, loss = _flash_stack(4)
+    assert flash_kernel_calls(jax.grad(loss), params) == (4, 2, 2)
+
+
+@pytest.mark.parametrize("n_layers", [2, 4], ids=["lead_and_one",
+                                                  "lead_and_scan"])
+def test_grads_equal_the_stack_without_remat_bit_for_bit(
+        flash_interpret, monkeypatch, n_layers):
+    """The backward kernels get the very out and lse the forward made."""
+    _, params, loss = _flash_stack(n_layers)
+    saved = jax.jit(jax.grad(loss))(params)
+    monkeypatch.setattr(jax, "checkpoint", lambda fn, **kw: fn)
+    plain = jax.jit(jax.grad(loss))(params)
+    for k in plain:
+        np.testing.assert_array_equal(np.asarray(saved[k]),
+                                      np.asarray(plain[k]), err_msg=k)
+
+
+def test_one_layer_saves_the_flash_residuals_and_nothing_else(
+        flash_interpret, remat_saved):
+    from paddle_tpu.framework.core import Tensor
+    from paddle_tpu.models import decoder
+    model, _, _ = _flash_stack(2)
+    layer = model.model.lead[0]
+    fn = jax.checkpoint(lambda h: layer(Tensor(h)).value,
+                        policy=decoder._REMAT_POLICY)
+    saved = remat_saved(fn, jnp.ones((2, 128, 64), jnp.float32))
+    assert [aval for aval, _ in saved] == ["float32[2,128,64]",
+                                           "float32[4,1,128]"]
+    assert all("flash_attention.py" in why for _, why in saved)
+    assert "named 'flash_lse'" in saved[1][1]

@@ -27,11 +27,9 @@ from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
 
 
 @pytest.fixture
-def flash(monkeypatch):
-    """Switch flash attention on in interpret mode; the returned
-    function switches it off again (the plain composition)."""
-    monkeypatch.setattr(ops, "_FLASH_ENV", "interpret")
-    assert ops.flash_attention_available()
+def flash(flash_interpret, monkeypatch):
+    """Flash attention on in interpret mode (conftest's switch); the
+    returned function switches it off again (the plain composition)."""
     yield lambda: monkeypatch.setattr(ops, "_FLASH_ENV", "0")
     set_mesh(None)
 

@@ -69,6 +69,94 @@ class TestGPTScanBlocks:
         assert np.isfinite(float(l.item()))
 
 
+def _flash_setup():
+    """Three blocks at a width and length the flash kernels take."""
+    paddle.seed(0)
+    cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=3,
+                    num_heads=2, max_position_embeddings=128, dropout=0.0)
+    m = GPTForCausalLM(cfg)
+    params, _ = state_arrays(m)
+    ids = jnp.asarray(
+        np.random.RandomState(0).randint(0, 128, (2, 128)), jnp.int32)
+
+    def loss(params, scan, remat):
+        cfg.scan_layers, cfg.scan_remat = scan, remat
+        logits = functional_call(m, params, {}, (ids,), training=True)
+        return jnp.mean(jax.nn.logsumexp(logits.astype(jnp.float32), -1))
+
+    return params, loss
+
+
+@pytest.mark.usefixtures("flash_interpret")
+class TestFlashResidualsSaved:
+    """scan_remat="names" saves the flash kernel's output and row
+    statistics (the names flash_out, flash_lse inside its custom_vjp):
+    the backward pass runs dq and dkv on them and the forward kernel
+    runs once a layer. Policies that know no names recompute it."""
+
+    @pytest.mark.parametrize("scan, remat, want", [
+        (True, "names", (1, 1, 1)),      # one scanned body
+        (False, "names", (3, 3, 3)),     # three unrolled blocks
+        (True, True, (2, 1, 1)),         # full recompute: the kernel again
+        (True, "dots", (2, 1, 1)),       # no name-based policy: the same
+        (True, False, (1, 1, 1)),        # no remat at all
+    ], ids=["names_scan", "names_unrolled", "full", "dots", "none"])
+    def test_forward_kernel_runs_once_under_names(self, flash_kernel_calls,
+                                                  scan, remat, want):
+        params, loss = _flash_setup()
+        assert flash_kernel_calls(
+            jax.grad(lambda p: loss(p, scan, remat)), params) == want
+
+    @pytest.mark.parametrize("scan", [True, False],
+                             ids=["scan", "unrolled"])
+    def test_grads_equal_the_stack_without_remat_bit_for_bit(self, scan):
+        """The backward kernels get the very out and lse the forward
+        made, so nothing may differ, not even in the last bit."""
+        params, loss = _flash_setup()
+        plain = jax.jit(jax.grad(lambda p: loss(p, scan, False)))(params)
+        saved = jax.jit(jax.grad(lambda p: loss(p, scan, "names")))(params)
+        for k in plain:
+            np.testing.assert_array_equal(np.asarray(saved[k]),
+                                          np.asarray(plain[k]), err_msg=k)
+
+    def test_one_block_saves_the_named_residuals(self, remat_saved):
+        from paddle_tpu.framework.core import Tensor
+        from paddle_tpu.models.gpt import _remat_policy
+        paddle.seed(0)
+        cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=1,
+                        num_heads=2, max_position_embeddings=128,
+                        dropout=0.0)
+        block = GPTForCausalLM(cfg).gpt.h[0]
+        fn = jax.checkpoint(lambda h: block(Tensor(h)).value,
+                            prevent_cse=False,
+                            policy=_remat_policy("names"))
+        saved = remat_saved(fn, jnp.ones((2, 128, 64), jnp.float32))
+        # qkv, the kernel's out (as the block consumes it, full lanes)
+        # and lse, the feed-forward's input: nothing else
+        assert [aval for aval, _ in saved] == [
+            "float32[2,128,192]", "float32[2,128,64]", "float32[4,1,128]",
+            "float32[2,128,256]"]
+        assert all("flash_attention.py" in why for _, why in saved[1:3])
+        assert "named 'flash_lse'" in saved[2][1]
+
+    def test_names_are_inert_without_a_policy(self, monkeypatch):
+        """A policy-free jax.checkpoint around the kernel lowers to the
+        text it lowers to with the names taken out."""
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        q = jnp.ones((2, 128, 2, 32), jnp.float32)
+
+        def lowered():
+            attn = jax.checkpoint(lambda q, k, v: fa.flash_attention_arrays(
+                q, k, v, causal=True, interpret=True))
+            return jax.jit(jax.grad(
+                lambda q, k, v: jnp.sum(attn(q, k, v)),
+                argnums=(0, 1, 2))).lower(q, q, q).as_text()
+
+        with_names = lowered()
+        monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+        assert lowered() == with_names
+
+
 class TestStaticCacheGenerate:
     """generate() must compile exactly two programs (prefill + scanned
     decode) and match a naive full-recompute greedy loop."""
