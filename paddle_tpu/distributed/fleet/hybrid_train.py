@@ -29,8 +29,7 @@ from ...jit.api import (functional_call, state_arrays, aot_compile,
                         count_train_use, export_step_metrics,
                         HealthMonitorMixin, CheckpointSnapshotMixin,
                         fire_step_faults, _step_arg_names,
-                        epilogue_leaf_meta, device_probe_open,
-                        device_probe_close)
+                        epilogue_leaf_meta)
 from ...jit import warm as _warm
 from ...jit.deferred import DeferredLoss
 from ...profiler import statistic as _stat
@@ -547,7 +546,7 @@ class HybridTrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
     def __call__(self, *batch):
         """One hybrid-parallel optimizer step. On the host the call is
         one `fleet.hybrid_step` span with TrainStep.__call__'s children
-        under TrainStep's names: `train.step.prep`, `train.step.probe`,
+        under TrainStep's names: `train.step.prep`,
         `train.step.dispatch`, `train.step.telemetry`."""
         self._step_i += 1
         with _stat.span("fleet.hybrid_step", step_num=self._step_i):
@@ -555,7 +554,6 @@ class HybridTrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                 if _fault.active():  # fault drills only; two dict reads when off
                     batch = fire_step_faults(self, batch)
                 sig, args = self._prep(batch, self._step_i)
-            probe = device_probe_open(self, self._step_i)
             out, info, compiled_now, dispatch_s = self._dispatch(
                 sig, args, len(batch))
             health = None
@@ -564,8 +562,6 @@ class HybridTrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
                     self.scaler_state = out
             else:
                 loss, self.params, self.opt_state, self.scaler_state = out
-            device_probe_close(self, self._step_i, probe, loss, info,
-                               compiled_now=compiled_now)
             with _stat.span("train.step.telemetry"):
                 if health is not None:
                     self._queue_health(self._step_i, health)

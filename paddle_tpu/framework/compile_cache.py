@@ -9,8 +9,7 @@ it on for the WHOLE framework at import time, so every
 process is written to (and reloaded from) disk. A warm process skips the
 cold compile entirely.
 
-ONE rule says where the cache lives (`resolve_cache_dir`, jax-free so
-bench.py's parent can ask it too):
+ONE rule says where the cache lives (`resolve_cache_dir`, jax-free):
 
   1. `JAX_COMPILATION_CACHE_DIR` set: JAX reads it itself and the
      framework writes `jax_compilation_cache_dir` NOWHERE — whoever runs
@@ -47,8 +46,7 @@ concerns:
   a compiled cache directory is a portable artifact — pack one on any
   machine that has paid the cold compile, seed it into a fresh
   machine/process, and the first train step loads instead of compiling
-  (the warm-start-across-processes reuse of arxiv 2412.14374). bench.py
-  seeds from `BENCH_CACHE_SEED` when set.
+  (the warm-start-across-processes reuse of arxiv 2412.14374).
 """
 import json
 import os
@@ -59,7 +57,7 @@ import warnings
 
 # jax is imported inside the functions that configure it: the path rule
 # (resolve_cache_dir) and the file helpers stay importable by a process
-# that must not touch jax (bench.py's parent, tools/seed_compile_cache)
+# that must not touch jax (tools/seed_compile_cache)
 
 __all__ = ["enable_compile_cache", "disable_compile_cache", "cache_dir",
            "resolve_cache_dir", "DEFAULT_CACHE_DIR", "pack", "seed_from",
@@ -176,7 +174,7 @@ def cache_entry_names():
 
 
 # files that may live in a cache dir without being cache entries
-_NON_ENTRY_FILES = frozenset(["bench_state.json", "MANIFEST.json"])
+_NON_ENTRY_FILES = frozenset(["MANIFEST.json"])
 
 
 # -- per-compile hit/miss attribution ------------------------------------
@@ -338,10 +336,7 @@ def copy_seed_entries(source, dest):
     """The pure-file half of seeding (no jax/framework state): copy the
     cache entries of `source` (a pack() artifact or a raw cache dir)
     into `dest`, skipping entries already present. Returns
-    (seeded, skipped). NOTE: bench.py's PARENT process re-implements
-    this loop (bench._seed_cache); keep the two skip-lists
-    (_NON_ENTRY_FILES here, the inline tuple there) in sync when adding
-    non-entry files."""
+    (seeded, skipped)."""
     os.makedirs(dest, exist_ok=True)
     seeded = skipped = 0
     for n in sorted(os.listdir(source)):

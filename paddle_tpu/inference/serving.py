@@ -1881,8 +1881,7 @@ class GenerationEngine(_SchedulerLifecycle):
         """Measured fraction of this engine's attention score slots
         spent OUTSIDE any row's causal bound — pad rows, bucketed
         table width, intra-page remainders. The bucketed decode path
-        pays all three; the ragged kernel pays only the last (bench.py
-        --serve compares the two in one run)."""
+        pays all three; the ragged kernel pays only the last."""
         if not self._attn_computed:
             return 0.0
         return max(0.0, 1.0 - self._attn_useful / self._attn_computed)

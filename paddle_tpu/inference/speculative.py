@@ -52,7 +52,7 @@ class SpeculativeConfig:
     `draft_temperature` optionally overrides the DRAFT's sampling
     temperature (the target's acceptance draw always uses the
     request's own sampling config — this knob only shifts how often
-    the draft guesses it; bench.py's accept-rate sweep varies it).
+    the draft guesses it).
     None means the draft mirrors each request's own sampling config,
     which maximizes agreement when draft and target logits are close.
 

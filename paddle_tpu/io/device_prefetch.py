@@ -169,7 +169,7 @@ class DevicePrefetchRing:
 def device_prefetch_iterator(source, depth=2, sharding_fn=None):
     """Generator wrapper around DevicePrefetchRing that closes the ring
     when iteration ends OR is abandoned (break / GC) — the form
-    DataLoader and bench.py consume."""
+    DataLoader consumes."""
     ring = DevicePrefetchRing(source, depth=depth, sharding_fn=sharding_fn)
     try:
         for batch in ring:

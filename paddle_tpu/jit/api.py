@@ -151,55 +151,6 @@ def count_train_use(owner, info):
     owner.last_compile_s = total
 
 
-def device_probe_open(step_obj, step_i):
-    """Open the cadence-gated device-time probe window for this step,
-    or None when the probe is not due (one int modulo —
-    dist_observatory.device_probe_due, PADDLE_TPU_DEVICE_TIME_EVERY).
-    Opening DRAINS the previous step still in flight, so the window
-    that closes after this step's output lands measures THIS step's
-    device time — not the async-dispatch pipeline depth. The blocking
-    read is the probe's whole point and is explicitly allowlisted; the
-    lint fences this function so nothing else creeps in."""
-    if not _dobs.device_probe_due(step_i):
-        return None
-    prev = getattr(step_obj, "_probe_prev_out", None)
-    if prev is None:
-        return None  # first step: nothing to drain against; next cadence
-    t_drain0 = time.perf_counter()
-    with _stat.span("train.step.probe"):
-        try:
-            jax.block_until_ready(prev)  # hot-sync-ok: cadence-gated device-time probe drain (PADDLE_TPU_DEVICE_TIME_EVERY; docs/OBSERVABILITY.md)
-        except (RuntimeError, TypeError):
-            return None
-    t0 = time.perf_counter()
-    # drain_s is the probe's ARTIFICIAL wait: export_step_metrics
-    # subtracts it from the probed step's inter-dispatch interval so
-    # the step-time accounting keeps real host stalls but not the probe
-    return t0, _dobs.eager_wait_s(), t0 - t_drain0
-
-
-def device_probe_close(step_obj, step_i, window, out_leaf, info,
-                       compiled_now=False):
-    """Close the probe window: block until this step's output is ready
-    and hand the measured wall window to the distributed observatory
-    (step_time_device_s / mfu_measured / overlap_fraction — carried in
-    this step's record by export_step_metrics). Always stores
-    `out_leaf` as the next probe's drain handle; records nothing for a
-    step that compiled (the window would measure the compile)."""
-    step_obj._probe_prev_out = out_leaf
-    if window is None or compiled_now:
-        return None
-    with _stat.span("train.step.probe"):
-        try:
-            jax.block_until_ready(out_leaf)  # hot-sync-ok: cadence-gated device-time probe window close (the ONE deliberate measured sync; lint-fenced)
-        except (RuntimeError, TypeError):
-            return None
-        t0, wait0, drain_s = window
-        return _dobs.record_device_time(step_obj, step_i,
-                                        time.perf_counter() - t0, info,
-                                        coll_wait0=wait0, drain_s=drain_s)
-
-
 def export_step_metrics(step, dispatch_s, info, compiled_now):
     """Per-step telemetry for a train-step object: step-time histogram,
     cost-analysis FLOPs/MFU gauges, and — when PADDLE_TPU_METRICS_FILE
@@ -216,42 +167,15 @@ def export_step_metrics(step, dispatch_s, info, compiled_now):
     step._last_step_end = now
     compile_s = info["lower_s"] + info["compile_s"] if compiled_now \
         else 0.0
-    # the device-time probe (dist_observatory) BLOCKS on the probed
-    # step: that step's inter-dispatch interval absorbs the probe's
-    # drain wait and the NEXT step's interval collapses to dispatch
-    # overhead. The probed step therefore subtracts the measured
-    # artificial drain from its interval (real host stalls — a slow
-    # data path, an injected delay — stay visible, only the probe's
-    # own wait is removed), and the step after a probe is treated like
-    # a first step (non-steady: no fake near-zero interval, no absurd
-    # MFU from it).
-    probe = getattr(step, "_last_device_probe", None)
-    if probe is not None and probe.get("step") != int(step._step_i):
-        probe = None
-    prev_drained = getattr(step, "_probe_drained", False)
-    step._probe_drained = probe is not None
-    if probe is not None and prev is not None:
-        step_time = max(now - prev - probe.get("probe_drain_s", 0.0),
-                        0.0)
-        steady = True
-    else:
-        steady = prev is not None and not compiled_now \
-            and not prev_drained and probe is None
-        if steady:
-            step_time = now - prev
-        else:
-            step_time = max(dispatch_s - compile_s, 0.0)
+    steady = prev is not None and not compiled_now
+    step_time = now - prev if steady \
+        else max(dispatch_s - compile_s, 0.0)
     flops = float(info.get("flops", 0.0))
     # MFU only from the steady inter-dispatch interval: the fallback
     # dispatch time is near zero under async dispatch and would publish
     # an absurd >1 utilization for the first/recompiling step
     m = _cost.mfu(flops, step_time) if steady else 0.0
-    # the step AFTER a probe has no meaningful interval (the probe
-    # drained the pipe; its fallback is dispatch overhead) — keep it
-    # out of the train.step_s reservoir, which feeds the rankstat
-    # p50/p99 the straggler gather compares across ranks
-    if not (prev_drained and probe is None):
-        _monitor.histogram("train.step_s").observe(step_time)
+    _monitor.histogram("train.step_s").observe(step_time)
     _monitor.gauge("train.flops_per_step").set(flops)
     _monitor.gauge("train.bytes_per_step").set(
         float(info.get("bytes", 0.0)))
@@ -279,13 +203,6 @@ def export_step_metrics(step, dispatch_s, info, compiled_now):
         rec["epilogue_bytes"] = eb
         rec["epilogue_share"] = float(share)
         _monitor.gauge("train.epilogue_share").set(float(share))
-    # measured device time (the sampled probe, dist_observatory): the
-    # probe that closed on THIS step leaves its numbers here — the
-    # step record carries measured time next to the cost-analysis MFU
-    if probe is not None:
-        rec["step_time_device_s"] = probe["step_time_device_s"]
-        rec["mfu_measured"] = probe["mfu_measured"]
-        rec["overlap_fraction"] = probe["overlap_fraction"]
     _monitor.export_step(rec)
     # periodic per-rank skew telemetry (kind:"rankstat") — one int
     # modulo off-cadence; emission + the rank-0 peer gather run only at
@@ -1473,15 +1390,13 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
     def __call__(self, *batch):
         """One optimizer step. On the host the call is one `train.step`
         span whose children cover it: `train.step.prep`,
-        `train.step.probe` (only on a step the device-time probe is
-        due), `train.step.dispatch` and `train.step.telemetry`."""
+        `train.step.dispatch` and `train.step.telemetry`."""
         self._step_i += 1
         with _stat.span("train.step", step_num=self._step_i):
             with _stat.span("train.step.prep"):
                 if _fault.active():  # fault drills only; two dict reads when off
                     batch = fire_step_faults(self, batch)
                 sig, args = self._prep(batch, self._step_i)
-            probe = device_probe_open(self, self._step_i)
             out, info, compiled_now, dispatch_s = self._dispatch(
                 self._exec, sig, lambda: self._jitted, args, "train.step",
                 arg_names=_step_arg_names(len(batch)),
@@ -1490,8 +1405,6 @@ class TrainStep(HealthMonitorMixin, CheckpointSnapshotMixin):
             health = rest.pop(0) if self.monitor_health else None
             self._params_store, self._opt_store, self.scaler_state, \
                 *extra = rest
-            device_probe_close(self, self._step_i, probe, loss, info,
-                               compiled_now=compiled_now)
             with _stat.span("train.step.telemetry"):
                 if health is not None:
                     self._queue_health(self._step_i, health)

@@ -582,8 +582,8 @@ class GPTForCausalLM(nn.Layer):
         """LM loss WITHOUT materializing [B*T, V] logits: the weight-tied
         vocab projection and the softmax-xent run chunked under remat
         (ops/chunked_xent.py). The memory this frees is what lets 1.3B+
-        single-chip configs raise their batch (see examples/
-        bench_gpt_1p3b.py); numerics match .loss() to bf16 precision."""
+        single-chip configs raise their batch; numerics match .loss()
+        to bf16 precision."""
         out = self.gpt(input_ids)
         hidden = out[0] if isinstance(out, tuple) else out
         from ..ops.chunked_xent import chunked_softmax_xent

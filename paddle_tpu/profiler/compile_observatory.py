@@ -33,10 +33,7 @@ much; this module keeps the per-executable WHAT:
   bytes-accessed regressions (the *Neptune*-style locality/fusion cost
   framing, arxiv 2510.08726).
 
-Listeners (`add_listener`) observe compile start/done live — bench.py
-streams per-executable compile progress over its `bench-phase:` stderr
-channel with one, so even a timed-out round names the executable that
-was compiling when the budget died.
+Listeners (`add_listener`) observe compile start/done live.
 
 See docs/OBSERVABILITY.md "The compilation observatory".
 """
@@ -330,8 +327,7 @@ def record_compile(tag, sig, sig_key, lower_s, compile_s, cache_hit,
 
 def ledger():
     """All compile records this process holds (ring-bounded), oldest
-    first — the table a debug bundle and bench.py's `compile_ledger`
-    key render."""
+    first — the table a debug bundle renders."""
     with _lock:
         return [dict(r) for r in _ledger]
 

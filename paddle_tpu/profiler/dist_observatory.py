@@ -1,6 +1,5 @@
 """The distributed observatory: collective telemetry, rank-skew and
-straggler detection, coordinator clock alignment, and measured
-device-time MFU.
+straggler detection, and coordinator clock alignment.
 
 Third observatory sibling (after `compile_observatory.py` and
 `serve_observatory.py`), built for the layer the other two cannot see:
@@ -9,7 +8,7 @@ count calls and bytes; this module adds the time dimension and the
 cross-rank dimension, which is the measurement prerequisite for
 productionizing pipeline parallelism (ROADMAP open item 2's success
 metric — "overlap measured in the Perfetto trace" — is unevaluable
-without it). Four pieces:
+without it). Three pieces:
 
 - **Per-collective timing** — every `paddle.distributed` collective
   call folds into an in-memory per-op rollup (calls / bytes / wall
@@ -45,20 +44,6 @@ without it). Four pieces:
   overlap (collective lanes lining up across pids) instead of skewed
   starts.
 
-- **Measured device time** — a sampled probe (every
-  `PADDLE_TPU_DEVICE_TIME_EVERY` steps; `0` disables) in the train-step
-  dispatch paths drains the in-flight step, dispatches, and blocks
-  until the new step's output is ready: the window IS the device step
-  time, free of async-dispatch pipelining. Both blocking reads live in
-  `jit/api.py` / `hybrid_train.py` under explicit `hot-sync-ok`
-  cadence-gate markers (`tools/check_no_hot_sync.py` fences this whole
-  module and those regions). Each probe yields `step_time_device_s`,
-  `mfu_measured` (XLA cost-analysis FLOPs over MEASURED time — the
-  companion the cost-analysis MFU never had), and an
-  `overlap_fraction` (share of the measured window NOT spent in
-  host-visible eager collective waits), carried in the step record,
-  the bench headline, and the multichip dryrun output.
-
 See docs/OBSERVABILITY.md "The distributed observatory".
 """
 import collections
@@ -73,20 +58,17 @@ from . import monitor as _monitor
 __all__ = ["record_collective", "collective_rollup", "eager_wait_s",
            "collectives_tail", "clock_sync", "clock_offset_s",
            "maybe_rankstat", "emit_rankstat", "rankstats_tail",
-           "read_peer_rankstats", "device_probe_due",
-           "record_device_time", "device_time_summary", "reset",
-           "COLLECTIVE_RING", "RANKSTAT_RING", "DEVICE_RING"]
+           "read_peer_rankstats", "reset",
+           "COLLECTIVE_RING", "RANKSTAT_RING"]
 
 COLLECTIVE_RING = 256  # sampled collective records kept in process
 RANKSTAT_RING = 64     # recent rankstat records (host_stats / bundles)
-DEVICE_RING = 64       # recent device-time probe results
 
 _lock = threading.RLock()
 _coll = {}  # op -> {"calls", "bytes", "wall_s", "traced_calls",
             #        "traced_wall_s"}
 _coll_ring = collections.deque(maxlen=COLLECTIVE_RING)
 _rank_ring = collections.deque(maxlen=RANKSTAT_RING)
-_device_ring = collections.deque(maxlen=DEVICE_RING)
 _state = {"clock_offset_s": 0.0, "clock_rtt_s": None,
           "rankstat_emitted": False, "detector": None}
 
@@ -394,77 +376,6 @@ def rankstats_tail():
         return [dict(r) for r in _rank_ring]
 
 
-# -- measured device time ------------------------------------------------
-
-def device_probe_due(step_i):
-    """Whether the device-time probe should run at this step — one int
-    modulo per step (PADDLE_TPU_DEVICE_TIME_EVERY, default 16; 0
-    disables). The probe's two blocking reads live at the call sites
-    in jit/api.py / hybrid_train.py under explicit hot-sync-ok cadence
-    markers; this module stays sync-free."""
-    every = _env_int("PADDLE_TPU_DEVICE_TIME_EVERY", 16)
-    return every > 0 and step_i % every == 0
-
-
-def record_device_time(step_obj, step_i, dt, info, coll_wait0=None,
-                       drain_s=0.0):
-    """Fold one device-time probe window into the observatory:
-    `dt` is the measured drain→dispatch→ready wall window (= device
-    step time, pipelining excluded), `info` the step executable's
-    compile info (cost-analysis flops), `coll_wait0` the eager
-    collective-wait total captured when the window opened. Publishes
-    the `train.step_time_device_s` / `train.mfu_measured` /
-    `train.overlap_fraction` gauges, rings the sample, and leaves the
-    values on `step_obj._last_device_probe` for `export_step_metrics`
-    to carry in the SAME step's record. Never raises."""
-    try:
-        from . import cost as _cost
-        dt = max(dt, 0.0) * 1.0
-        flops = (info.get("flops", 0.0) or 0.0) if info else 0.0
-        m = _cost.mfu(flops, dt)
-        coll = 0.0
-        if coll_wait0 is not None:
-            coll = max(eager_wait_s() - coll_wait0, 0.0)
-        overlap = 1.0 - min(coll / dt, 1.0) if dt > 0 else 0.0
-        probe = {"step": int(step_i),
-                 "step_time_device_s": round(dt, 6),
-                 "mfu_measured": round(m, 6),
-                 "overlap_fraction": round(overlap, 6),
-                 # the probe's artificial drain wait — what
-                 # export_step_metrics subtracts from the probed step's
-                 # inter-dispatch interval (never exported)
-                 "probe_drain_s": max(drain_s, 0.0) * 1.0}
-        step_obj._last_device_probe = probe
-        _monitor.gauge("train.step_time_device_s").set(dt)
-        _monitor.gauge("train.mfu_measured").set(m)
-        _monitor.gauge("train.overlap_fraction").set(overlap)
-        with _lock:
-            _device_ring.append(dict(probe))
-        return probe
-    except Exception:
-        return None
-
-
-def device_time_summary():
-    """Median-of-samples rollup of the probe ring: {"samples",
-    "step_time_device_s", "mfu_measured", "overlap_fraction"} — what
-    the bench headline and the multichip dryrun report. {} when no
-    probe has fired."""
-    with _lock:
-        samples = [dict(r) for r in _device_ring]
-    if not samples:
-        return {}
-
-    def med(key):
-        vals = sorted(r[key] for r in samples)
-        return vals[len(vals) // 2]
-
-    return {"samples": len(samples),
-            "step_time_device_s": med("step_time_device_s"),
-            "mfu_measured": med("mfu_measured"),
-            "overlap_fraction": med("overlap_fraction")}
-
-
 def reset():
     """Drop rollups, rings, detector state, and the clock offset
     (tests)."""
@@ -472,7 +383,6 @@ def reset():
         _coll.clear()
         _coll_ring.clear()
         _rank_ring.clear()
-        _device_ring.clear()
         _state.update({"clock_offset_s": 0.0, "clock_rtt_s": None,
                        "rankstat_emitted": False, "detector": None})
     _monitor.set_clock_offset(0.0)

@@ -393,7 +393,7 @@ def dump(reason="manual", exc=None, base_dir=None):
             manifest["state_providers"] = provided
 
         # env / versions / argv
-        envkeys = ("PADDLE", "JAX", "XLA", "TPU", "BENCH", "FLAGS_")
+        envkeys = ("PADDLE", "JAX", "XLA", "TPU", "FLAGS_")
         env = {k: v for k, v in os.environ.items()
                if any(k.startswith(p) for p in envkeys)}
         versions = {"python": sys.version}

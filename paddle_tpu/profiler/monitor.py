@@ -15,10 +15,7 @@ level; pinned at 0 means the step loop is data-bound).
 Distributed signals (the distributed observatory,
 profiler/dist_observatory.py — docs/OBSERVABILITY.md "The distributed
 observatory"): `collective.<kind>.calls` / `collective.<kind>.bytes`
-counters (every collective call site), `train.step_time_device_s` /
-`train.mfu_measured` / `train.overlap_fraction` gauges (the sampled
-device-time probe: measured step time, cost-analysis-FLOPs-over-
-MEASURED-time MFU, and the non-collective-wait share of the window),
+counters (every collective call site),
 `dist.rankstats` counter (per-rank `kind:"rankstat"` records emitted)
 and `dist.stragglers` counter (rank-0 `event:"straggler"` detections).
 The sampled per-collective detail (`kind:"collective"`: op, group,
@@ -271,8 +268,7 @@ def reset_metrics():
 def host_blocked_s():
     """Total seconds the host has spent blocked on device reads (the
     `host.blocked_s` histogram sum) — ~0 in a healthy async step loop,
-    where the only blocks are log_freq/epoch boundaries. bench.py
-    reports the steady-phase delta of this in its phase breakdown."""
+    where the only blocks are log_freq/epoch boundaries."""
     m = get_metric("host.blocked_s")
     return float(m.sum) if m is not None else 0.0
 

@@ -29,7 +29,6 @@ STRAGGLER = int(sys.argv[2])
 os.environ["PADDLE_TPU_METRICS_FILE"] = os.path.join(
     OUTDIR, f"metrics.rank{RANK}.jsonl")
 os.environ.setdefault("PADDLE_TPU_RANKSTAT_EVERY", "2")
-os.environ.setdefault("PADDLE_TPU_DEVICE_TIME_EVERY", "3")
 os.environ.setdefault("PADDLE_TPU_COLLECTIVE_SAMPLE", "1")
 
 import jax  # noqa: E402

@@ -1,14 +1,13 @@
 """The distributed observatory (ISSUE 13): per-collective timing,
-rank-skew/straggler detection, clock-aligned multi-rank traces, and
-measured device-time MFU.
+rank-skew/straggler detection and clock-aligned multi-rank traces.
 
 Proof points:
 - every collective call folds into the rollup and the sampled subset
   emits schema-valid `kind:"collective"` records (eager calls with real
   bandwidth, traced insertions flagged);
-- the device-time probe (cadence-gated, lint-fenced) stamps
-  `step_time_device_s` / `mfu_measured` / `overlap_fraction` onto
-  exactly the steps it measured, schema-valid;
+- a step's `step_time_s` is the interval between dispatch returns, a
+  host stall included, and every step enters the `train.step_s`
+  reservoir the rankstat percentiles come from, in both train steps;
 - `kind:"rankstat"` records validate, snapshot atomically into the
   gather dir, and rank 0's gather feeds the straggler detector
   (edge-triggered, naming rank + lag);
@@ -28,6 +27,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -127,65 +127,54 @@ def test_traced_collective_flagged_not_timed(monkeypatch):
     assert dobs.eager_wait_s() == 0.0
 
 
-# ------------------------------------------------ device-time probe
-def test_device_probe_stamps_measured_fields(tmp_path, monkeypatch):
+# ------------------------------------------------ step-time accounting
+def _make_hybrid_step():
+    from paddle_tpu.distributed.env import build_mesh
+    from paddle_tpu.distributed.fleet.hybrid_train import HybridTrainStep
+    paddle.seed(0)
+    m = nn.Linear(8, 8)
+    o = opt.SGD(learning_rate=0.01, parameters=m.parameters())
+    step = HybridTrainStep(m, lambda out, y: ((out - y) ** 2).mean(), o,
+                           build_mesh(dp=8))
+    x = paddle.to_tensor(
+        np.random.RandomState(0).randn(8, 8).astype(np.float32))
+    return step, x
+
+
+@pytest.mark.parametrize("make", [_make_step, _make_hybrid_step],
+                         ids=["TrainStep", "HybridTrainStep"])
+def test_step_time_keeps_host_stalls_and_every_step_is_in_the_reservoir(
+        make, tmp_path, monkeypatch):
+    """A step that did not compile and has a predecessor is steady: its
+    step_time_s is the interval between dispatch returns, so a host
+    stall between two calls (here 250 ms of sleep before calls 17 and
+    18, across what was the probe's cadence) shows in that step and in
+    no other, and each of the 20 steps is one observation of the
+    train.step_s reservoir the rankstat percentiles come from."""
     path = tmp_path / "m.jsonl"
     monkeypatch.setenv("PADDLE_TPU_METRICS_FILE", str(path))
-    monkeypatch.setenv("PADDLE_TPU_DEVICE_TIME_EVERY", "2")
-    step, x = _make_step()
+    step, x = make()
     loss = None
-    for _ in range(4):
+    for i in range(1, 21):
+        if i in (17, 18):
+            time.sleep(0.25)
         loss = step(x, x)
     float(loss.item())
-    recs = [json.loads(l) for l in path.read_text().splitlines()
-            if l.strip()]
-    steps = {r["step"]: r for r in recs if r["kind"] == "step"}
-    # probed steps carry the measured fields; unprobed steps don't
-    # (step 2 is the first probe: step 1 left the drain handle)
-    for i in (2, 4):
-        assert steps[i]["step_time_device_s"] > 0
-        assert 0.0 <= steps[i]["overlap_fraction"] <= 1.0
-        assert steps[i]["mfu_measured"] >= 0.0  # 0.0 on CPU (no peak)
-    for i in (1, 3):
-        assert "step_time_device_s" not in steps[i]
-    summary = dobs.device_time_summary()
-    assert summary["samples"] == 2
-    assert summary["step_time_device_s"] > 0
-    assert monitor.get_metric("train.step_time_device_s").value > 0
-    tool = _load_tool("check_metrics_schema")
-    assert tool.validate_file(str(path)) == []
-
-
-def test_probed_step_time_keeps_host_stalls_drops_probe_drain(
-        tmp_path, monkeypatch):
-    """The probe BLOCKS: without correction the probed step's
-    inter-dispatch interval absorbs the drain wait and the next step's
-    collapses to ~0 with a faked 'steady' MFU. The fix subtracts ONLY
-    the probe's own drain — a real host stall (here a PR-11 injected
-    100 ms delay, the straggler scenario) must stay visible in
-    step_time_s, while step_time_device_s keeps the pure device
-    window."""
-    from paddle_tpu.framework import fault_injection
-    path = tmp_path / "m.jsonl"
-    monkeypatch.setenv("PADDLE_TPU_METRICS_FILE", str(path))
-    monkeypatch.setenv("PADDLE_TPU_DEVICE_TIME_EVERY", "3")
-    fault_injection.configure("delay@train.step=0.1")
-    try:
-        step, x = _make_step()
-        loss = None
-        for _ in range(7):
-            loss = step(x, x)
-        float(loss.item())
-    finally:
-        fault_injection.configure("")
     steps = {r["step"]: r for r in
              (json.loads(l) for l in path.read_text().splitlines()
               if l.strip()) if r["kind"] == "step"}
-    for i in (3, 6):  # the probed steps
-        # the injected host delay is part of the step time...
-        assert steps[i]["step_time_s"] > 0.09, steps[i]
-        # ...but not of the measured device window
-        assert steps[i]["step_time_device_s"] < 0.09, steps[i]
+    assert sorted(steps) == list(range(1, 21))
+    assert steps[1]["compile_s"] > 0
+    for i in range(2, 21):
+        assert steps[i]["compile_s"] == 0.0, steps[i]
+        if i in (17, 18):
+            assert steps[i]["step_time_s"] > 0.24, steps[i]
+        else:
+            assert 0.0 < steps[i]["step_time_s"] < 0.2, steps[i]
+    hist = monitor.get_metric("train.step_s")
+    assert hist.count == 20
+    tool = _load_tool("check_metrics_schema")
+    assert tool.validate_file(str(path)) == []
 
 
 def test_emit_rankstat_respects_disable_unless_forced(monkeypatch):
@@ -193,15 +182,6 @@ def test_emit_rankstat_respects_disable_unless_forced(monkeypatch):
     monitor.histogram("train.step_s").observe(0.01)
     assert dobs.emit_rankstat(step=1) is None       # epoch-boundary path
     assert dobs.emit_rankstat(step=1, force=True) is not None  # gate/dryrun
-
-
-def test_device_probe_off_by_default_env_zero(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_DEVICE_TIME_EVERY", "0")
-    step, x = _make_step()
-    for _ in range(3):
-        loss = step(x, x)
-    float(loss.item())
-    assert dobs.device_time_summary() == {}
 
 
 # ------------------------------------------------ rankstat + straggler
@@ -275,7 +255,7 @@ def test_gather_skips_stale_and_out_of_world_snapshots(
                        str(tmp_path / "m.jsonl"))
     monkeypatch.setenv("PADDLE_TPU_RANKSTAT_DIR", str(gather))
     monkeypatch.setenv("PADDLE_TRAINERS_NUM", "2")
-    now = __import__("time").time()
+    now = time.time()
     # rank 1: fresh, healthy. rank 5: outside the 2-rank world. rank 1
     # variant stale: a frozen slow snapshot from an hour ago
     (gather / "rankstat.1.json").write_text(json.dumps(
@@ -293,19 +273,6 @@ def test_gather_skips_stale_and_out_of_world_snapshots(
     evs = [e for e in flight_recorder.snapshot()["events"]
            if e.get("event") == "straggler"]
     assert evs == [], evs  # the phantom slow ranks were filtered out
-
-
-def test_post_probe_step_kept_out_of_step_time_reservoir(monkeypatch):
-    """The step after a probe has no meaningful interval — it must not
-    enter the train.step_s reservoir the rankstat p50/p99 come from."""
-    monkeypatch.setenv("PADDLE_TPU_DEVICE_TIME_EVERY", "2")
-    step, x = _make_step()
-    loss = None
-    for _ in range(6):  # probes at 2, 4; drained successors 3, 5
-        loss = step(x, x)
-    float(loss.item())
-    hist = monitor.get_metric("train.step_s")
-    assert hist.count == 4  # 6 steps minus the 2 post-probe successors
 
 
 def test_maybe_rankstat_cadence(monkeypatch):
@@ -346,16 +313,15 @@ def test_schema_rejects_bad_collective_and_rankstat(tmp_path):
              step_time_p50_s=0.01, step_time_p99_s=0.02,
              host_blocked_s=0.0, collective_wait_s=0.0,
              collective_wait_share=1.5, peak_bytes=0),
-        # probe fields on a step record: overlap out of range
+        # a step record: epilogue share out of range
         dict(base, kind="step", step=1, step_time_s=0.1, compile_s=0.0,
              cache_hit=True, peak_bytes=1, flops=1.0, mfu=0.1,
-             step_time_device_s=0.1, mfu_measured=0.2,
-             overlap_fraction=1.5),
+             epilogue_bytes=8, epilogue_share=1.5),
     ]) + "\n")
     errors = tool.validate_file(str(bad))
     for needle in ("bw_gbps", "bytes must be >= 0", "world_size",
                    "percentiles cannot invert",
-                   "collective_wait_share", "overlap_fraction"):
+                   "collective_wait_share", "epilogue_share"):
         assert any(needle in e for e in errors), (needle, errors)
 
 
@@ -452,7 +418,6 @@ def test_obs_report_renders_run_summary(tmp_path, monkeypatch):
     path = tmp_path / "m.jsonl"
     monkeypatch.setenv("PADDLE_TPU_METRICS_FILE", str(path))
     monkeypatch.setenv("PADDLE_TPU_COLLECTIVE_SAMPLE", "1")
-    monkeypatch.setenv("PADDLE_TPU_DEVICE_TIME_EVERY", "2")
     step, x = _make_step()
     loss = None
     for _ in range(4):
@@ -467,7 +432,7 @@ def test_obs_report_renders_run_summary(tmp_path, monkeypatch):
     recs = rep.load_records(str(path))
     text = rep.render(recs)
     assert "== training ==" in text
-    assert "measured device time" in text
+    assert "4 steps  wall" in text
     assert "== collectives ==" in text
     assert "all_reduce" in text
     assert "STRAGGLER rank 2" in text

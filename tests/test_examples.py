@@ -56,21 +56,3 @@ def test_train_vision_hapi():
     out = _run("train_vision_hapi.py", "--model", "resnet18",
                "--epochs", "1", "--batch", "32")
     assert "loss" in out or "acc" in out
-
-
-@pytest.mark.heavy
-def test_bench_decode():
-    out = _run("bench_decode.py")
-    assert "decode_tok_per_s" in out
-
-
-@pytest.mark.heavy
-def test_bench_bert():
-    out = _run("bench_bert.py")
-    assert "sequences_per_sec" in out
-
-
-@pytest.mark.heavy
-def test_bench_gpt_1p3b():
-    out = _run("bench_gpt_1p3b.py")
-    assert "tokens_per_sec" in out

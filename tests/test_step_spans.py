@@ -6,8 +6,8 @@ jax.profiler trace, on the thread that did the work:
 
 - `TrainStep.__call__` / `HybridTrainStep.__call__`: one parent
   (`train.step` / `fleet.hybrid_step`) and the children that cover it —
-  `train.step.prep`, `.probe` (only where the device-time probe is due),
-  `.dispatch`, `.telemetry`;
+  `train.step.prep`, `.dispatch`, `.telemetry`, and no call waits for
+  the device;
 - `GenerationEngine`'s scheduler thread: `serve.step` and its children,
   the step's sizes readable from the `serve.step.dispatch` event;
 - with no profiler session the aggregate tree and the ring hold the same
@@ -28,8 +28,8 @@ from paddle_tpu.jit import TrainStep
 from paddle_tpu.profiler import mem_observatory as mobs
 from paddle_tpu.profiler import statistic
 
-TRAIN_CHILDREN = ["train.step.prep", "train.step.probe",
-                  "train.step.dispatch", "train.step.telemetry"]
+TRAIN_CHILDREN = ["train.step.prep", "train.step.dispatch",
+                  "train.step.telemetry"]
 SERVE_CHILDREN = ["serve.step.admit", "serve.step.plan",
                   "serve.step.dispatch", "serve.step.fetch",
                   "serve.step.emit", "serve.step.telemetry"]
@@ -118,9 +118,7 @@ def ring_children(parent_name):
 
 
 # (a) ------------------------------------------------------------------
-def test_train_step_spans_are_host_events_of_a_profiler_trace(
-        tmp_path, monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_DEVICE_TIME_EVERY", "2")
+def test_train_step_spans_are_host_events_of_a_profiler_trace(tmp_path):
     step, x, y = make_step()
     float(step(x, y))                      # step 1 compiles, untraced
 
@@ -134,16 +132,11 @@ def test_train_step_spans_are_host_events_of_a_profiler_trace(
     assert len(fams) == 3
     assert [p[3]["step_num"] for p, _ in fams] == [2, 3, 4]
     for p, kids in fams:
-        due = p[3]["step_num"] % 2 == 0
-        assert [len(kids[c]) for c in TRAIN_CHILDREN] \
-            == [1, 2 if due else 0, 1, 1]
-        # in order on the parent's own thread: prep, the probe's drain,
-        # dispatch, the probe's close, telemetry
+        assert [len(kids[c]) for c in TRAIN_CHILDREN] == [1, 1, 1]
+        # in order on the parent's own thread, none inside another
         order = sorted((k for v in kids.values() for k in v),
                        key=lambda k: k[1])
-        assert [k[0] for k in order if k[0] != "train.step.probe"] \
-            == ["train.step.prep", "train.step.dispatch",
-                "train.step.telemetry"]
+        assert [k[0] for k in order] == TRAIN_CHILDREN
         assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
 
 
@@ -223,14 +216,12 @@ def test_without_a_profiler_session_tree_and_ring_hold_the_same_spans():
 
 
 # (d) ------------------------------------------------------------------
-def test_children_cover_the_train_step(monkeypatch):
+def test_children_cover_the_train_step():
     """What `train.step`'s children leave uncovered is the bookkeeping
-    between them, some tens of microseconds a call: a twentieth of a
-    call that does nothing else (the CPU's half millisecond), nothing of
-    one that waits for the device as a call on the chip does. The probe
-    on every second step makes these calls wait for a step of some
-    milliseconds, and then the children cover over 95% of the parent."""
-    monkeypatch.setenv("PADDLE_TPU_DEVICE_TIME_EVERY", "2")
+    between them, some tens of microseconds a call: a sixteenth of a
+    call on a model of no size (the CPU's 0.4 ms), under a hundredth
+    once the CPU's dispatch takes the milliseconds it takes at these
+    shapes."""
     step, x, y = make_step(hidden=2048, batch=2048)
     float(step(x, y))
     statistic.reset_statistics()
@@ -238,8 +229,8 @@ def test_children_cover_the_train_step(monkeypatch):
         loss = step(x, y)
     float(loss)
     fams = ring_children("train.step")[-24:]
-    assert sum(1 for _, kids in fams for k in kids
-               if k["name"] == "train.step.probe") == 24
+    assert all([k["name"] for k in kids] == TRAIN_CHILDREN
+               for _, kids in fams)
     whole = sum(p["dur_s"] for p, _ in fams)
     covered = sum(k["dur_s"] for _, kids in fams for k in kids)
     assert covered <= whole
@@ -250,17 +241,40 @@ def test_children_cover_the_train_step(monkeypatch):
 
 
 # (e) ------------------------------------------------------------------
-def test_probe_child_only_on_the_steps_the_probe_is_due(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_DEVICE_TIME_EVERY", "2")
-    step, x, y = make_step()
-    for _ in range(9):
+def make_hybrid_step():
+    from paddle_tpu.distributed.env import build_mesh
+    from paddle_tpu.distributed.fleet.hybrid_train import HybridTrainStep
+    paddle.seed(0)
+    m = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 4))
+    o = opt.AdamW(learning_rate=1e-3, parameters=m.parameters())
+    step = HybridTrainStep(
+        m, lambda a, b: nn.functional.mse_loss(a, b), o, build_mesh(dp=8))
+    rng = np.random.RandomState(0)
+    x = paddle.to_tensor(rng.randn(16, 8).astype(np.float32))
+    y = paddle.to_tensor(rng.randn(16, 4).astype(np.float32))
+    return step, x, y
+
+
+@pytest.mark.parametrize("make", [make_step, make_hybrid_step],
+                         ids=["TrainStep", "HybridTrainStep"])
+def test_no_call_into_the_step_waits_for_the_device(make, monkeypatch):
+    """Twenty calls, the first of which compiles: none asks JAX to wait
+    for an array or to fetch one."""
+    waits = []
+
+    def counted(name, real):
+        def call(*args, **kw):
+            waits.append(name)
+            return real(*args, **kw)
+        return call
+
+    for name in ("block_until_ready", "device_get"):
+        monkeypatch.setattr(jax, name, counted(name, getattr(jax, name)))
+    step, x, y = make()
+    for _ in range(20):
         loss = step(x, y)
+    assert waits == []
     float(loss)
-    probes = [sum(1 for k in kids if k["name"] == "train.step.probe")
-              for _, kids in ring_children("train.step")]
-    # step 1 compiles and has nothing to drain; then both blocking
-    # halves on every second step and none between
-    assert probes == [0, 2, 0, 2, 0, 2, 0, 2, 0]
 
 
 # (f) ------------------------------------------------------------------
@@ -308,23 +322,12 @@ def test_spans_balance_when_the_body_raises(exit_, tmp_path, monkeypatch):
 
 # the hybrid step: another parent, the same children ---------------------
 def test_hybrid_step_has_the_same_children_under_its_own_parent():
-    from paddle_tpu.distributed.env import build_mesh
-    from paddle_tpu.distributed.fleet.hybrid_train import HybridTrainStep
-    paddle.seed(0)
-    m = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 4))
-    o = opt.AdamW(learning_rate=1e-3, parameters=m.parameters())
-    step = HybridTrainStep(
-        m, lambda a, b: nn.functional.mse_loss(a, b), o, build_mesh(dp=8))
-    rng = np.random.RandomState(0)
-    x = paddle.to_tensor(rng.randn(16, 8).astype(np.float32))
-    y = paddle.to_tensor(rng.randn(16, 4).astype(np.float32))
+    step, x, y = make_hybrid_step()
     for _ in range(2):
         loss = step(x, y)
     float(loss)
     fams = ring_children("fleet.hybrid_step")
     assert len(fams) == 2
     for _, kids in fams:
-        assert [k["name"] for k in kids] == [
-            "train.step.prep", "train.step.dispatch",
-            "train.step.telemetry"]
+        assert [k["name"] for k in kids] == TRAIN_CHILDREN
     assert not ring_children("train.step")

@@ -22,9 +22,7 @@ Proof points:
   per-thread cache events + a claimed-entries ledger);
 - tools/check_metrics_schema.py validates (and rejects malformed)
   warm/seed records; tools/check_compile_budget.py gates the warm-set
-  wall-clock against BASELINE_HLO.json and only ever ratchets tighter;
-- bench.py seeds from BENCH_CACHE_SEED (pure file copies in the
-  parent) and rolls unused attempt budget over.
+  wall-clock against BASELINE_HLO.json and only ever ratchets tighter.
 """
 import importlib.util
 import json
@@ -511,94 +509,21 @@ def test_gate_common_load_warm_record(tmp_path):
     assert gc.load_warm_record(str(p2)) is None
 
 
-# ------------------------------------------------------- bench plumbing
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_seed_cache_copies_entries(tmp_path, monkeypatch):
-    """bench's parent-side seeding is pure file copies (no jax import):
-    entries land in the cache dir, existing entries are skipped, pack
-    metadata is excluded, and a bad source degrades to a note."""
-    bench = _load_bench()
+def test_copy_seed_entries_skips_what_is_there_and_what_is_no_entry(
+        tmp_path):
+    """Seeding is idempotent file copies: entries land in the cache dir,
+    a second seed skips them all, and a pack's manifest, hidden files
+    and directories are no entries."""
     src = tmp_path / "artifact"
     src.mkdir()
     (src / "abc-cache").write_bytes(b"x" * 64)
     (src / "def-cache").write_bytes(b"y" * 64)
     (src / "MANIFEST.json").write_text("{}")
     (src / ".hidden").write_text("no")
+    (src / "subdir").mkdir()
     dst = tmp_path / "cache"
-    monkeypatch.setattr(bench, "_CACHE_DIR", str(dst))
-    monkeypatch.setenv("BENCH_CACHE_SEED", str(src))
-    info = bench._seed_cache()
-    assert info["entries_seeded"] == 2 and info["entries_skipped"] == 0
+    assert compile_cache.copy_seed_entries(str(src), str(dst)) == (2, 0)
     assert sorted(os.listdir(dst)) == ["abc-cache", "def-cache"]
-    # idempotent: a second seed skips everything
-    info = bench._seed_cache()
-    assert info["entries_seeded"] == 0 and info["entries_skipped"] == 2
-    # unset -> no-op; bad dir -> error note, never a raise
-    monkeypatch.delenv("BENCH_CACHE_SEED")
-    assert bench._seed_cache() is None
-    monkeypatch.setenv("BENCH_CACHE_SEED", str(tmp_path / "missing"))
-    info = bench._seed_cache()
-    assert "error" in info and info["entries_seeded"] == 0
-
-
-@pytest.mark.heavy
-def test_bench_headline_carries_trajectory_and_seed(tmp_path):
-    """A full CPU bench run with BENCH_CACHE_SEED: the merged headline
-    must carry cache_seeded, the per-attempt compile trajectory, the
-    cross-round compile history, and the warm-set keys."""
-    src = tmp_path / "artifact"
-    src.mkdir()                       # empty artifact: seeded=0 entries
-    env = dict(os.environ)
-    env.update({"JAX_PLATFORMS": "cpu",
-                "PYTHONUNBUFFERED": "1", "BENCH_1P3B": "0",
-                "PADDLE_TPU_COMPILE_CACHE": str(tmp_path / "xla_cache"),
-                "BENCH_CACHE_SEED": str(src),
-                "BENCH_TOTAL_BUDGET": "150"})
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run(
-        [sys.executable, "-u", os.path.join(REPO, "bench.py")], env=env,
-        timeout=170, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-        text=True)
-    assert out.returncode == 0
-    final = json.loads([l for l in out.stdout.splitlines()
-                        if l.startswith("{")][-1])
-    assert final["value"] > 0
-    assert final["cache_seeded"] is False       # empty artifact
-    assert final["cache_seed"]["entries_seeded"] == 0
-    assert final["warm_wall_s"] >= 0
-    assert final["warm_sum_s"] >= 0
-    traj = final["compile_trajectory"]
-    assert len(traj) >= 1
-    assert traj[0]["attempt"].startswith("scan=1")  # scan-first default
-    assert traj[0]["rc"] == "ok"
-    assert traj[0]["compile_s"] > 0
-    hist = final["compile_history"]
-    assert hist[-1]["attempts"][0]["compile_s"] == traj[0]["compile_s"]
-    # the trajectory persists across rounds in bench_state.json
-    state = json.loads(
-        (tmp_path / "xla_cache" / "bench_state.json").read_text())
-    assert state["compile_history"][-1]["attempts"][0]["attempt"] \
-        == traj[0]["attempt"]
-
-
-def test_bench_attempt_budget_rolls_over():
-    """bench._attempt_budget: a fast first attempt's unused budget
-    funds the second attempt past the fixed per-attempt cap, and the
-    total-budget fence always wins."""
-    bench = _load_bench()
-    # attempt 1: plenty of total budget -> the cap, no carry yet
-    budget1 = bench._attempt_budget(300.0, 0.0, 500.0)
-    assert budget1 == 300.0
-    carry = max(0.0, budget1 - 40.0)      # finished in 40s
-    # attempt 2: cap + carry, exceeding the old fixed split
-    budget2 = bench._attempt_budget(300.0, carry, 460.0)
-    assert budget2 == 430.0 > 300.0
-    # the 30s merge fence caps everything near the end of the window
-    assert bench._attempt_budget(300.0, 260.0, 100.0) == 70.0
+    assert compile_cache.copy_seed_entries(str(src), str(dst)) == (0, 2)
+    with pytest.raises(ValueError, match="not a directory"):
+        compile_cache.seed_from(str(tmp_path / "missing"))

@@ -3,7 +3,7 @@
 
 The per-step metrics file (PADDLE_TPU_METRICS_FILE, written by
 paddle_tpu/profiler/monitor.py export_step) is a contract between the
-framework, bench.py, and whatever driver/dashboard tails it. This tool
+framework and whatever driver/dashboard tails it. This tool
 is the contract's enforcement point: tests/test_telemetry.py runs it on
 a freshly emitted file, so the schema can't silently drift.
 
@@ -33,9 +33,7 @@ Schema (documented in docs/OBSERVABILITY.md):
                   paddle_tpu/inference/serving.py) additionally requires:
                   engine       str     emitting engine's name (non-empty;
                                        the per-engine key that keeps
-                                       multi-engine JSONL attributable —
-                                       bench.py --serve runs several
-                                       engines in one process)
+                                       multi-engine JSONL attributable)
                   requests     int     requests fused into the batch (>= 1)
                   batch_size   int     real rows dispatched (>= 1)
                   bucket_batch int     ladder bucket the batch padded to
@@ -136,15 +134,6 @@ Schema (documented in docs/OBSERVABILITY.md):
                   clock_offset_s number  this rank's clock offset vs
                                        rank 0 (any sign)
                   steps_observed int   >= 0
-  kind == "step" optional measured-device-time fields (the sampled
-                  probe, PADDLE_TPU_DEVICE_TIME_EVERY):
-                  step_time_device_s number >= 0 measured drain->ready
-                                       window
-                  mfu_measured number  >= 0, finite — cost-analysis
-                                       FLOPs over MEASURED device time
-                  overlap_fraction number in [0, 1] — share of the
-                                       window not spent in eager
-                                       collective waits
   kind == "event" (structured anomaly/lifecycle events —
                   profiler/flight_recorder.record_event) additionally
                   requires:
@@ -814,30 +803,11 @@ def validate_line(line, where="<line>"):
                 errors.append(
                     f"{where}: epilogue_share must be a number in "
                     f"[0, 1], got {v!r}")
-        # measured-device-time probe fields (optional — the sampled
-        # probe stamps them on the step it measured)
-        for key in ("step_time_device_s", "mfu_measured"):
-            if key in rec:
-                v = rec[key]
-                if not isinstance(v, (int, float)) or \
-                        isinstance(v, bool) or v < 0 or \
-                        not math.isfinite(v):
-                    errors.append(
-                        f"{where}: {key} must be a finite number >= 0, "
-                        f"got {v!r}")
-        if "overlap_fraction" in rec:
-            v = rec["overlap_fraction"]
-            if not isinstance(v, (int, float)) or isinstance(v, bool) \
-                    or not (0.0 <= v <= 1.0):
-                errors.append(
-                    f"{where}: overlap_fraction must be a number in "
-                    f"[0, 1], got {v!r}")
     elif rec.get("kind") == "serve":
         _check_types(rec, SERVE_REQUIRED, where, errors)
         _cache_strategy(rec, where, errors)
         # engine is REQUIRED and non-empty: it is the only key that
-        # keeps multi-engine JSONL attributable (bench.py --serve runs
-        # both engine paths in one process)
+        # keeps multi-engine JSONL attributable
         if isinstance(rec.get("engine"), str) and not rec["engine"]:
             errors.append(
                 f"{where}: engine must be a non-empty string, "

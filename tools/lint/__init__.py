@@ -1,7 +1,7 @@
 """paddlelint — the repo's concurrency + tracing-safety static
 analyzer (driver: tools/paddlelint.py, docs: docs/STATIC_ANALYSIS.md).
 
-Five passes over `paddle_tpu/` + `tools/` + `bench.py`, each
+Five passes over `paddle_tpu/` + `tools/`, each
 mechanizing a bug class the PR 8-12 review-hardening logs kept
 finding by hand:
 

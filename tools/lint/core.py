@@ -841,7 +841,7 @@ EXCLUDE_DIRS = {"__pycache__", ".git", "fixtures"}
 
 def default_fileset(root):
     """The analyzed set: paddle_tpu/**, tools/** (the linter's own
-    fixtures excluded — they are known-bad on purpose), bench.py."""
+    fixtures excluded — they are known-bad on purpose)."""
     rels = []
     for top in ("paddle_tpu", "tools"):
         base = os.path.join(root, top)
@@ -852,8 +852,6 @@ def default_fileset(root):
                 if fn.endswith(".py"):
                     rels.append(os.path.relpath(
                         os.path.join(dirpath, fn), root))
-    if os.path.isfile(os.path.join(root, "bench.py")):
-        rels.append("bench.py")
     return rels
 
 

@@ -37,11 +37,6 @@ HOT_REGIONS = {
     "paddle_tpu/jit/api.py": [
         "TrainStep.__call__", "TrainStep._prep", "TrainStep._dispatch",
         "TrainStep.accumulate", "TrainStep.run_steps",
-        # the device-time probe (distributed observatory): its TWO
-        # blocking reads are the measurement itself — cadence-gated
-        # (PADDLE_TPU_DEVICE_TIME_EVERY) and explicitly hot-sync-ok
-        # marked; fencing the functions keeps anything else out
-        "device_probe_open", "device_probe_close",
         # the checkpoint snapshot hook: on-device buffer copies only —
         # the blocking device read belongs to the background writer
         # (distributed/checkpoint.py _write_one), never the step loop

@@ -2,7 +2,7 @@
 """Open-loop load harness for the serving front door
 (docs/SERVING.md, docs/OBSERVABILITY.md "The fleet observatory").
 
-Closed-loop clients (bench.py --serve) hide overload: a slow fleet
+Closed-loop clients hide overload: a slow fleet
 slows its own offered load, so attainment looks fine right up to the
 cliff. This harness is OPEN-LOOP — the arrival schedule is generated
 up front (seeded, deterministic) and the submit thread walks it by the
@@ -40,10 +40,6 @@ plain, once with a SpeculativeConfig threaded through the router
 (docs/SERVING.md "Speculative decoding") — and prints both goodputs
 next to the fleet accept rate, so burst-regime speculation overhead
 is measured against an identical arrival schedule.
-
-`bench.py --serve` runs the same harness as its load stage
-(BENCH_SERVE_LOAD=0 skips) and persists the headline numbers in
-serve_history.
 """
 import argparse
 import json

@@ -73,16 +73,6 @@ def section_steps(recs, out):
             f"  compile {compile_s:.2f}s")
         if mfus:
             out.append(f"  mfu (cost analysis, last): {mfus[-1]:.4f}")
-        probes = [r for r in steps if "step_time_device_s" in r]
-        if probes:
-            dts = sorted(float(r["step_time_device_s"]) for r in probes)
-            mm = [float(r.get("mfu_measured", 0.0)) for r in probes]
-            ov = [float(r.get("overlap_fraction", 0.0)) for r in probes]
-            out.append(
-                f"  measured device time ({len(probes)} probes): "
-                f"p50 {_fmt_s(_pct(dts, 50))}  "
-                f"mfu_measured {_pct(sorted(mm), 50):.4f}  "
-                f"overlap {_pct(sorted(ov), 50):.3f}")
     if scans:
         n = sum(int(r.get("steps", 0)) for r in scans)
         out.append(f"  {len(scans)} scanned segments ({n} steps)")

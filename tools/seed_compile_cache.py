@@ -21,8 +21,7 @@ Usage:
       Copy SOURCE's entries (a pack artifact or any raw cache dir) into
       the active cache, skipping entries already present.
 
-bench.py seeds automatically when BENCH_CACHE_SEED names an artifact
-dir; in-process, `paddle_tpu.framework.compile_cache.seed_from()` does
+In-process, `paddle_tpu.framework.compile_cache.seed_from()` does
 the same and emits a `kind:"seed"` metrics record.
 
 Exit 0 on success, 2 on a bad source/cache.
