@@ -256,8 +256,12 @@ class GPTAttention(nn.Layer):
     def forward(self, x, cache=None):
         B, T, H = x.shape
         qkv = _ckpt_name(self.qkv_proj(x), "gpt_qkv")
-        qkv = qkv.reshape([B, T, 3, self.num_heads, self.head_dim])
-        q, k, v = qkv.unbind(axis=2)
+        # three lane-aligned slices of [B, T, 3H], each then viewed by head
+        # for free; cut out of a [B, T, 3, heads, head_dim] view they cost
+        # a transposed copy of the whole of qkv each way on the chip
+        from ..tensor.manipulation import split
+        q, k, v = (t.reshape([B, T, self.num_heads, self.head_dim])
+                   for t in split(qkv, 3, axis=-1))
         if isinstance(cache, StaticCacheSlot):
             return self._forward_static_cache(x, q, k, v, cache)
         if isinstance(cache, RaggedJitSlot):

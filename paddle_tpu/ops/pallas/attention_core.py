@@ -30,11 +30,14 @@ import typing
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .common import NEG_INF
 
 # the MXU is a 128x128 systolic array: a score dot wants 128 query rows
 MXU_ROWS = 128
+# a register, and a tile in memory, is 128 lanes wide
+LANES = 128
 # f32 tiles are (8, 128): a dot with M < 8 pads the sublane dimension
 # with zeros — the hard floor the dot-shape gate enforces
 MIN_DOT_ROWS = 8
@@ -137,6 +140,22 @@ def choose_flash_blocks(t_q, t_k, d):
     return FlashBlocks(bq, bk, **tiles)
 
 
+def heads_per_block(heads, d):
+    """g: how many heads ONE lane block of a [B, T, heads * d] array
+    holds, so that the training kernels pick heads by a BlockSpec index
+    and the array needs no transpose — a function of the shape alone. A
+    block's minor dimension must be a multiple of LANES or the whole
+    array's: g = 1 where d is such a multiple (head dims 128, 256) or
+    there is one head; LANES // d heads side by side where d divides
+    LANES and g divides the head count (two at head dim 64); None where
+    no block fits (head dims 80, 96; five heads of 64), and the caller
+    folds the heads into the batch."""
+    if heads == 1 or d % LANES == 0:
+        return 1
+    g = LANES // d
+    return g if LANES % d == 0 and heads % g == 0 else None
+
+
 def _tiles_in(x, t, n, up=False):
     """How many whole tiles of width t fit in x (`up`: are touched by
     x), held to [0, n]."""
@@ -161,6 +180,24 @@ def causal_q_tiles(col0, tk, tq, n):
     rest hold no masked element — the dkv kernel's extents, the
     transpose of causal_kv_tiles."""
     return _tiles_in(col0, tq, n), _tiles_in(col0 + tk - 1, tq, n, up=True)
+
+
+def last_kv_block(i, block_q, block_k):
+    """The last kv GRID block that q grid block i sees under the causal
+    mask (row >= column): the one its last row's own column lies in.
+    For an index map: `i` is a traced int32 and so is every constant
+    (the package runs in x64 mode, and Mosaic takes no i64)."""
+    i32 = np.int32
+    return jax.lax.div(i * i32(block_q) + i32(block_q - 1), i32(block_k))
+
+
+def first_q_block(j, block_q, block_k, n_q):
+    """The first q GRID block, of n_q, that sees kv grid block j under
+    the causal mask: the one the block's first column's own row lies
+    in; the last one where no q block sees it (Tk > Tq)."""
+    i32 = np.int32
+    return jax.lax.min(jax.lax.div(j * i32(block_k), i32(block_q)),
+                       i32(n_q - 1))
 
 
 def visited_tile_share(t_q, t_k, tiles, causal):

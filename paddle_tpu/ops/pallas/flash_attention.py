@@ -17,8 +17,10 @@ iota, compare or select on the rest. The extents are Python ints
 (attention_core.causal_kv_tiles / causal_q_tiles), so the diagonal must
 stand at a static place in the block: a SQUARE block it crosses can
 only stand on it (offset 0) and has that body under pl.when; blocks
-wholly below the diagonal run unmasked, blocks above it run nothing,
-and a crossed block that is not square (head dims over 64, causal with
+wholly below the diagonal run unmasked, blocks above it run nothing
+(and move nothing: the index maps hold such a step at the last block
+that was computed, and the pipeline copies no block twice), and a
+crossed block that is not square (head dims over 64, causal with
 Tq != Tk) goes whole under the mask. The strips are unrolled so that
 the scheduler overlaps them; the same schedule as rolled loops over
 pl.ds sub-tiles ran 2 to 5 times slower on the chip (PERF.md section 6,
@@ -45,11 +47,28 @@ scan_remat="names", models/decoder.py) runs the forward kernel once a
 layer; under any other policy, or none, the names are identity ops.
 
 Layout contract: q, k, v are [batch, seq, heads, head_dim] (the
-framework's fused-attention layout); internally folded to [B*H, T, D].
-The output is un-folded INSIDE the custom_vjp, so the saved `out` is the
-[B, T, H*D] value the model consumes: full lanes at any head dim (a
-[B*H, T, 64] bf16 array is stored with its lanes padded to 128, twice
-the bytes) and nothing to transpose when the layer is recomputed.
+framework's fused-attention layout), and the kernels take them AS THEY
+LIE, as [B, T, H*D] (a free reshape), and write out, dq, dk, dv the same
+way: a head is picked by the BlockSpec index map, not by a transpose.
+The grid is (batch, head group, q block, kv block) and a block is
+(1, block_q, g*D) at (b, i, group): g = attention_core.heads_per_block,
+a function of the shape alone. One head where D is a multiple of 128;
+two side by side where D = 64, the lanes of one head zeroed in the strip
+that enters a score dot (the zeros add nothing to a 128-deep contraction,
+which takes the MXU the passes a 64-deep one takes) and each head's half
+of a [rows, 128] product picked by a lane select: the matmul count of
+two heads, half the bytes of a [B*H, T, 64] array (stored with its lanes
+padded to 128), and none of the 16 transposing copies a layer that the
+fold cost GPT-medium's step. lse and delta stay [B*H, 1, T], a block of
+g rows; delta = rowsum(out * dout) is made inside the dq kernel, from
+the out and dout blocks as they lie, and handed to dkv. Shapes no lane block
+fits (head dims 80 or 96, an odd head count at 64) have their heads
+folded into the batch, [B*H, T, D]: the same kernels with one head, a
+transposing copy of every array each way. Either way the saved `out` is
+the [B, T, H*D] value the model consumes. profiler.monitor counts which
+a traced call got (`flash.calls.direct`, `.direct.g<g>`,
+`flash.calls.folded`) and the call's ops stand under a scope of that
+name.
 The causal mask is top-left aligned (row >= column).
 """
 import functools
@@ -70,19 +89,30 @@ DIAGONAL = "diagonal"  # a square block ON it: the strips' extents are static
 CROSSED = "crossed"    # any other block it crosses: all of it under the mask
 
 
+def _block_offset(iq, ik, block_q, block_k, lone):
+    """First row - first column of this grid step's block: traced, or
+    the Python int 0 where the block is the `lone` one of its grid."""
+    return 0 if lone else iq * block_q - ik * block_k
+
+
 def _on_diagonal_position(causal, offset, block_q, block_k, body):
-    """Run body(where) for this grid step. `offset` is the (traced)
-    distance first row - first column of its block. Without a mask, or
-    wholly below the diagonal: body(WHOLE). Crossed by it: a square
-    block can only stand at offset 0, so what each strip sees is a
-    Python int — body(DIAGONAL); blocks that are not square (causal with
-    Tq != Tk, head dims over 64) take the whole block under the mask —
-    body(CROSSED). Wholly above: nothing runs."""
+    """Run body(where) for this grid step. `offset` is the distance
+    first row - first column of its block. Without a mask, or wholly
+    below the diagonal: body(WHOLE). Crossed by it: a square block can
+    only stand at offset 0, so what each strip sees is a Python int —
+    body(DIAGONAL); blocks that are not square (causal with Tq != Tk,
+    head dims over 64) take the whole block under the mask —
+    body(CROSSED). Wholly above: nothing runs. A lone block (offset the
+    int 0: sequences up to 1024) is always the crossed one, and no
+    other body is built for it: half the kernel to trace and lower."""
+    crossed = DIAGONAL if block_q == block_k else CROSSED
     if not causal:
         return body(WHOLE)
+    if isinstance(offset, int):
+        return body(crossed)
     pl.when(offset >= block_k - 1)(functools.partial(body, WHOLE))
-    pl.when((offset > -block_q) & (offset < block_k - 1))(functools.partial(
-        body, DIAGONAL if block_q == block_k else CROSSED))
+    pl.when((offset > -block_q) & (offset < block_k - 1))(
+        functools.partial(body, crossed))
 
 
 def _extent(where, start, t, u, n, of_rows):
@@ -128,6 +158,31 @@ def _f32(ref):
     return ref[0].astype(jnp.float32)
 
 
+def _head_lanes(x, h, d):
+    """x [rows, g*d] with the lanes of every head but h zeroed; g = 1:
+    x itself. A dot that contracts the lanes of it contracts head h's d
+    alone: the zeros add nothing, and a 128-deep contraction takes the
+    MXU the passes a 64-deep one takes."""
+    if x.shape[-1] == d:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= h * d) & (lane < (h + 1) * d), x,
+                     jnp.zeros_like(x))
+
+
+def _join_heads(parts, d):
+    """parts[h] [rows, g*d], each right in head h's lanes alone (a dot
+    against a whole [extent, g*d] block yields the other heads' lanes
+    beside them, in the same passes) -> the [rows, g*d] tile that takes
+    every head's lanes from its own part."""
+    out = parts[-1]
+    if len(parts) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        for h in range(len(parts) - 2, -1, -1):
+            out = jnp.where(lane < (h + 1) * d, parts[h], out)
+    return out
+
+
 def _along_lanes(col):
     """[t, 1] -> [1, t]. As the transpose of the column spread over one
     register's lanes it goes through the transpose unit for nearly
@@ -141,91 +196,131 @@ def _along_lanes(col):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry_refs, scale,
-                causal, block_q, block_k, tiles):
-    """carry_refs (m, l, acc) hold the online softmax between kv grid
-    steps; with ONE kv block there is nothing to hold and none are
+                causal, block_q, block_k, tiles, heads, lone):
+    """One grid step: a [block_q, heads * d] block of q — `heads` heads
+    side by side in its lanes, as the model's [B, T, H*D] holds them —
+    against a kv block of the same heads. carry_refs (m, l, acc), each
+    with a leading axis of `heads`, hold the online softmax between kv
+    grid steps; with ONE kv block there is nothing to hold and none are
     passed: a strip's softmax is born and finalized in place. The mask
     needs no zeroing of probabilities here: every row sees column 0, in
     the first kv block, so no row meets a later block untouched."""
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+    nk = pl.num_programs(3)
     tq, tk = tiles
-    d = q_ref.shape[-1]
-    offset = iq * block_q - ik * block_k
+    w = q_ref.shape[-1]
+    d = w // heads
+    offset = _block_offset(iq, ik, block_q, block_k, lone)
 
-    fresh = functools.partial(core.softmax_carry, d=d, column=True)
+    fresh = functools.partial(core.softmax_carry, d=w, column=True)
 
     if carry_refs:
         @pl.when(ik == 0)
         def _init():
             for ref, x in zip(carry_refs, fresh(block_q)):
-                ref[:] = x
+                for h in range(heads):
+                    ref[h] = x
 
     def _body(where):
-        v = _f32(v_ref)                             # [bk, d], for p.v
+        v = _f32(v_ref)                             # [bk, w], for p.v
         for i in range(block_q // tq):
             rows = slice(i * tq, (i + 1) * tq)
             ext = _extent(where, i * tq, tq, tk, block_k // tk, True)
             lo, hi = ext[:2]
-            s = core.score_dot(q_ref[0, rows, :], k_ref[0, lo:hi, :],
-                               scale)               # [tq, hi - lo]
-            s = _mask_crossed(s, ext, lambda shape, col: core.causal_valid(
+            # every head of the block sees the same mask: made once
+            valid = functools.cache(lambda shape, col: core.causal_valid(
                 offset + i * tq, col, shape))
-            carry = tuple(ref[rows] for ref in carry_refs) or fresh(tq)
-            carry = core.softmax_update(*carry, s, v[lo:hi])
-            if carry_refs:
-                for ref, x in zip(carry_refs, carry):
-                    ref[rows] = x
-            else:
-                out, lse = core.softmax_finalize(*carry)
-                o_ref[0, rows, :] = out.astype(o_ref.dtype)
-                lse_ref[0, :, rows] = _along_lanes(lse)
+            outs = []
+            for h in range(heads):
+                s = core.score_dot(_head_lanes(q_ref[0, rows, :], h, d),
+                                   k_ref[0, lo:hi, :], scale)
+                s = _mask_crossed(s, ext, valid)    # [tq, hi - lo]
+                carry = tuple(ref[h, rows] for ref in carry_refs) \
+                    or fresh(tq)
+                carry = core.softmax_update(*carry, s, v[lo:hi])
+                if carry_refs:
+                    for ref, x in zip(carry_refs, carry):
+                        ref[h, rows] = x
+                else:
+                    out, lse = core.softmax_finalize(*carry)
+                    outs.append(out)
+                    lse_ref[h, :, rows] = _along_lanes(lse)
+            if outs:
+                o_ref[0, rows, :] = _join_heads(outs, d).astype(o_ref.dtype)
 
     _on_diagonal_position(causal, offset, block_q, block_k, _body)
 
     if carry_refs:
         @pl.when(ik == nk - 1)
         def _finalize():
-            out, lse = core.softmax_finalize(*(r[:] for r in carry_refs))
-            o_ref[0] = out.astype(o_ref.dtype)
-            lse_ref[0] = _along_lanes(lse)
+            outs = []
+            for h in range(heads):
+                out, lse = core.softmax_finalize(*(r[h] for r in carry_refs))
+                outs.append(out)
+                lse_ref[h] = _along_lanes(lse)
+            o_ref[0] = _join_heads(outs, d).astype(o_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               *acc_ref, scale, causal, block_q, block_k, tiles):
-    """acc_ref: the f32 dq between kv grid steps; none with one kv
-    block."""
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+               delta_ref, *acc_ref, scale, causal, block_q, block_k, tiles,
+               heads, lone):
+    """delta = rowsum(out * dout) a head is made HERE, from the out and
+    dout blocks as they lie, and is an output too, which dkv reads. With
+    ONE kv block each strip makes its own as it goes (a column, as the
+    strip uses it, and work the scheduler puts beside the other strips'
+    dots); with several, a q block's first kv step makes the block's
+    before anything else and the strips read it back. acc_ref: the f32
+    dq between kv grid steps; none with one kv block."""
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+    nk = pl.num_programs(3)
     tq, tk = tiles
-    offset = iq * block_q - ik * block_k
+    d = q_ref.shape[-1] // heads
+    offset = _block_offset(iq, ik, block_q, block_k, lone)
+    strips = [slice(i * tq, (i + 1) * tq) for i in range(block_q // tq)]
+
+    def delta_of(rows):
+        """[tq, 1] a head, and written along delta_ref's lanes."""
+        prod = (o_ref[0, rows, :].astype(jnp.float32)
+                * do_ref[0, rows, :].astype(jnp.float32))
+        cols = [jnp.sum(_head_lanes(prod, h, d), axis=1, keepdims=True)
+                for h in range(heads)]
+        for h, col in enumerate(cols):
+            delta_ref[h, :, rows] = _along_lanes(col)
+        return cols
 
     if acc_ref:
         @pl.when(ik == 0)
         def _init():
+            for rows in strips:
+                delta_of(rows)
             acc_ref[0][:] = jnp.zeros_like(acc_ref[0])
 
     def _body(where):
-        k32 = _f32(k_ref)                           # [bk, d], for ds.k
-        for i in range(block_q // tq):
-            rows = slice(i * tq, (i + 1) * tq)
+        k32 = _f32(k_ref)                           # [bk, w], for ds.k
+        for i, rows in enumerate(strips):
             ext = _extent(where, i * tq, tq, tk, block_k // tk, True)
             lo, hi = ext[:2]
-            s = core.score_dot(q_ref[0, rows, :], k_ref[0, lo:hi, :],
-                               scale)               # [tq, hi - lo]
-            s = _mask_crossed(s, ext, lambda shape, col: core.causal_valid(
+            valid = functools.cache(lambda shape, col: core.causal_valid(
                 offset + i * tq, col, shape))
-            p = jnp.exp(s - lse_ref[0, 0, rows][:, None])
-            dp = jax.lax.dot_general(
-                do_ref[0, rows, :], v_ref[0, lo:hi, :],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, 0, rows][:, None])
-            dq = jax.lax.dot_general(
-                ds, k32[lo:hi], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * jnp.float32(scale)
+            delta = [delta_ref[h, 0, rows][:, None] for h in range(heads)] \
+                if acc_ref else delta_of(rows)
+            dqs = []
+            for h in range(heads):
+                s = core.score_dot(_head_lanes(q_ref[0, rows, :], h, d),
+                                   k_ref[0, lo:hi, :], scale)
+                s = _mask_crossed(s, ext, valid)    # [tq, hi - lo]
+                p = jnp.exp(s - lse_ref[h, 0, rows][:, None])
+                dp = jax.lax.dot_general(
+                    _head_lanes(do_ref[0, rows, :], h, d),
+                    v_ref[0, lo:hi, :], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds = p * (dp - delta[h])
+                dqs.append(jax.lax.dot_general(
+                    ds, k32[lo:hi], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            dq = _join_heads(dqs, d) * jnp.float32(scale)
             if acc_ref:
                 acc_ref[0][rows] += dq
             else:
@@ -241,7 +336,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, *acc_refs, scale, causal, block_q, block_k,
-                tiles):
+                tiles, heads, lone):
     """Strips of kv columns, computed TRANSPOSED, [tk, rows]: lse and
     delta then broadcast along the lanes they are stored in, and all
     four dots are A.B or A.B^T — none contracts over its left operand's
@@ -249,11 +344,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     where one q block sees every kv column. (Where it does not — causal
     with Tk > Tq — kv blocks wholly above the diagonal run nothing, and
     the sums, zeroed at the first step, are what writes their zeros.)"""
-    ik = pl.program_id(1)
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
+    ik = pl.program_id(2)
+    iq = pl.program_id(3)
+    nq = pl.num_programs(3)
     tq, tk = tiles
-    offset = iq * block_q - ik * block_k
+    d = q_ref.shape[-1] // heads
+    offset = _block_offset(iq, ik, block_q, block_k, lone)
 
     if acc_refs:
         @pl.when(iq == 0)
@@ -262,27 +358,31 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 ref[:] = jnp.zeros_like(ref)
 
     def _body(where):
-        q32 = _f32(q_ref)                           # [bq, d], for ds^T.q
+        q32 = _f32(q_ref)                           # [bq, w], for ds^T.q
         do32 = _f32(do_ref)                         # for p^T.do
         for j in range(block_k // tk):
             cols = slice(j * tk, (j + 1) * tk)
             ext = _extent(where, j * tk, tk, tq, block_q // tq, False)
             lo, hi = ext[:2]
-            st = core.score_dot(k_ref[0, cols, :], q_ref[0, lo:hi, :],
-                                scale)              # [tk, hi - lo]
-            st = _mask_crossed(st, ext, lambda shape, row: core.causal_valid(
+            valid = functools.cache(lambda shape, row: core.causal_valid(
                 offset + row, j * tk, shape, row_axis=1))
-            pt = jnp.exp(st - lse_ref[0, :, lo:hi])
-            dpt = jax.lax.dot_general(
-                v_ref[0, cols, :], do_ref[0, lo:hi, :],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dst = pt * (dpt - delta_ref[0, :, lo:hi])
-            dk, dv = (jax.lax.dot_general(
-                a, b[lo:hi], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [tk, d]
-                for a, b in ((dst, q32), (pt, do32)))
-            dk = dk * jnp.float32(scale)
+            dks, dvs = [], []
+            for h in range(heads):
+                st = core.score_dot(_head_lanes(k_ref[0, cols, :], h, d),
+                                    q_ref[0, lo:hi, :], scale)
+                st = _mask_crossed(st, ext, valid)  # [tk, hi - lo]
+                pt = jnp.exp(st - lse_ref[h, :, lo:hi])
+                dpt = jax.lax.dot_general(
+                    _head_lanes(v_ref[0, cols, :], h, d),
+                    do_ref[0, lo:hi, :], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dst = pt * (dpt - delta_ref[h, :, lo:hi])
+                for parts, a, b in ((dks, dst, q32), (dvs, pt, do32)):
+                    parts.append(jax.lax.dot_general(
+                        a, b[lo:hi], (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))  # [tk, w]
+            dk = _join_heads(dks, d) * jnp.float32(scale)
+            dv = _join_heads(dvs, d)
             if acc_refs:
                 acc_refs[0][cols] += dk
                 acc_refs[1][cols] += dv
@@ -300,131 +400,168 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
 
 def _fold(x, heads):
-    """[B, T, H*D] (or [B, T, H, D]) -> the kernels' [B*H, T, D]."""
+    """[B, T, H*D] -> [B*H, T, D]: every head a batch row of its own."""
     B, T = x.shape[:2]
     x = x.reshape(B, T, heads, -1)
     return jnp.swapaxes(x, 1, 2).reshape(B * heads, T, x.shape[-1])
 
 
 def _unfold(x, heads):
-    """The kernels' [B*H, T, D] -> [B, T, H*D]."""
+    """[B*H, T, D] -> [B, T, H*D]."""
     BH, T, D = x.shape
     x = x.reshape(BH // heads, heads, T, D)
     return jnp.swapaxes(x, 1, 2).reshape(BH // heads, T, heads * D)
 
 
+def _in_kernel_layout(heads, xs):
+    """(arrays, heads, g, back) for [B, T, H*D] arrays `xs`: as they lie,
+    g heads to a lane block, where attention_core.heads_per_block finds
+    a block that picks heads out of the lanes; else with the heads
+    FOLDED into the batch ([B*H, T, D]: one head, whose block is the
+    whole minor dimension), which costs a transposing copy of every
+    array each way. back() returns a [.., T, heads * D] result of the
+    kernels to [B, T, H*D]."""
+    g = core.heads_per_block(heads, xs[0].shape[-1] // heads)
+    if g is None:
+        return ([_fold(x, heads) for x in xs], 1, 1,
+                functools.partial(_unfold, heads=heads))
+    return xs, heads, g, lambda x: x
+
+
+def _index_maps(groups, causal, blocks, n_q, kv_major=False):
+    """(row, col, stat) index maps over the grid (batch, head group, q
+    block, kv block) — dkv's grid, `kv_major`, has the last two swapped:
+    blocks of q-like arrays [B, Tq, H*D], of kv-like ones, and of the
+    row statistics [B*H, 1, Tq], whose head is a row of the first axis.
+    A grid step the causal mask leaves nothing of runs nothing, and
+    should move nothing either: along the grid's inner axis its index
+    is held at the nearest block that IS computed
+    (attention_core.last_kv_block / first_q_block), and the pipeline
+    starts no copy for an index that did not change."""
+    bq, bk = blocks.block_q, blocks.block_k
+    if not causal:
+        seen = lambda i, j: (i, j)
+    elif kv_major:
+        seen = lambda i, j: (jax.lax.max(
+            i, core.first_q_block(j, bq, bk, n_q)), j)
+    else:
+        seen = lambda i, j: (i, jax.lax.min(
+            j, core.last_kv_block(i, bq, bk)))
+
+    def over_grid(f):
+        g = lambda b, h, i, j: f(b, h, *seen(i, j))
+        return (lambda b, h, j, i: g(b, h, i, j)) if kv_major else g
+    return (over_grid(lambda b, h, i, j: (b, i, h)),
+            over_grid(lambda b, h, i, j: (b, j, h)),
+            over_grid(lambda b, h, i, j: (b * groups + h, I0, i)))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, heads, causal, scale, interpret):
-    """q, k, v folded [B*H, T, D] -> out [B, Tq, H*D]: the un-fold is
-    inside the rule, so that the residual a remat policy saves is the
-    value the model consumes, with full lanes at any head dim."""
-    out, _ = _flash_fwd_impl(q, k, v, causal, scale, interpret)
-    return _unfold(out, heads)
+    """q, k, v [B, T, H*D] -> out [B, Tq, H*D], all as the model holds
+    them: the residual a remat policy saves is the value the model
+    consumes."""
+    return _flash_fwd_impl(q, k, v, heads, causal, scale, interpret)[0]
 
 
-def _flash_fwd_impl(q, k, v, causal, scale, interpret):
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    blocks = core.choose_flash_blocks(Tq, Tk, D)
+def _flash_fwd_impl(q, k, v, heads, causal, scale, interpret):
+    """out [B, Tq, H*D], lse [B*H, 1, Tq]."""
+    (q, k, v), H, g, back = _in_kernel_layout(heads, (q, k, v))
+    B, Tq, HD = q.shape
+    Tk, w = k.shape[1], HD // H * g
+    blocks = core.choose_flash_blocks(Tq, Tk, HD // H)
     bq, bk = blocks.block_q, blocks.block_k
-    grid = (BH, Tq // bq, Tk // bk)
+    row, col, stat = _index_maps(H // g, causal, blocks, Tq // bq)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, tiles=blocks.fwd),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, I0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, I0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, I0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, I0)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, I0, i)),
-        ],
+                          block_q=bq, block_k=bk, tiles=blocks.fwd,
+                          heads=g, lone=(Tq, Tk) == (bq, bk)),
+        grid=(B, H // g, Tq // bq, Tk // bk),
+        in_specs=[pl.BlockSpec((1, bq, w), row),
+                  pl.BlockSpec((1, bk, w), col),
+                  pl.BlockSpec((1, bk, w), col)],
+        out_specs=[pl.BlockSpec((1, bq, w), row),
+                   pl.BlockSpec((g, 1, bq), stat)],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
-            # lse kept [BH, 1, Tq]: trailing block dims (1, bq) satisfy the
-            # TPU (8, 128) tiling rule, which a [BH, Tq] layout cannot
-            jax.ShapeDtypeStruct((BH, 1, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Tq, HD), q.dtype),
+            # lse kept [B*H, 1, Tq]: trailing block dims (1, bq) satisfy
+            # the TPU (8, 128) tiling rule, which [B*H, Tq] cannot
+            jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32),
         ],
         # the online softmax between kv grid steps; one step holds none
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, D), jnp.float32)] * (Tk > bk),
+        scratch_shapes=[pltpu.VMEM((g, bq, 1), jnp.float32),
+                        pltpu.VMEM((g, bq, 1), jnp.float32),
+                        pltpu.VMEM((g, bq, w), jnp.float32)] * (Tk > bk),
         name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
-    return out, lse
+    return back(out), lse
 
 
 def _flash_fwd(q, k, v, heads, causal, scale, interpret):
-    out, lse = _flash_fwd_impl(q, k, v, causal, scale, interpret)
+    out, lse = _flash_fwd_impl(q, k, v, heads, causal, scale, interpret)
     # named save points: a caller's remat policy that saves "flash_out"
     # and "flash_lse" keeps this kernel out of its backward pass. The
     # PRIMAL comes from the named value too: tagged only as a residual,
     # the layer's own recomputation asks for `out` again (the next
     # matmul's weight gradient needs its input) and runs the kernel twice
-    out = checkpoint_name(_unfold(out, heads), "flash_out")
+    out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(heads, causal, scale, interpret, res, dout):
-    q, k, v, out, lse = res
-    out, dout = _fold(out, heads), _fold(dout, heads)
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    blocks = core.choose_flash_blocks(Tq, Tk, D)
+    *acts, lse = res
+    (q, k, v, out, dout), H, g, back = _in_kernel_layout(
+        heads, (*acts, dout))
+    B, Tq, HD = q.shape
+    Tk, w = k.shape[1], HD // H * g
+    blocks = core.choose_flash_blocks(Tq, Tk, HD // H)
     bq, bk = blocks.block_q, blocks.block_k
-    delta = jnp.sum(out.astype(jnp.float32) * dout.astype(jnp.float32),
-                    axis=-1)[:, None, :]  # [BH, 1, Tq]
+    kernel = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
+                  heads=g, lone=(Tq, Tk) == (bq, bk))
+    stats = jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, tiles=blocks.dq),
-        grid=(BH, Tq // bq, Tk // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, I0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, I0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, I0)),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, I0)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, I0, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, I0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, I0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)] * (Tk > bk),
+    row, col, stat = _index_maps(H // g, causal, blocks, Tq // bq)
+    dq, delta = pl.pallas_call(
+        functools.partial(_dq_kernel, tiles=blocks.dq, **kernel),
+        grid=(B, H // g, Tq // bq, Tk // bk),
+        in_specs=[pl.BlockSpec((1, bq, w), row),
+                  pl.BlockSpec((1, bk, w), col),
+                  pl.BlockSpec((1, bk, w), col),
+                  pl.BlockSpec((1, bq, w), row),
+                  pl.BlockSpec((1, bq, w), row),
+                  pl.BlockSpec((g, 1, bq), stat)],
+        out_specs=[pl.BlockSpec((1, bq, w), row),
+                   pl.BlockSpec((g, 1, bq), stat)],
+        out_shape=[jax.ShapeDtypeStruct((B, Tq, HD), q.dtype), stats],
+        scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)] * (Tk > bk),
         name="flash_attention_dq",
         interpret=interpret,
-    )(q, k, v, dout, lse, delta)
+    )(q, k, v, dout, out, lse)
 
+    row, col, stat = _index_maps(H // g, causal, blocks, Tq // bq,
+                                 kv_major=True)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, tiles=blocks.dkv),
-        grid=(BH, Tk // bk, Tq // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, I0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, I0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, I0)),
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, I0)),
-            pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, I0, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, I0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, I0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, I0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Tk, D), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)] * (
+        functools.partial(_dkv_kernel, tiles=blocks.dkv, **kernel),
+        grid=(B, H // g, Tk // bk, Tq // bq),
+        in_specs=[pl.BlockSpec((1, bq, w), row),
+                  pl.BlockSpec((1, bk, w), col),
+                  pl.BlockSpec((1, bk, w), col),
+                  pl.BlockSpec((1, bq, w), row),
+                  pl.BlockSpec((g, 1, bq), stat),
+                  pl.BlockSpec((g, 1, bq), stat)],
+        out_specs=[pl.BlockSpec((1, bk, w), col),
+                   pl.BlockSpec((1, bk, w), col)],
+        out_shape=[jax.ShapeDtypeStruct((B, Tk, HD), k.dtype),
+                   jax.ShapeDtypeStruct((B, Tk, HD), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
+                        pltpu.VMEM((bk, w), jnp.float32)] * (
                             Tq > bq or (causal and Tk > Tq)),
         name="flash_attention_dkv",
         interpret=interpret,
     )(q, k, v, dout, lse, delta)
-    return dq, dk, dv
+    return back(dq), back(dk), back(dv)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -432,11 +569,21 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention_arrays(q, k, v, causal=False, scale=None,
                            interpret=False):
-    """Array-level entry: q,k,v [B, T, H, D] → out [B, T, H, D]."""
+    """Array-level entry: q,k,v [B, T, H, D] → out [B, T, H, D]. Which
+    layout the kernels get is counted as it traces (profiler.monitor
+    `flash.calls.direct`, with `.g<heads a block>`, or
+    `flash.calls.folded`) and names the scope its ops stand under."""
+    from ...profiler import monitor
     B, Tq, H, D = q.shape
     scale = core.default_scale(scale, D)
-    out = _flash(_fold(q, H), _fold(k, H), _fold(v, H), H, causal, scale,
-                 interpret)
+    g = core.heads_per_block(H, D)
+    path = "folded" if g is None else "direct"
+    monitor.counter(f"flash.calls.{path}").inc()
+    if g is not None:
+        monitor.counter(f"flash.calls.direct.g{g}").inc()
+    with jax.named_scope(f"flash.{path}"):
+        out = _flash(*(x.reshape(*x.shape[:2], H * D) for x in (q, k, v)),
+                     H, causal, scale, interpret)
     return out.reshape(B, Tq, H, D)
 
 

@@ -203,14 +203,27 @@ _OPCODE_RE = re.compile(r"^[^\n]*? = [^\n]*?([a-z][a-z0-9_-]*)\(",
                         re.MULTILINE)
 
 
+# a Pallas kernel in the compiled text: the custom call's op_name ends
+# ".../<innermost named scope>/<kernel name>/pallas_call"
+_KERNEL_RE = re.compile(
+    r'tpu_custom_call[^\n]*?op_name="[^"\n]*?([^/"\n]+/[^/"\n]+)/pallas_call')
+
+
 def hlo_stats(compiled):
     """Instruction counts by op kind + fusion count from the compiled
-    executable's optimized HLO text. {} -shaped zeros when the backend
-    exposes no text — stats must never fail a compile."""
+    executable's optimized HLO text, and its Pallas kernels by
+    "<innermost scope>/<kernel name>" (so "flash.direct/
+    flash_attention_fwd" says which layout the flash kernels got).
+    {} -shaped zeros when the backend exposes no text — stats must
+    never fail a compile."""
     try:
         text = compiled.as_text()
     except Exception:
-        return {"instructions": 0, "fusion_count": 0, "op_counts": {}}
+        return {"instructions": 0, "fusion_count": 0, "op_counts": {},
+                "kernels": {}}
+    kernels = {}
+    for m in _KERNEL_RE.finditer(text):
+        kernels[m.group(1)] = kernels.get(m.group(1), 0) + 1
     counts = {}
     for m in _OPCODE_RE.finditer(text):
         op = m.group(1)
@@ -218,7 +231,7 @@ def hlo_stats(compiled):
     top = dict(sorted(counts.items(), key=lambda kv: (-kv[1], kv[0]))[:32])
     return {"instructions": sum(counts.values()),
             "fusion_count": counts.get("fusion", 0),
-            "op_counts": top}
+            "op_counts": top, "kernels": kernels}
 
 
 def peak_memory_bytes(compiled):
@@ -307,6 +320,7 @@ def record_compile(tag, sig, sig_key, lower_s, compile_s, cache_hit,
             "instructions": int(stats["instructions"]),
             "fusion_count": int(stats["fusion_count"]),
             "op_counts": stats["op_counts"],
+            "kernels": stats["kernels"],
             # cost_analysis can answer -1 for "unknown"; the schema (and
             # the ratchet math) want "unknown" as 0
             "flops": max(float(cost.get("flops", 0.0)), 0.0),

@@ -193,6 +193,19 @@ def _kernels(jaxpr):
     return found
 
 
+def _kernel_operands(jaxpr):
+    """{kernel name: its operands' shapes} of every pallas_call below."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = [tuple(v.aval.shape)
+                                         for v in eqn.invars]
+        else:
+            for sub in _sub_jaxprs(eqn):
+                found.update(_kernel_operands(sub))
+    return found
+
+
 class TestFlashKernel:
     def _ref(self, q, k, v, causal):
         D = q.shape[-1]
@@ -204,17 +217,24 @@ class TestFlashKernel:
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
-    def _check(self, B, Tq, Tk, H, D, causal, dtype):
+    @staticmethod
+    def _loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v) * jnp.cos(f(q, k, v)))
+
+    @staticmethod
+    def _flash(causal):
         from paddle_tpu.ops.pallas.flash_attention import \
             flash_attention_arrays
+        return lambda q, k, v: flash_attention_arrays(
+            q, k, v, causal=causal, interpret=True).astype(jnp.float32)
+
+    def _check(self, B, Tq, Tk, H, D, causal, dtype):
         rng = np.random.default_rng(0)
         q, k, v = (jnp.asarray(rng.standard_normal((B, T, H, D)), dtype)
                    for T in (Tq, Tk, Tk))
-        flash = lambda q, k, v: flash_attention_arrays(
-            q, k, v, causal=causal, interpret=True).astype(jnp.float32)
+        flash = self._flash(causal)
         ref = lambda q, k, v: self._ref(q, k, v, causal)
-        loss = lambda f: (lambda q, k, v: jnp.sum(
-            f(q, k, v) * jnp.cos(f(q, k, v))))
+        loss = self._loss
         # a bf16-sized tolerance: four roundings (2^-8) of the largest value
         f32 = dtype == jnp.float32
         out = ref(q, k, v)
@@ -263,6 +283,145 @@ class TestFlashKernel:
         dtype = jnp.bfloat16 if D == 256 else jnp.float32
         self._check(1, Tq, Tk, 1, D, causal, dtype)
 
+    @staticmethod
+    def _out_and_grads(f, q, k, v):
+        """(out, dq, dk, dv), float32, under a cotangent that differs
+        everywhere."""
+        out, vjp = jax.vjp(f, q, k, v)
+        return [np.asarray(x.astype(jnp.float32))
+                for x in (out, *vjp(jnp.cos(out)))]
+
+    @pytest.mark.parametrize("B,Tq,Tk,H,D,g,causal,dtype", [
+        # GPT-medium's 16 heads of 64: 8 lane blocks of two
+        (1, 256, 256, 16, 64, 2, True, jnp.bfloat16),
+        (1, 128, 128, 16, 64, 2, False, jnp.float32),
+        # gpt_small's 12: 6 blocks, and two batch rows
+        (2, 128, 128, 12, 64, 2, True, jnp.float32),
+        (2, 128, 128, 12, 64, 2, False, jnp.bfloat16),
+        # gpt_1p3b's head dim: a lane block a head
+        (1, 256, 256, 2, 128, 1, True, jnp.bfloat16),
+        (1, 128, 128, 2, 128, 1, False, jnp.float32),
+        # latent attention's: a head is two lane tiles
+        (1, 128, 128, 2, 256, 1, True, jnp.float32),
+        (1, 128, 128, 2, 256, 1, False, jnp.bfloat16),
+        # Tk = 2 Tq
+        (1, 128, 256, 4, 64, 2, True, jnp.float32),
+        (1, 128, 256, 4, 64, 2, False, jnp.bfloat16),
+        # four heads of 32 to a block
+        (1, 128, 128, 4, 32, 4, True, jnp.float32),
+        # two kv GRID blocks: the carries, one set a head of the block
+        (1, 2048, 2048, 2, 64, 2, True, jnp.float32),
+    ], ids=["d64_h16_causal_bf16", "d64_h16_f32", "d64_h12_causal_f32",
+            "d64_h12_bf16", "d128_causal_bf16", "d128_f32",
+            "d256_causal_f32", "d256_bf16", "tk_2tq_causal_f32",
+            "tk_2tq_bf16", "d32_g4", "d64_carries"])
+    def test_heads_picked_by_the_block_index(self, monkeypatch, B, Tq, Tk,
+                                             H, D, g, causal, dtype):
+        """The kernels on the model's own [B, T, H*D] arrays, g heads to
+        a lane block: forward and gradients against the einsum reference
+        and against the SAME kernels with the heads folded into the
+        batch (what every shape got before PR 30)."""
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        from paddle_tpu.profiler import monitor
+        assert core.heads_per_block(H, D) == g
+        f32 = dtype == jnp.float32
+        rng = np.random.default_rng(1)
+        q, k, v = (jnp.asarray(rng.standard_normal((B, T, H, D)), dtype)
+                   for T in (Tq, Tk, Tk))
+        flash = self._flash(causal)
+        run = lambda: self._out_and_grads(flash, q, k, v)
+        operands = lambda: _kernel_operands(jax.make_jaxpr(
+            lambda *a: jax.vjp(flash, *a)[1](jnp.ones(
+                (B, Tq, H, D), jnp.float32)))(q, k, v).jaxpr)
+        calls = monitor.counter("flash.calls.direct").value
+        got = run()
+        assert monitor.counter("flash.calls.direct").value == calls + 1
+        assert monitor.counter(f"flash.calls.direct.g{g}").value > 0
+        assert set(operands()["flash_attention_dq"][:5]) == {
+            (B, Tq, H * D), (B, Tk, H * D)}
+        want = self._out_and_grads(
+            lambda q, k, v: self._ref(q, k, v, causal), q, k, v)
+        monkeypatch.setattr(fa.core, "heads_per_block", lambda h, d: None)
+        jax.clear_caches()      # the custom_vjp's traces are cached
+        folded = run()
+        assert set(operands()["flash_attention_dq"][:5]) == {
+            (B * H, Tq, D), (B * H, Tk, D)}
+        for name, a, b, c in zip(("out", "dq", "dk", "dv"), got, want,
+                                 folded):
+            top = float(np.abs(b).max())
+            np.testing.assert_allclose(
+                a, b, atol=(3e-6 if f32 else 2.0 ** -6) * max(top, 1.0),
+                err_msg=name)
+            # the same dots over the same extents: only delta's lanes
+            # are summed in another order
+            np.testing.assert_allclose(
+                a, c, atol=(2e-6 if f32 else 2.0 ** -7) * max(top, 1.0),
+                err_msg=f"{name} against the folded call")
+
+    @pytest.mark.parametrize("H,D", [(2, 96), (5, 64), (2, 80)],
+                             ids=["d96", "d64_h5", "d80"])
+    def test_shapes_no_lane_block_fits_fold_the_heads(self, H, D):
+        """Head dims that neither divide 128 nor are its multiple, and a
+        head count the heads of a block do not divide: the kernels get
+        [B*H, T, D], as every shape did, and the counter says so."""
+        from paddle_tpu.profiler import monitor
+        assert core.heads_per_block(H, D) is None
+        folded = monitor.counter("flash.calls.folded").value
+        direct = monitor.counter("flash.calls.direct").value
+        self._check(1, 128, 128, H, D, True, jnp.float32)
+        assert monitor.counter("flash.calls.folded").value > folded
+        assert monitor.counter("flash.calls.direct").value == direct
+        x = jax.ShapeDtypeStruct((1, 128, H, D), jnp.float32)
+        operands = _kernel_operands(jax.make_jaxpr(self._flash(True))(
+            x, x, x).jaxpr)
+        assert operands["flash_attention_fwd"] == [(H, 128, D)] * 3
+
+    @pytest.mark.parametrize("t_q,t_k,d", [
+        (4096, 4096, 256),    # cell 2: 4 x 16 blocks of 1024 x 256
+        (2048, 2048, 128),    # gpt_1p3b: 2 x 4 of 1024 x 512
+        (2048, 2048, 64),     # square blocks
+        (1024, 2048, 64),     # kv blocks no q block sees
+        (2048, 1024, 64),
+    ])
+    def test_skipped_grid_steps_hold_their_block_index(self, t_q, t_k, d):
+        """Under the causal mask a grid step wholly above the diagonal
+        runs nothing; its block index along the grid's inner axis is
+        held at the nearest block that is computed, so the pipeline
+        copies nothing for it. Computed steps get their own blocks."""
+        from paddle_tpu.ops.pallas.flash_attention import _index_maps
+        b = core.choose_flash_blocks(t_q, t_k, d)
+        bq, bk = b.block_q, b.block_k
+        nq, nk = t_q // bq, t_k // bk
+        runs = lambda i, j: i * bq - j * bk > -bq     # the kernels' own test
+        i32 = np.int32
+        _, col, _ = _index_maps(3, True, b, nq)
+        row, _, stat = _index_maps(3, True, b, nq, kv_major=True)
+        for i in range(nq):
+            js = [int(col(i32(0), i32(1), i32(i), i32(j))[1])
+                  for j in range(nk)]
+            last = max(j for j in range(nk) if runs(i, j))
+            assert js == [min(j, last) for j in range(nk)]
+        for j in range(nk):
+            got = [(int(row(i32(0), i32(1), i32(j), i32(i))[1]),
+                    int(stat(i32(0), i32(1), i32(j), i32(i))[2]))
+                   for i in range(nq)]
+            seen = [i for i in range(nq) if runs(i, j)]
+            first = min(seen) if seen else nq - 1
+            assert got == [(max(i, first),) * 2 for i in range(nq)]
+        # no mask: every step its own blocks
+        _, col, _ = _index_maps(3, False, b, nq)
+        assert int(col(i32(0), i32(1), i32(0), i32(nk - 1))[1]) == nk - 1
+
+    def test_heads_per_block_is_a_function_of_the_shape(self):
+        assert [core.heads_per_block(16, d) for d in (64, 128, 256)] == \
+            [2, 1, 1]
+        assert core.heads_per_block(12, 64) == 2
+        assert core.heads_per_block(8, 32) == 4
+        assert core.heads_per_block(1, 8) == 1      # one head: its block
+        assert core.heads_per_block(20, 256) == 1   # is the whole width
+        for heads, d in ((5, 64), (16, 80), (16, 96), (2, 8), (6, 32)):
+            assert core.heads_per_block(heads, d) is None
+
     @pytest.mark.parametrize("t_q,t_k,tiles", [
         (1024, 1024, (256, 256)), (1024, 1024, (128, 128)),
         (1024, 1024, (256, 512)), (1024, 1024, (512, 128)),
@@ -296,8 +455,9 @@ class TestFlashKernel:
         if tq == tk and t_q == t_k:
             assert (kinds > 0).mean() == (nq + 1) / (2 * nq)
 
-    @pytest.mark.parametrize("T,D", [(1024, 8), (2048, 128)],
-                             ids=["square_blocks", "d128_blocks"])
+    @pytest.mark.parametrize("T,D", [(1024, 8), (2048, 8), (2048, 128)],
+                             ids=["lone_block", "square_blocks",
+                                  "d128_blocks"])
     @pytest.mark.parametrize("causal", [False, True])
     def test_only_diagonal_tiles_are_masked(self, causal, T, D):
         """What each kernel lowers to is what the bounds functions say:
@@ -315,7 +475,8 @@ class TestFlashKernel:
                 .astype(jnp.float32)), argnums=(0, 1, 2)))(x, x, x)
         b = core.choose_flash_blocks(T, T, D)
         square = D == 8
-        assert (b.block_q, b.block_k) == ((T, T) if square else (1024, 512))
+        assert (b.block_q, b.block_k) == ((1024, 1024) if square
+                                          else (1024, 512))
         dots = {"flash_attention_fwd": 2, "flash_attention_dq": 3,
                 "flash_attention_dkv": 4}
         kernels = _kernels(jaxpr.jaxpr)
@@ -329,6 +490,13 @@ class TestFlashKernel:
                 assert _count(body, "select_n") == 0
                 assert _count(body, "iota") == 0
                 continue
+            if T == b.block_q:
+                # a lone block stands on the diagonal: that body alone,
+                # under no condition
+                assert _count(body, "cond") == 0
+                assert (_count(body, "dot_general"),
+                        _count(body, "select_n")) == (dots[name] * n, n)
+                continue
             got = sorted(
                 (_count(br.jaxpr, "dot_general"), _count(br.jaxpr, "select_n"))
                 for eqn in body.eqns if eqn.primitive.name == "cond"
@@ -340,9 +508,9 @@ class TestFlashKernel:
             # and what a strip on the diagonal sees is the bounds'
             t = b.dq[0]
             assert b.dq == (t, t)
-            seen = [core.causal_kv_tiles(i * t, t, t, T // t)
-                    for i in range(T // t)]
-            assert seen == [(i, i + 1) for i in range(T // t)]
+            n = b.block_q // t
+            seen = [core.causal_kv_tiles(i * t, t, t, n) for i in range(n)]
+            assert seen == [(i, i + 1) for i in range(n)]
 
     def test_blocks_share_the_core_policy(self):
         # one source of truth: the kernel module re-exports nothing of

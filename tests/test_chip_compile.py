@@ -12,6 +12,7 @@ loads the library. A compile that passes is not a chip run:
 `python chip_smoke.py` on the chip is.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +69,25 @@ def _flash_kernel_counts(compiled):
     """(forward, dq, dkv) Mosaic calls in the module."""
     return tuple(_custom_calls(compiled, f"flash_attention_{k}")
                  for k in ("fwd", "dq", "dkv"))
+
+
+def _copied_shapes(compiled):
+    """The result dims, as "8,1024,3072", of every `copy` instruction of
+    the module, a leading stack axis of 1 dropped. The slices and
+    concatenations a model asks for are fusions under other names."""
+    return [m.group(1) for m in re.finditer(
+        r"= \w+\[(?:1,)?([\d,]+)\]\{[^}]*\} copy\(", compiled.as_text())]
+
+
+def _flash_operands(compiled):
+    """{kernel: its operand_layout_constraints text} of the flash calls."""
+    found = {}
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"flash_attention_(fwd|dq|dkv)[.\d]* = .*"
+                      r"operand_layout_constraints=\{(.*?\})\}", line)
+        if m and "tpu_custom_call" in line:
+            found[m.group(1)] = m.group(2)
+    return found
 
 
 def test_flash_attention_fwd_bwd_gpt_medium(compile_for_chip):
@@ -130,12 +150,16 @@ def chip_branches(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def _gpt_two_blocks():
+def _gpt_two_blocks(hidden=1024, batch=8, seq=1024):
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
     return GPTForCausalLM(GPTConfig(
-        vocab_size=512, hidden_size=1024, num_layers=2, num_heads=16,
-        max_position_embeddings=1024, dropout=0.0,
-        scan_remat="names")), (8, 1024)
+        vocab_size=512, hidden_size=hidden, num_layers=2, num_heads=16,
+        max_position_embeddings=seq, dropout=0.0,
+        scan_remat="names")), (batch, seq)
+
+
+def _gpt_1p3b_two_blocks():
+    return _gpt_two_blocks(hidden=2048, batch=2, seq=2048)
 
 
 def _glm_two_expert_layers():
@@ -146,15 +170,44 @@ def _glm_two_expert_layers():
         first_k_dense_replace=0)), (2, 4096)
 
 
-@pytest.mark.parametrize("build", [_gpt_two_blocks, _glm_two_expert_layers],
-                         ids=["gpt_names_8x1024x16x64",
-                              "decoder_2x4096x20x256"])
+# per stack: the kernels' operand [B, T, H*D]; the attention activations
+# that NO copy instruction may produce any more (the kernels' old
+# [B, H, T, D] fold — 16 such copies a GPT layer, 13 a decoder layer
+# before PR 30 — and the whole of qkv, which the compiler moved through a
+# T-minor layout while models/gpt.py cut q, k, v out of a 5-D view); and
+# how many copies of the operand's own size may stay
+_STACKS = {
+    "gpt_names_8x1024x16x64": (
+        _gpt_two_blocks, "bf16[8,1024,1024]",
+        ("8,16,1024,64", "8,1024,16,64", "8,1024,3072"),
+        # the re-read of the saved `out` a layer; two outside the scan
+        ("8,1024,1024", 3)),
+    "gpt_1p3b_2x2048x16x128": (
+        _gpt_1p3b_two_blocks, "bf16[2,2048,2048]",
+        ("2,16,2048,128", "2,2048,16,128", "2,2048,6144"),
+        ("2,2048,2048", 3)),
+    "decoder_2x4096x20x256": (
+        _glm_two_expert_layers, "bf16[2,4096,5120]",
+        ("2,20,4096,256", "2,4096,20,256"),
+        # NOT the kernels': the compiler holds the latent projections'
+        # q, k, v (and dq, dk, dv) T-minor, because the model cuts the
+        # head dim at 192 | 64 | 32, and transposes them for any
+        # row-major consumer: 3 forward, 3 recomputed, 3 gradients a
+        # layer, before PR 30 as after (PERF.md section 7)
+        ("2,4096,5120", 9)),
+}
+
+
+@pytest.mark.parametrize("stack", list(_STACKS))
 def test_scanned_stack_gradient_runs_flash_forward_once(
-        compile_for_chip, chip_branches, build):
+        compile_for_chip, chip_branches, stack):
     """The gradient of a two-layer scanned stack under the stack's own
     remat policy, at the benchmark cells' attention shapes: the policy
     saves the kernel's named out and lse, so the module holds ONE forward
-    kernel beside one dq and one dkv (two forwards before PR 28)."""
+    kernel beside one dq and one dkv (two forwards before PR 28). And the
+    kernels take and give the model's own [B, T, H*D] arrays: no copy of
+    an attention activation in the kernels' old layout is left."""
+    build, operand, gone, (own, at_most) = _STACKS[stack]
     import paddle_tpu as paddle
     from paddle_tpu.jit.api import functional_call, state_arrays
     paddle.seed(0)
@@ -170,6 +223,14 @@ def test_scanned_stack_gradient_runs_flash_forward_once(
         jax.grad(loss), {k: (v.shape, v.dtype) for k, v in params.items()},
         (ids_shape, I32))
     assert _flash_kernel_counts(c) == (1, 1, 1)
+    operands = _flash_operands(c)
+    assert sorted(operands) == ["dkv", "dq", "fwd"]
+    for kernel, text in operands.items():
+        # q, k, v (and dout, out): all [B, T, H*D], row-major
+        assert text.count(operand + "{2,1,0}") >= 3, (kernel, text)
+    copied = _copied_shapes(c)
+    assert not [x for x in copied if x in gone], copied
+    assert copied.count(own) <= at_most, copied
 
 
 def test_glm_flash_ep8_step_fits_the_chip(compile_for_chip, chip_branches):

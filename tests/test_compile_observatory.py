@@ -435,3 +435,35 @@ def test_compile_schema_rejects_bad_records():
                cms.validate_line(json.dumps(bad)))
     bad = dict(good, tag="")
     assert any("tag" in e for e in cms.validate_line(json.dumps(bad)))
+
+
+def test_compile_record_names_the_pallas_kernels_under_their_scope():
+    """hlo_stats counts a compiled text's Pallas kernels by "<innermost
+    named scope>/<kernel>" (flash attention's entry names the layout it
+    gave its kernels there); the record carries them, the schema tool
+    takes them, and obs_report prints them under the tag."""
+    class Compiled:
+        def as_text(self):
+            call = ('  %flash_attention_{k}.4 = bf16[8,1024,1024]{{2,1,0}} '
+                    'custom-call(%a, %b), custom_call_target='
+                    '"tpu_custom_call", metadata={{op_name="jit(step)/'
+                    'while/body/flash.direct/flash_attention_{k}/'
+                    'pallas_call" stack_frame_id=8}}\n')
+            return ("HloModule m\n  %p = f32[2]{0} parameter(0)\n"
+                    + call.format(k="fwd") + call.format(k="dq")
+                    + call.format(k="dq"))
+
+    stats = compile_observatory.hlo_stats(Compiled())
+    assert stats["kernels"] == {"flash.direct/flash_attention_fwd": 1,
+                                "flash.direct/flash_attention_dq": 2}
+    rec = {"ts": 1.0, "rank": 0, "kind": "compile", "tag": "train.step",
+           "signature": "abc", "lower_s": 0.1, "compile_s": 0.2,
+           "cache_hit": False, "instructions": stats["instructions"],
+           "fusion_count": 0, "bytes_accessed": 1.0, "flops": 1.0,
+           "peak_memory_bytes": 64.0, "kernels": stats["kernels"]}
+    cms = _load_tool("check_metrics_schema")
+    assert cms.validate_line(json.dumps(rec)) == []
+    bad = dict(rec, kernels={"flash.direct/flash_attention_fwd": -1})
+    assert any("kernels" in e for e in cms.validate_line(json.dumps(bad)))
+    text = _load_tool("obs_report").render([rec])
+    assert "kernels: flash.direct/flash_attention_dq x2" in text

@@ -92,15 +92,16 @@ def lower_ragged_kernel():
     return lowered.as_text()
 
 
-def lower_flash_kernel():
-    """Lower the training flash kernel (fwd) standalone at the
-    canonical train-step geometry (batch 2, seq 16, 2 heads, D=16)."""
+def lower_flash_kernel(B=2, T=16, H=2, D=16):
+    """Lower the training flash kernel (fwd) standalone: at the
+    canonical train-step geometry (batch 2, seq 16, 2 heads, D=16: the
+    heads folded into the batch), or with TWO heads of 64 to a lane
+    block of the model's own [B, T, H*D]."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas.flash_attention import \
         flash_attention_arrays
 
-    B, T, H, D = 2, 16, 2, 16
     sds = jax.ShapeDtypeStruct
     fn = jax.jit(lambda q, k, v: flash_attention_arrays(
         q, k, v, causal=True, interpret=True))
@@ -178,7 +179,9 @@ def main(argv=None):
     try:
         modules = [("serve.ragged_step/paged_attention",
                     lower_ragged_kernel()),
-                   ("train.step/flash_attention", lower_flash_kernel())]
+                   ("train.step/flash_attention", lower_flash_kernel()),
+                   ("train.step/flash_attention, 2 heads a block",
+                    lower_flash_kernel(1, 128, 2, 64))]
     except Exception as e:  # lowering itself broke: gate failure
         print(f"check_dot_shapes: lowering failed: {e}", file=sys.stderr)
         return 2

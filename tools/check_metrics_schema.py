@@ -160,6 +160,8 @@ Schema (documented in docs/OBSERVABILITY.md):
                   peak_memory_bytes number  memory-analysis peak (>= 0)
                   and optionally:
                   op_counts    dict    {op kind: count >= 0}
+                  kernels      dict    {"<scope>/<Pallas kernel>":
+                                       count >= 0}
   kind == "warm" (one record per resolved warm set —
                   paddle_tpu/jit/warm.py join) additionally requires:
                   n_executables int    handles in the set (>= 0)
@@ -900,19 +902,21 @@ def validate_line(line, where="<line>"):
                 f"{where}: cache_hit record spent {comp}s in compile_s "
                 f"(> {CACHE_HIT_COMPILE_S_MAX}s) — a hit loads an "
                 "artifact, it does not compile")
-        ops = rec.get("op_counts")
-        if ops is not None:
+        for key in ("op_counts", "kernels"):
+            ops = rec.get(key)
+            if ops is None:
+                continue
             if not isinstance(ops, dict):
-                errors.append(f"{where}: op_counts must be a dict, got "
+                errors.append(f"{where}: {key} must be a dict, got "
                               f"{type(ops).__name__}")
-            else:
-                for k, v in ops.items():
-                    if not isinstance(k, str) or not isinstance(v, int) \
-                            or isinstance(v, bool) or v < 0:
-                        errors.append(
-                            f"{where}: op_counts entry {k!r}: {v!r} must "
-                            "be str -> int >= 0")
-                        break
+                continue
+            for k, v in ops.items():
+                if not isinstance(k, str) or not isinstance(v, int) \
+                        or isinstance(v, bool) or v < 0:
+                    errors.append(
+                        f"{where}: {key} entry {k!r}: {v!r} must "
+                        "be str -> int >= 0")
+                    break
     elif rec.get("kind") == "warm":
         _check_types(rec, WARM_REQUIRED, where, errors)
 

@@ -86,8 +86,9 @@ def section_compiles(recs, out, top):
     by_tag = {}
     for r in comps:
         t = by_tag.setdefault(r.get("tag", "?"),
-                              {"n": 0, "s": 0.0, "hits": 0})
+                              {"n": 0, "s": 0.0, "hits": 0, "kernels": {}})
         t["n"] += 1
+        t["kernels"] = r.get("kernels") or t["kernels"]  # the newest
         t["s"] += float(r.get("lower_s", 0.0)) + \
             float(r.get("compile_s", 0.0))
         t["hits"] += 1 if r.get("cache_hit") else 0
@@ -97,6 +98,9 @@ def section_compiles(recs, out, top):
     for tag, t in rows:
         out.append(f"  {tag:<28} {t['s']:>8.2f}s  "
                    f"x{t['n']}  cache hits {t['hits']}/{t['n']}")
+        if t["kernels"]:    # Pallas kernels under their scope: which
+            out.append("    kernels: " + "  ".join(     # layout flash got
+                f"{k} x{v}" for k, v in sorted(t["kernels"].items())))
     out.append("")
 
 

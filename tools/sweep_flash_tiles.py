@@ -2,13 +2,18 @@
 """Device time of the three bare flash-attention kernels, per sub-tile.
 
 Where attention_core.SUB_TILE_CAPS comes from: on the chip, run forward
-and backward of the bare kernels at the shapes the GPT sizes use and read
-each kernel's device time from a profiler trace (the benchmark's own
-reduction, benchmarks/lib/xplane.py), once per candidate (tq, tk). The
-candidates are set by assigning SUB_TILE_CAPS from here — the program
-has no option for it. A tree without SUB_TILE_CAPS (an older commit on
-PYTHONPATH) is timed as it stands, and `--control` times the compiler's
-own attention (nn/functional/attention.py _sdpa_reference) the same way.
+and backward of the kernels through the public entry, on [B, T, H, D]
+arrays at the shapes the models hand it (so what is timed is what they
+run: the kernels, and under `all_device_ops` whatever copies the entry
+puts around them), and read each kernel's device time from a profiler
+trace (the benchmark's own reduction, benchmarks/lib/xplane.py), once
+per candidate (tq, tk). The candidates are set by assigning
+SUB_TILE_CAPS from here — the program has no option for it. A tree
+without SUB_TILE_CAPS (an older commit on PYTHONPATH) is timed as it
+stands, and `--control` times the compiler's own attention
+(nn/functional/attention.py _sdpa_reference) the same way, and the
+compiler's reduce for delta = rowsum(out * dout) over the [B, T, H*D]
+layout, which the dq kernel makes in its prologue instead.
 
   chiprun -- python tools/sweep_flash_tiles.py --out chiprun_out/tiles.jsonl
 
@@ -28,7 +33,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # an older commit named on PYTHONPATH wins over this checkout
 sys.path.append(REPO)
 
-SHAPES = {"gpt2-medium": (8, 1024, 16, 64), "gpt-1p3b": (2, 2048, 16, 128)}
+SHAPES = {"gpt2-medium": (8, 1024, 16, 64), "gpt-1p3b": (2, 2048, 16, 128),
+          "glm-mla": (2, 4096, 20, 256)}
 KERNELS = ("flash_attention_fwd", "flash_attention_dq",
            "flash_attention_dkv")
 
@@ -55,6 +61,9 @@ def traced_ms(fn, args, calls):
         tr = xplane.Trace.from_file(pb, wall)
         ms = {k: 1e3 * tr.kernel_seconds(k)[0] / calls for k in KERNELS}
         ms["all_device_ops"] = 1e3 * tr.busy_fullest_s / calls
+        # what the entry and the compiler put around the kernels
+        ms["others"] = {name: 1e3 * s / calls for name, s in tr.top_ops(9)
+                        if not name.startswith("flash_attention_")}
         ms["wall"] = 1e3 * wall / calls
         return ms
     finally:
@@ -90,10 +99,18 @@ def main(argv=None):
               for t in args.tiles.split(",")] if has_tiles else [None])
     lines = []
     for name in args.shapes.split(","):
-        shape = SHAPES[name]
+        shape = B, T, H, D = SHAPES[name]
         keys = jax.random.split(jax.random.PRNGKey(26), 4)
-        q, k, v, w = (jax.random.normal(kk, shape, jnp.float32)
+        # held [B, T, H*D], as a model's projections leave them, and
+        # viewed by head inside the compiled function, as a model views
+        # them: a [B, T, H, D] array of its own is tiled over (H, D) on
+        # the chip, and the view would cost a copy that no model pays
+        q, k, v, w = (jax.random.normal(kk, (B, T, H * D), jnp.float32)
                       .astype(jnp.bfloat16) for kk in keys)
+
+        def by_head(attn):
+            return lambda *qkv: attn(*(x.reshape(shape) for x in qkv)) \
+                .reshape(B, T, H * D)
 
         def grads_of(attn):
             # a weighted sum: every element of dout differs
@@ -101,22 +118,29 @@ def main(argv=None):
                 attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
             return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
-        plain = lambda q, k, v: _sdpa_reference(
+        plain = by_head(lambda q, k, v: _sdpa_reference(
             q.astype(jnp.float32), k.astype(jnp.float32),
-            v.astype(jnp.float32), is_causal=causal)
+            v.astype(jnp.float32), is_causal=causal))
         want = jax.jit(plain)(q, k, v), grads_of(plain)(q, k, v)
         if args.control:
-            xla = lambda q, k, v: _sdpa_reference(q, k, v,
-                                                  is_causal=causal)
+            xla = by_head(lambda q, k, v: _sdpa_reference(
+                q, k, v, is_causal=causal))
             ms = traced_ms(grads_of(xla), (q, k, v), args.calls)
             lines.append({"label": "xla_composition", "shape": name,
                           "causal": causal, "ms": ms})
             print(json.dumps(lines[-1]), flush=True)
+            delta = jax.jit(lambda o, do: jnp.swapaxes(jnp.sum(
+                (o.astype(jnp.float32) * do.astype(jnp.float32))
+                .reshape(B, T, H, D), -1), 1, 2).reshape(B * H, 1, T))
+            ms = traced_ms(delta, (q, w), args.calls)
+            lines.append({"label": "xla_delta", "shape": name,
+                          "ms": ms["all_device_ops"]})
+            print(json.dumps(lines[-1]), flush=True)
         for t in tiles:
             if t is not None:
                 core.SUB_TILE_CAPS = {kern: t for kern in core.SUB_TILE_CAPS}
-            flash = lambda q, k, v: flash_attention_arrays(
-                q, k, v, causal=causal, interpret=False)
+            flash = by_head(lambda q, k, v: flash_attention_arrays(
+                q, k, v, causal=causal, interpret=False))
             line = {"label": args.label, "shape": name, "causal": causal,
                     "caps": t}
             try:
@@ -134,6 +158,9 @@ def main(argv=None):
                     "out": err(got[0], want[0]),
                     **{f"d{n}": err(a, b) for n, a, b
                        in zip("qkv", got[1], want[1])}}
+                if hasattr(core, "heads_per_block"):
+                    line["heads_per_block"] = core.heads_per_block(
+                        *shape[2:])
                 if has_tiles:
                     b = core.choose_flash_blocks(shape[1], shape[1],
                                                  shape[3])
