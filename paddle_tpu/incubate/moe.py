@@ -179,19 +179,34 @@ STEP_COUNTERS = ("moe.assignments", "moe.local_assignments",
                  "moe.expert_load_max", "moe.dropped")
 
 
-def route_tokens(x, router_w, bias, top_k, scale, norm_topk_prob):
-    """Sigmoid routing with a selection bias (DeepSeek-V3, "auxiliary-
-    loss-free" balancing): s = sigmoid(x Wr) in float32 over ALL experts;
-    chosen = top-k of s + bias; weight = s[chosen], renormalised over the
-    chosen (+1e-20) where `norm_topk_prob`, times `scale`. The bias
-    decides who is chosen and never enters a weight. Returns (chosen
-    [N, k] int32, weights [N, k] float32)."""
-    s = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+SCORINGS = ("sigmoid", "softmax_topk")
+GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def route_tokens(x, router_w, bias, top_k, scale, norm_topk_prob,
+                 scoring="sigmoid"):
+    """Top-k routing over ALL experts, in float32. `scoring`:
+    "sigmoid" (DeepSeek-V3, "auxiliary-loss-free" balancing): s =
+    sigmoid(x Wr); chosen = top-k of s + bias; weight = s[chosen],
+    renormalised over the chosen (+1e-20) where `norm_topk_prob`.
+    "softmax_topk" (SmallThinker's primary router): s = x Wr; chosen =
+    top-k of s + bias; weight = softmax over the CHOSEN logits — the
+    softmax over all experts renormalised over the chosen — or, without
+    `norm_topk_prob`, the chosen entries of the softmax over all.
+    Either way the bias decides who is chosen and never enters a weight,
+    and the weights are times `scale`. Returns (chosen [N, k] int32,
+    weights [N, k] float32)."""
+    s = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        s = jax.nn.sigmoid(s)
+    elif scoring == "softmax_topk" and not norm_topk_prob:
+        s = jax.nn.softmax(s, axis=-1)
     _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(s, chosen, axis=-1)
-    if norm_topk_prob:
+    if scoring == "softmax_topk" and norm_topk_prob:
+        picked = jax.nn.softmax(picked, axis=-1)
+    elif norm_topk_prob:
         picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return chosen.astype(jnp.int32), picked * scale
 
@@ -225,6 +240,18 @@ def dispatch_plan(chosen, first, n_local):
     return sizes, pos, src
 
 
+def _gathered(rows, pos):
+    """[rows[pos[:, j]] for each of a token's k assignments j], float32
+    [N, D] each, a row past the buffer (`pos` = its length: not held)
+    read as the last one — the caller masks it. An assignment at a time:
+    one [N, k, D] gather has its k (4, 6) padded to the chip's 8-row
+    tile, is written and read back in float32 and laid out anew on the
+    way to its sum (PERF.md section 6, PR 31)."""
+    last = rows.shape[0] - 1
+    return [rows[jnp.minimum(pos[:, j], last)].astype(jnp.float32)
+            for j in range(pos.shape[1])]
+
+
 @jax.custom_vjp
 def _dispatch(x, src, pos):
     """xs [rows, D]: row r holds token src[r] // k of x [N, D], zero
@@ -240,9 +267,9 @@ def _dispatch_fwd(x, src, pos):
 
 def _dispatch_bwd(res, dxs):
     src, pos = res
-    rows = dxs.shape[0]
-    got = dxs[jnp.minimum(pos, rows - 1)].astype(jnp.float32)   # [N, k, D]
-    dx = jnp.where((pos < rows)[..., None], got, 0.0).sum(1)
+    held = pos < dxs.shape[0]
+    dx = sum(jnp.where(held[:, j, None], got, 0.0)
+             for j, got in enumerate(_gathered(dxs, pos)))
     return dx.astype(dxs.dtype), None, None
 
 
@@ -253,10 +280,9 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 def _combine(ys, w, src, pos):
     """y [N, D] = sum over a token's held assignments of w[n, j] *
     ys[pos[n, j]] (float32 sum). Backward: a gather by `src`."""
-    rows = ys.shape[0]
-    got = ys[jnp.minimum(pos, rows - 1)].astype(jnp.float32)    # [N, k, D]
-    wl = jnp.where(pos < rows, w, 0.0)
-    return (got * wl[..., None]).sum(1).astype(ys.dtype)
+    wl = jnp.where(pos < ys.shape[0], w, 0.0)
+    return sum(got * wl[:, j, None]
+               for j, got in enumerate(_gathered(ys, pos))).astype(ys.dtype)
 
 
 def _combine_fwd(ys, w, src, pos):
@@ -265,27 +291,27 @@ def _combine_fwd(ys, w, src, pos):
 
 def _combine_bwd(res, dy):
     ys, w, src, pos = res
-    rows = ys.shape[0]
     N, k = pos.shape
     a = jnp.minimum(src, N * k - 1)
     w_row = jnp.where(src < N * k, w.reshape(-1)[a], 0.0)
     dys = (dy[a // k].astype(jnp.float32) * w_row[:, None]).astype(ys.dtype)
-    got = ys[jnp.minimum(pos, rows - 1)].astype(jnp.float32)
-    dw = jnp.where(pos < rows,
-                   (got * dy.astype(jnp.float32)[:, None, :]).sum(-1), 0.0)
-    return dys, dw.astype(w.dtype), None, None
+    dy32 = dy.astype(jnp.float32)
+    dw = jnp.stack([(got * dy32).sum(-1) for got in _gathered(ys, pos)], 1)
+    return dys, jnp.where(pos < ys.shape[0], dw, 0.0).astype(w.dtype), \
+        None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, first):
+def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, first,
+                     activation=jax.nn.silu):
     """The held experts' part of the layer's result, for tokens x [N, D]
     routed as (chosen, weights) [N, k]: stable sort of the held
-    assignments by expert -> gather -> grouped matmul x3 (SiLU-gated) ->
-    weight -> sum per token. Every assignment to a held expert (indices
-    first .. first + E_local - 1) is computed, however they fall: the
-    buffer holds the worst case. The grouped matmul is the compiler's
+    assignments by expert -> gather -> grouped matmul x3 (gated by
+    `activation`) -> weight -> sum per token. Every assignment to a held
+    expert (indices first .. first + E_local - 1) is computed, however
+    they fall: the buffer holds the worst case. The grouped matmul is the compiler's
     jax.lax.ragged_dot (rows past the groups come out zero and get no
     gradient); what a Pallas kernel gave against it on the chip is in
     PERF.md section 6 (PR 27). Returns (y [N, D], counters [4] int32:
@@ -299,7 +325,7 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, first):
 
     with jax.named_scope("moe.experts"):
         xs = _dispatch(x, src, pos)
-        h = jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)
+        h = activation(mm(xs, w_gate)) * mm(xs, w_up)
         ys = mm(h, w_down)
     with jax.named_scope("moe.combine"):
         y = _combine(ys, weights.astype(jnp.float32), src, pos)
@@ -321,12 +347,16 @@ class DroplessMoE(nn.Layer):
     holds (all by default) and is data of the layer. Routing
     (`route_tokens`) is over all experts: sigmoid scores, a selection
     bias (the buffer `e_score_correction_bias`, zero unless set),
-    renormalised top-k, `routed_scaling_factor`. Assignments to experts
-    held elsewhere add nothing here; every assignment to a held expert
-    is computed — no capacity, no drop (`dropless_experts`). Experts and
-    the `n_shared_experts` shared ones are SiLU-gated feed-forwards of
-    width `d_expert`. On one chip the layer runs without its exchange;
-    under a mesh with an 'ep' axis the expert stacks shard over it
+    renormalised top-k, `routed_scaling_factor` — or, `scoring=
+    "softmax_topk"`, a softmax over the chosen logits. Assignments to
+    experts held elsewhere add nothing here; every assignment to a held
+    expert is computed — no capacity, no drop (`dropless_experts`).
+    Experts and the `n_shared_experts` shared ones are feed-forwards of
+    width `d_expert` gated by `activation` ("silu", "relu").
+    forward(x, router_input=None) routes on `router_input` where one is
+    given (SmallThinker's router reads the decoder layer's input, before
+    attention) and transforms x. On one chip the layer runs without its
+    exchange; under a mesh with an 'ep' axis the expert stacks shard over it
     (`sharding_spec()`, MoELayer's convention).
 
     Each forward records STEP_COUNTERS as one int32 vector
@@ -341,8 +371,14 @@ class DroplessMoE(nn.Layer):
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  local_experts=None, n_shared_experts=0,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
-                 weight_attr=None, name=None):
+                 weight_attr=None, name=None, scoring="sigmoid",
+                 activation="silu"):
         super().__init__()
+        if scoring not in SCORINGS or activation not in GATE_ACTIVATIONS:
+            raise ValueError(f"scoring={scoring!r} (of {SCORINGS}), "
+                             f"activation={activation!r} (of "
+                             f"{tuple(GATE_ACTIVATIONS)})")
+        self.scoring, self.activation = scoring, activation
         local = range(num_experts) if local_experts is None \
             else local_experts
         if local.step != 1 or local.start < 0 or local.stop > num_experts \
@@ -369,6 +405,7 @@ class DroplessMoE(nn.Layer):
         self.experts_down = self.create_parameter(
             [E, d_expert, d_model], default_initializer=init)
         self.shared = nn.GatedMLP(d_model, d_expert * n_shared_experts,
+                                  activation=activation,
                                   weight_attr=init) \
             if n_shared_experts else None
         self._step_counters = None
@@ -380,22 +417,24 @@ class DroplessMoE(nn.Layer):
                 "experts_down": P("ep", None, None),
                 "router.weight": P()}
 
-    def forward(self, x):
-        shape = x.shape
-        D = shape[-1]
+    def forward(self, x, router_input=None):
+        D = x.shape[-1]
 
-        def fn(xa, rw, bias, wg, wu, wd):
+        def fn(xa, ra, rw, bias, wg, wu, wd):
             x2 = xa.reshape(-1, D)
             with jax.named_scope("moe.route"):
                 chosen, weights = route_tokens(
-                    x2, rw, bias, self.top_k, self.routed_scaling_factor,
-                    self.norm_topk_prob)
+                    ra.reshape(-1, D), rw, bias, self.top_k,
+                    self.routed_scaling_factor, self.norm_topk_prob,
+                    self.scoring)
             y, counters = dropless_experts(
-                x2, chosen, weights, wg, wu, wd, self.local_experts.start)
+                x2, chosen, weights, wg, wu, wd, self.local_experts.start,
+                GATE_ACTIVATIONS[self.activation])
             return y.reshape(xa.shape), counters
 
         y, counters = apply_op(
-            fn, x, self.router.weight, self.e_score_correction_bias,
+            fn, x, x if router_input is None else router_input,
+            self.router.weight, self.e_score_correction_bias,
             self.experts_gate, self.experts_up, self.experts_down,
             n_outputs=2)
         self._step_counters = counters.value

@@ -6,4 +6,5 @@ from .bert import (BertConfig, BertModel, BertForMaskedLM,
                    ErnieForSequenceClassification, bert_base, ernie_base)
 from .seq2seq import Seq2SeqConfig, Seq2SeqTransformer
 from .decoder import (DecoderConfig, DecoderStack, DecoderForCausalLM,
-                      LatentAttention, glm_4_7_flash_ep8)
+                      LatentAttention, GroupedQueryAttention,
+                      glm_4_7_flash_ep8, smallthinker_21b_ep8)

@@ -2,17 +2,23 @@
 
 The stack is data: `DecoderConfig.layers` is a list of (mixer,
 feed-forward) names, one pair a layer, looked up in MIXERS and FFNS; the
-norm and the positions are names too. Today's parts are what the
-GLM-4.x / DeepSeek-V3 family needs — latent attention ("mla"), a
-SiLU-gated feed-forward ("dense"), the dropless expert layer ("moe":
-incubate.moe.DroplessMoE), RMSNorm, rotary positions — and this is where
-models/gpt.py's and models/ssm.py's blocks are to move (ROADMAP D3): a
-new architecture is a new entry in a table and a preset, not a third
-hand-written model file.
+norm is a name too. Today's parts are what two families need. The
+GLM-4.x / DeepSeek-V3 one: latent attention with rotary positions
+("mla"), a SiLU-gated feed-forward ("dense"), the dropless expert layer
+("moe": incubate.moe.DroplessMoE, sigmoid scores). SmallThinker:
+grouped-query attention in two specs — "gqa_full", every earlier key and
+no positions at all, and "gqa_window", a window of `sliding_window_size`
+keys with rotary positions — and "moe_pre", the same expert layer with a
+softmax over the chosen logits, ReLU gating and a router that reads the
+LAYER'S INPUT, before attention. Positions belong to a mixer's spec, so
+they differ by layer. This is where models/gpt.py's and models/ssm.py's
+blocks are to move (ROADMAP D3): a new architecture is a new entry in a
+table and a preset, not a third hand-written model file.
 
 Block: x += mixer(norm(x)); x += ffn(norm(x)); final norm; untied head.
 Parameter names follow the published module tree (`model.embed_tokens`,
-`self_attn.q_a_proj`, `mlp.down_proj`, `lm_head`, ...). The layers stand
+`self_attn.q_a_proj`, `self_attn.q_proj`, `mlp.down_proj`, `lm_head`,
+...). The layers stand
 in two lists: `model.lead.<i>`, the leading layers that differ from the
 rest, and `model.h.<i>`, the uniform run that ends the stack. Under a
 trace every layer is rematerialised in the backward pass
@@ -21,9 +27,16 @@ trace every layer is rematerialised in the backward pass
 than one layer is one lax.scan over its stacked parameters, traced and
 compiled once whatever its length.
 
+A pattern that repeats with a period over one layer (SmallThinker's
+full, window, window, window) is NOT one scan over periods: everything
+before the last uniform run is unrolled (ROADMAP C10).
+
 Training only: no decode cache yet (ROADMAP C9: a latent paged cache with
-an absorbed decode path).
+an absorbed decode path; C11: window layers and grouped key/value heads
+in serving).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -32,17 +45,29 @@ from .. import nn
 from ..nn import functional as F
 
 __all__ = ["DecoderConfig", "DecoderStack", "DecoderForCausalLM",
-           "LatentAttention", "glm_4_7_flash_ep8"]
+           "LatentAttention", "GroupedQueryAttention", "glm_4_7_flash_ep8",
+           "smallthinker_21b_ep8"]
 
 
 class DecoderConfig:
-    """Field names are the published config's (transformers'
-    `glm4_moe_lite` / `deepseek_v3`), so that a configuration file can be
-    held against this object key by key. Beside them: `router_experts`,
+    """Field names are published configs' (transformers' `glm4_moe_lite`
+    / `deepseek_v3`; `num_key_value_heads`, `head_dim` and
+    `sliding_window_size`, which the grouped-query mixers read, as
+    SmallThinker's config.json has them), so that a configuration file
+    can be held against this object key by key. Where two families name
+    one thing differently the field keeps the first's name and the
+    other's preset maps onto it (`moe_intermediate_size`,
+    `n_routed_experts`, `num_experts_per_tok`: SmallThinker's
+    `moe_ffn_hidden_size`, `moe_num_primary_experts`,
+    `moe_num_active_primary_experts`). Beside them: `router_experts`,
     the router's width (all routed experts of the model), where
     `n_routed_experts` counts those HELD here, from `local_expert_start`
-    on; `layers`, the per-layer specs (by default `first_k_dense_replace`
-    dense layers, then expert layers); `norm`, `positions`."""
+    on; `router_scoring` and `hidden_act`, the expert layer's scores and
+    gate (incubate.moe.DroplessMoE's `scoring`, `activation`); `layers`,
+    the per-layer specs (by default `first_k_dense_replace` dense
+    layers, then expert layers, all with latent attention); `norm`;
+    `positions`, which the latent mixer checks (the grouped-query specs
+    carry their own)."""
 
     def __init__(self, vocab_size=1024, hidden_size=128,
                  intermediate_size=512, num_hidden_layers=2,
@@ -54,12 +79,23 @@ class DecoderConfig:
                  n_shared_experts=0, num_experts_per_tok=2,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
                  first_k_dense_replace=None, layers=None, norm="rms",
-                 positions="rotary", initializer_range=0.02):
+                 positions="rotary", initializer_range=0.02,
+                 num_key_value_heads=None, head_dim=None,
+                 sliding_window_size=None, router_scoring="sigmoid",
+                 hidden_act="silu"):
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.intermediate_size = intermediate_size
         self.num_hidden_layers = num_hidden_layers
         self.num_attention_heads = num_attention_heads
+        if num_key_value_heads is not None:
+            # a field only where it is stated: the latent mixer has no
+            # such count, and a file's key is held against this object
+            self.num_key_value_heads = num_key_value_heads
+        self.head_dim = head_dim or hidden_size // num_attention_heads
+        self.sliding_window_size = sliding_window_size
+        self.router_scoring = router_scoring
+        self.hidden_act = hidden_act
         self.q_lora_rank = q_lora_rank
         self.kv_lora_rank = kv_lora_rank
         self.qk_nope_head_dim = qk_nope_head_dim
@@ -162,6 +198,58 @@ class LatentAttention(nn.Layer):
         return self.o_proj(o.reshape([B, T, nh * vd]))
 
 
+class GroupedQueryAttention(nn.Layer):
+    """Grouped-query attention (Ainslie et al., 2023): `num_attention_
+    heads` query heads of `head_dim` on `num_key_value_heads` key/value
+    heads, query head i on key/value head i // group. No biases.
+
+        q = x Wq -> heads x head_dim;  k = x Wk, v = x Wv -> kv heads x
+        head_dim;  positions "rotary": q, k rotated over the whole head
+        dim, "none": no positions at all (the causal mask is the only
+        order the layer sees)
+        o = softmax(q k^T / sqrt(head_dim)) v over the keys j <= t and,
+        with `window`, t - j < sliding_window_size;  out = o Wo
+
+    The core goes through F.scaled_dot_product_attention with k, v at
+    their own head count: on the chip the flash kernels pick a query
+    head's key/value head by their index maps and skip what lies behind
+    the window; nothing is repeated to the query heads' count."""
+
+    def __init__(self, cfg, window=False, positions="none"):
+        super().__init__()
+        if positions not in ("none", "rotary"):
+            raise ValueError(f"GroupedQueryAttention: positions="
+                             f"{positions!r} is not built")
+        if window and not cfg.sliding_window_size:
+            raise ValueError("a window layer needs sliding_window_size")
+        H = cfg.hidden_size
+        self.nh, self.hd = cfg.num_attention_heads, cfg.head_dim
+        self.nkv = getattr(cfg, "num_key_value_heads", self.nh)
+        if self.nh % self.nkv:
+            raise ValueError(f"{self.nh} query heads on {self.nkv} "
+                             "key/value heads")
+        self.window = cfg.sliding_window_size if window else None
+        self.q_proj = _linear(H, self.nh * self.hd, cfg)
+        self.k_proj = _linear(H, self.nkv * self.hd, cfg)
+        self.v_proj = _linear(H, self.nkv * self.hd, cfg)
+        self.o_proj = _linear(self.nh * self.hd, H, cfg)
+        self.rotary = nn.RotaryEmbedding(self.hd, cfg.rope_theta) \
+            if positions == "rotary" else None
+
+    def forward(self, x):
+        B, T, _ = x.shape
+        with jax.named_scope("gqa.project"):
+            q = self.q_proj(x).reshape([B, T, self.nh, self.hd])
+            k = self.k_proj(x).reshape([B, T, self.nkv, self.hd])
+            v = self.v_proj(x).reshape([B, T, self.nkv, self.hd])
+            if self.rotary is not None:
+                q, k = self.rotary(q), self.rotary(k)
+        with jax.named_scope("gqa.core"):
+            o = F.scaled_dot_product_attention(q, k, v, is_causal=True,
+                                               window=self.window)
+        return self.o_proj(o.reshape([B, T, self.nh * self.hd]))
+
+
 def _rms(width, cfg):
     return nn.RMSNorm(width, epsilon=cfg.rms_norm_eps)
 
@@ -182,13 +270,19 @@ def _moe_ffn(cfg):
         n_shared_experts=cfg.n_shared_experts,
         routed_scaling_factor=cfg.routed_scaling_factor,
         norm_topk_prob=cfg.norm_topk_prob,
-        weight_attr=nn.initializer.Normal(0.0, cfg.initializer_range))
+        weight_attr=nn.initializer.Normal(0.0, cfg.initializer_range),
+        scoring=cfg.router_scoring, activation=cfg.hidden_act)
 
 
 # the tables a layer spec is looked up in
-MIXERS = {"mla": LatentAttention}
-FFNS = {"dense": _dense_ffn, "moe": _moe_ffn}
+MIXERS = {"mla": LatentAttention,
+          "gqa_full": GroupedQueryAttention,
+          "gqa_window": functools.partial(GroupedQueryAttention, window=True,
+                                          positions="rotary")}
+FFNS = {"dense": _dense_ffn, "moe": _moe_ffn, "moe_pre": _moe_ffn}
 NORMS = {"rms": _rms}
+# feed-forwards whose router reads the layer's input, not their own
+ROUTED_ON_LAYER_INPUT = ("moe_pre",)
 
 # what a layer keeps for its backward pass beside its input: the flash
 # kernel's output and row statistics (named in ops/pallas/
@@ -207,10 +301,13 @@ class DecoderLayer(nn.Layer):
         self.self_attn = MIXERS[mixer](cfg)
         self.post_attention_layernorm = norm(cfg.hidden_size, cfg)
         self.mlp = FFNS[ffn](cfg)
+        self.routes_on_input = ffn in ROUTED_ON_LAYER_INPUT
 
     def forward(self, x):
-        x = x + self.self_attn(self.input_layernorm(x))
-        return x + self.mlp(self.post_attention_layernorm(x))
+        h = x + self.self_attn(self.input_layernorm(x))
+        y = self.post_attention_layernorm(h)
+        return h + (self.mlp(y, router_input=x) if self.routes_on_input
+                    else self.mlp(y))
 
 
 def _call_layer(layer, h, names):
@@ -337,4 +434,33 @@ def glm_4_7_flash_ep8(**overrides):
               num_experts_per_tok=4, routed_scaling_factor=1.8,
               norm_topk_prob=True)
     kw.update(overrides)
+    return DecoderConfig(**kw)
+
+
+def smallthinker_21b_ep8(**overrides):
+    """PowerInfer/SmallThinker-21BA3B-Instruct (config.json; report
+    arXiv:2507.20984) as ONE chip's share of an 8-way expert-parallel
+    group holds it, every width as published: hidden 2560; 28 query heads
+    of 128 on 4 key/value heads; layers in periods of four — one with
+    full causal attention and no positions, three with a window of 4,096
+    keys and rotary positions (theta 1.5e6) — each with 64 ReLU-gated
+    experts of width 768, 6 a token, weighted by a softmax over the
+    chosen logits of a router that reads the layer's input; no shared
+    expert, no dense layer. The share: experts 0-7 of the 64, rows
+    0-18,991 of the 151,936 of the vocabulary, one period of the 13
+    (benchmarks/configs/smallthinker-21b-ep8.json states the
+    deployment)."""
+    kw = dict(vocab_size=18992, hidden_size=2560, num_hidden_layers=4,
+              num_attention_heads=28, num_key_value_heads=4, head_dim=128,
+              sliding_window_size=4096, rope_theta=1500000,
+              rms_norm_eps=1e-6, moe_intermediate_size=768,
+              n_routed_experts=8, router_experts=64, local_expert_start=0,
+              n_shared_experts=0, num_experts_per_tok=6,
+              routed_scaling_factor=1.0, norm_topk_prob=True,
+              router_scoring="softmax_topk", hidden_act="relu")
+    kw.update(overrides)
+    # rope_layout = sliding_window_layout = [0, 1, 1, 1] x 13
+    kw.setdefault("layers", [
+        ("gqa_window" if i % 4 else "gqa_full", "moe_pre")
+        for i in range(kw["num_hidden_layers"])])
     return DecoderConfig(**kw)

@@ -16,17 +16,24 @@ from ...framework.core import Tensor, apply_op
 
 
 def _sdpa_reference(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
-                    scale=None):
-    # q,k,v: [B, T, H, D] (paddle layout)
+                    scale=None, window=None):
+    # q: [B, T, H, D], k, v: [B, T, KVH, D] (paddle layout); query head
+    # i attends key/value head i // (H / KVH)
     d = q.shape[-1]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
+    group = q.shape[2] // k.shape[2]
     qh = jnp.swapaxes(q, 1, 2)  # B,H,T,D
     kh = jnp.swapaxes(k, 1, 2)
     vh = jnp.swapaxes(v, 1, 2)
+    if group > 1:
+        kh, vh = (jnp.repeat(x, group, axis=1) for x in (kh, vh))
     logits = jnp.einsum("bhqd,bhkd->bhqk", qh, kh).astype(jnp.float32) * s
     if is_causal:
         Tq, Tk = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq)
+        if window is not None:
+            # a query sees a key only if query - key < window
+            cm &= ~jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq - window)
         logits = jnp.where(cm, logits, -1e30)
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -40,22 +47,30 @@ def _sdpa_reference(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, scale=None, name=None):
+                                 training=True, scale=None, name=None,
+                                 window=None):
     """Flash attention on TPU; XLA reference composition elsewhere.
 
-    Layout follows paddle incubate fused attention: [batch, seq, heads, dim].
+    Layout follows paddle incubate fused attention: [batch, seq, heads,
+    dim]. `key` and `value` may have fewer heads than `query`, a divisor
+    of its count (grouped-query attention: query head i attends key/value
+    head i // group). `window`, with `is_causal` on equal lengths: a
+    query sees a key only if query - key < window.
     """
+    if window is not None and not is_causal:
+        raise ValueError("window takes is_causal=True")
     from ...ops import flash_attention_available, flash_attention
 
     use_flash = (flash_attention_available() and dropout_p == 0.0
                  and attn_mask is None)
     if use_flash:
         return flash_attention(query, key, value, causal=is_causal,
-                               scale=scale)
+                               scale=scale, window=window)
 
     def fn(q, k, v, *rest):
         m = rest[0] if rest else None
-        return _sdpa_reference(q, k, v, m, dropout_p, is_causal, scale)
+        return _sdpa_reference(q, k, v, m, dropout_p, is_causal, scale,
+                               window)
 
     args = [query, key, value]
     if attn_mask is not None:

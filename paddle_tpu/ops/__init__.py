@@ -52,9 +52,9 @@ def kernels_partitioned_over(mesh, batch_axis, head_axis):
         _tracing.partition = prev
 
 
-def flash_attention(q, k, v, causal=False, scale=None):
+def flash_attention(q, k, v, causal=False, scale=None, window=None):
     from .pallas.flash_attention import flash_attention as fa
-    return fa(q, k, v, causal=causal, scale=scale,
+    return fa(q, k, v, causal=causal, scale=scale, window=window,
               partition=getattr(_tracing, "partition", None))
 
 

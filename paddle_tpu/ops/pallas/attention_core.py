@@ -182,6 +182,25 @@ def causal_q_tiles(col0, tk, tq, n):
     return _tiles_in(col0, tq, n), _tiles_in(col0 + tk - 1, tq, n, up=True)
 
 
+def window_kv_tiles(row0, tq, tk, n, window):
+    """(first, first_full), causal_kv_tiles' twin for the band's
+    TRAILING edge (a row sees a column only if row - column < window):
+    of the n kv tiles, tiles j < first lie wholly behind the band of
+    every row of the strip [row0, row0 + tq), first <= j < first_full
+    are crossed by the edge, the rest hold nothing behind it."""
+    return (_tiles_in(row0 - window + 1, tk, n),
+            _tiles_in(row0 + tq - window, tk, n, up=True))
+
+
+def window_q_tiles(col0, tk, tq, n, window):
+    """(n_full, n_visit), causal_q_tiles' twin for the trailing edge:
+    q tiles i < n_full see every column of the strip
+    [col0, col0 + tk) inside the band, n_full <= i < n_visit are crossed
+    by the edge, the rest lie wholly past it."""
+    return (_tiles_in(col0 + window, tq, n),
+            _tiles_in(col0 + tk - 1 + window, tq, n, up=True))
+
+
 def last_kv_block(i, block_q, block_k):
     """The last kv GRID block that q grid block i sees under the causal
     mask (row >= column): the one its last row's own column lies in.
@@ -200,19 +219,80 @@ def first_q_block(j, block_q, block_k, n_q):
                        i32(n_q - 1))
 
 
-def visited_tile_share(t_q, t_k, tiles, causal):
+def first_kv_block(i, block_q, block_k, window):
+    """last_kv_block's lower twin: the first kv GRID block that q grid
+    block i sees inside a band of `window` — the one its first row's
+    oldest visible column lies in."""
+    i32 = np.int32
+    return jax.lax.div(
+        jax.lax.max(i * i32(block_q) - i32(window - 1), i32(0)),
+        i32(block_k))
+
+
+def last_q_block(j, block_q, block_k, n_q, window):
+    """first_q_block's upper twin: the last q GRID block, of n_q, that
+    sees kv grid block j inside a band of `window` — the one the last
+    row that sees the block's last column lies in."""
+    i32 = np.int32
+    return jax.lax.min(
+        jax.lax.div((j + i32(1)) * i32(block_k) + i32(window - 2),
+                    i32(block_q)), i32(n_q - 1))
+
+
+def band_offsets(t_q, t_k, block_q, block_k, window):
+    """(distances, inside) for the grid blocks of a [t_q, t_k] causal
+    band of `window`: the distances first row - first column, Python
+    ints, at which a block is CROSSED by one of the band's edges (or
+    both) — neither wholly inside it nor wholly outside; the training
+    kernels build one body per such distance, its strips' extents
+    static — and whether any block lies wholly inside."""
+    crossed, inside = set(), False
+    for i in range(t_q // block_q):
+        for j in range(t_k // block_k):
+            o = i * block_q - j * block_k
+            if o + block_q - 1 < 0 or o - (block_k - 1) >= window:
+                continue
+            if o - (block_k - 1) >= 0 and o + block_q - 1 < window:
+                inside = True
+            else:
+                crossed.add(o)
+    return tuple(sorted(crossed)), inside
+
+
+def visited_tile_share(t_q, t_k, tiles, causal, window=None):
     """Share of the [t_q, t_k] score matrix's (tq, tk) sub-tiles that
     the kernels compute: 1.0 without a mask; under the causal mask what
-    causal_kv_tiles visits — (n + 1) / (2n) for n square tiles a side.
-    Where the grid blocks are not square the kernels skip by the block,
-    and `tiles` is the grid block."""
+    causal_kv_tiles visits — (n + 1) / (2n) for n square tiles a side —
+    less, inside a band of `window`, what window_kv_tiles leaves behind.
+    Where the grid blocks are not square a causal walk skips by the
+    block, and `tiles` is the grid block."""
     tq, tk = tiles
     nq, nk = t_q // tq, t_k // tk
     if not causal:
         return 1.0
-    visited = sum(causal_kv_tiles(i * tq, tq, tk, nk)[1]
-                  for i in range(nq))
+    visited = sum(
+        max(causal_kv_tiles(i * tq, tq, tk, nk)[1] - (
+            window_kv_tiles(i * tq, tq, tk, nk, window)[0]
+            if window else 0), 0)
+        for i in range(nq))
     return visited / float(nq * nk)
+
+
+def window_visited_share(t, d, window):
+    """Of the tiles a causal walk of the training kernels visits at
+    [t, t] and head dim d, the share their walk of a band of `window`
+    visits: the mean over the three kernels, each in its own strips
+    (the band's walk) against the causal walk's unit (its strips where
+    the grid block is square, else the block). What the pairs alone
+    give is (window * (2t - window + 1)) / (t * (t + 1))."""
+    b = choose_flash_blocks(t, t, d)
+    block = (b.block_q, b.block_k)
+    shares = []
+    for tiles in (b.fwd, b.dq, b.dkv):
+        walk = tiles if b.block_q == b.block_k else block
+        shares.append(visited_tile_share(t, t, tiles, True, window)
+                      / visited_tile_share(t, t, walk, True))
+    return sum(shares) / len(shares)
 
 
 def default_interpret(interpret):
@@ -290,12 +370,23 @@ def score_dot(q, k, scale):
     return s * jnp.float32(scale)
 
 
+def _rows_ahead(shape, row_axis):
+    """query row - kv column of every element of a `shape` tile whose
+    first row and column are 0; rows run along `row_axis`. The same for
+    every tile of a shape, so a mask costs one compare."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, row_axis)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - row_axis))
+
+
+def window_valid(row0, col0, shape, window, row_axis=0):
+    """causal_valid's twin for the band's trailing edge: query row -
+    kv column < window."""
+    return _rows_ahead(shape, row_axis) < window + col0 - row0
+
+
 def causal_valid(row0, col0, shape, row_axis=0):
     """`shape` bool tile of the causal mask, query row >= kv column,
     for a tile whose first row and column stand at absolute positions
     row0, col0; rows run along `row_axis` (1 for the dkv kernel's
-    transposed [columns, rows] tiles). The iota difference is the same
-    for every tile of a shape, so a tile costs one compare."""
-    ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, row_axis)
-             - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - row_axis))
-    return ahead >= col0 - row0
+    transposed [columns, rows] tiles)."""
+    return _rows_ahead(shape, row_axis) >= col0 - row0

@@ -70,11 +70,34 @@ a traced call got (`flash.calls.direct`, `.direct.g<g>`,
 `flash.calls.folded`) and the call's ops stand under a scope of that
 name.
 The causal mask is top-left aligned (row >= column).
+
+Grouped key/value heads: k, v may come [batch, seq, kv_heads, head_dim]
+with kv_heads a divisor of heads; query head i reads key/value head
+i // group, group = heads / kv_heads. Nothing is repeated in HBM: forward
+and dq walk the query heads and their kv-like index maps pick block
+`head // group`; dkv walks the KEY/VALUE heads, the group's members on
+one more grid axis inside the kv block's, dk and dv summed over members
+and q blocks in the kernel's f32 scratch and written [B, Tk, kv_heads*D].
+This takes one head to a lane block (D a multiple of 128); at other head
+dims the entry repeats k, v to the query heads' count and the equal-heads
+kernels run. Window (`window`, with causal, Tq == Tk): a query sees a
+key only if query - key < window. The band has two edges, and a block is
+skipped (and, by the index maps' first_kv_block / last_q_block, not
+copied) where it lies wholly behind the trailing one as above the
+diagonal; between the edges it runs unmasked; where an edge crosses it,
+the block's distance first row - first column is one of a few Python
+ints (attention_core.band_offsets: -512, 0, 3584, 4096 at T = 16,384,
+head dim 128, window 4,096), each with a body of its own whose strips
+have static extents — a strip behind the band runs nothing, one the
+trailing edge crosses masks only the tiles it crosses — whatever the
+block's shape. Equal heads and no window trace to the kernels as they
+were: the bodies above and a 4-D dkv grid.
 """
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -87,6 +110,13 @@ from . import attention_core as core
 WHOLE = "whole"        # wholly below it, or no causal mask: nothing masked
 DIAGONAL = "diagonal"  # a square block ON it: the strips' extents are static
 CROSSED = "crossed"    # any other block it crosses: all of it under the mask
+# inside a band (causal with a window) a crossed block's position is the
+# Python int first row - first column, one body per distance
+# (attention_core.band_offsets), and every strip's extent is static
+
+# the crossed parts of a strip's extent: before the clear part, after it,
+# or all of it
+HEAD, TAIL, ALL = "head", "tail", "all"
 
 
 def _block_offset(iq, ik, block_q, block_k, lone):
@@ -95,7 +125,14 @@ def _block_offset(iq, ik, block_q, block_k, lone):
     return 0 if lone else iq * block_q - ik * block_k
 
 
-def _on_diagonal_position(causal, offset, block_q, block_k, body):
+def _distance(where, offset):
+    """First row - first column of the block a body was built for: the
+    Python int it was built at inside a band, else this grid step's."""
+    return where if isinstance(where, int) else offset
+
+
+def _on_diagonal_position(causal, offset, block_q, block_k, body,
+                          band=None):
     """Run body(where) for this grid step. `offset` is the distance
     first row - first column of its block. Without a mask, or wholly
     below the diagonal: body(WHOLE). Crossed by it: a square block can
@@ -104,10 +141,26 @@ def _on_diagonal_position(causal, offset, block_q, block_k, body):
     head dims over 64) take the whole block under the mask —
     body(CROSSED). Wholly above: nothing runs. A lone block (offset the
     int 0: sequences up to 1024) is always the crossed one, and no
-    other body is built for it: half the kernel to trace and lower."""
+    other body is built for it: half the kernel to trace and lower.
+    Inside a `band` (window, the distances at which its edges cross a
+    block, whether any block lies wholly inside): body(distance) under
+    its own pl.when for each, body(WHOLE) for the blocks between the
+    edges, nothing for those wholly behind the band or above the
+    diagonal."""
     crossed = DIAGONAL if block_q == block_k else CROSSED
     if not causal:
         return body(WHOLE)
+    if band is not None:
+        window, distances, inside = band
+        if isinstance(offset, int):
+            return body(offset if offset in distances else WHOLE)
+        for o in distances:
+            pl.when(offset == o)(functools.partial(body, o))
+        if inside:
+            pl.when((offset >= block_k - 1)
+                    & (offset + (block_q - 1) < window))(
+                        functools.partial(body, WHOLE))
+        return None
     if isinstance(offset, int):
         return body(crossed)
     pl.when(offset >= block_k - 1)(functools.partial(body, WHOLE))
@@ -115,43 +168,81 @@ def _on_diagonal_position(causal, offset, block_q, block_k, body):
         functools.partial(body, crossed))
 
 
-def _extent(where, start, t, u, n, of_rows):
-    """(lo, hi, mlo, mhi), Python ints: along the other axis (n steps of
-    u wide) the strip of t rows (`of_rows`) or t columns that starts at
-    `start` within its block computes [lo, hi), never empty, of which
-    [mlo, mhi) is crossed by the diagonal and takes the mask: the tail
-    of a strip of rows, the head of a strip of columns."""
+def _extent(where, start, t, u, n, of_rows, window=None):
+    """(lo, hi, clear_lo, clear_hi), Python ints: along the other axis
+    (n steps of u wide) the strip of t rows (`of_rows`) or t columns that
+    starts at `start` within its block computes [lo, hi), never empty,
+    of which [clear_lo, clear_hi) takes no mask. What lies before and
+    after the clear part is crossed by an edge and takes the mask: the
+    tail of a strip of rows and the head of a strip of columns by the
+    diagonal, the other end — inside a band of `window` only, where
+    `where` is the block's distance first row - first column — by the
+    band's trailing edge. Nothing clear: clear_lo = clear_hi = lo. None:
+    the band leaves the strip nothing of this block."""
     if where == WHOLE:
-        return 0, n * u, 0, 0
-    if where == CROSSED:
         return 0, n * u, 0, n * u
+    if where == CROSSED:
+        return 0, n * u, 0, 0
+    o = 0 if where == DIAGONAL else where
     if of_rows:
-        n_full, n_visit = core.causal_kv_tiles(start, t, u, n)
-        ext = 0, n_visit * u, n_full * u, n_visit * u
+        n_full, n_visit = core.causal_kv_tiles(o + start, t, u, n)
+        first, first_full = core.window_kv_tiles(
+            o + start, t, u, n, window) if window else (0, 0)
     else:
-        first, first_full = core.causal_q_tiles(start, t, u, n)
-        ext = first * u, n * u, first * u, first_full * u
-    # every strip of a block on the diagonal sees some of it
-    assert ext[0] < ext[1], (where, start, t, u, n)
-    return ext
+        first, first_full = core.causal_q_tiles(start - o, t, u, n)
+        n_full, n_visit = core.window_q_tiles(
+            start - o, t, u, n, window) if window else (n, n)
+    lo, hi = first * u, n_visit * u
+    if lo >= hi:
+        # every strip of a block on the diagonal sees some of it
+        assert window, (where, start, t, u, n)
+        return None
+    clear_lo, clear_hi = max(first_full * u, lo), min(n_full * u, hi)
+    if clear_lo >= clear_hi:
+        clear_lo = clear_hi = lo
+    return lo, hi, clear_lo, clear_hi
 
 
 def _mask_crossed(s, extent, valid):
     """The scores s of the extent's [lo, hi) columns with the crossed
-    part [mlo, mhi) — head, tail or all of it — set to NEG_INF wherever
-    valid(shape, first column) is False; the rest passes untouched."""
-    lo, hi, mlo, mhi = extent
-    if mlo == mhi:
+    parts — before the clear part [clear_lo, clear_hi), after it, or all
+    of it — set to NEG_INF wherever valid(shape, first column, part) is
+    False; the clear part passes untouched."""
+    lo, hi, clear_lo, clear_hi = extent
+    if (clear_lo, clear_hi) == (lo, hi):
         return s
+
+    def masked(a, b, part):
+        crossed = s[:, a - lo:b - lo]
+        return jnp.where(valid(crossed.shape, a, part), crossed,
+                         jnp.float32(NEG_INF))
+
+    if clear_lo == clear_hi:
+        return masked(lo, hi, ALL)
     parts = []
-    if mlo > lo:
-        parts.append(s[:, :mlo - lo])
-    crossed = s[:, mlo - lo:mhi - lo]
-    parts.append(jnp.where(valid(crossed.shape, mlo), crossed,
-                           jnp.float32(NEG_INF)))
-    if hi > mhi:
-        parts.append(s[:, mhi - lo:])
+    if clear_lo > lo:
+        parts.append(masked(lo, clear_lo, HEAD))
+    parts.append(s[:, clear_lo - lo:clear_hi - lo])
+    if hi > clear_hi:
+        parts.append(masked(clear_hi, hi, TAIL))
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _edges_valid(row0, col0, shape, part, window, of_rows):
+    """The mask of a crossed part of a strip's scores, whose first row
+    and column stand at row0, col0 (rows along axis 0 for a strip of
+    rows, along axis 1 for dkv's transposed strips of columns): the
+    diagonal's where it crosses that part (the tail of a strip of rows,
+    the head of a strip of columns), the trailing edge's at the other
+    end, both where nothing between them is clear."""
+    axis = 0 if of_rows else 1
+    ok = None
+    if part == ALL or (part == TAIL) == of_rows:
+        ok = core.causal_valid(row0, col0, shape, row_axis=axis)
+    if window and (part == ALL or (part == HEAD) == of_rows):
+        behind = core.window_valid(row0, col0, shape, window, row_axis=axis)
+        ok = behind if ok is None else ok & behind
+    return ok
 
 
 def _f32(ref):
@@ -196,7 +287,7 @@ def _along_lanes(col):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry_refs, scale,
-                causal, block_q, block_k, tiles, heads, lone):
+                causal, block_q, block_k, tiles, heads, lone, band=None):
     """One grid step: a [block_q, heads * d] block of q — `heads` heads
     side by side in its lanes, as the model's [B, T, H*D] holds them —
     against a kv block of the same heads. carry_refs (m, l, acc), each
@@ -204,7 +295,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry_refs, scale,
     grid steps; with ONE kv block there is nothing to hold and none are
     passed: a strip's softmax is born and finalized in place. The mask
     needs no zeroing of probabilities here: every row sees column 0, in
-    the first kv block, so no row meets a later block untouched."""
+    the first kv block, so no row meets a later block untouched. (Inside
+    a band a row's first block may hold nothing it sees: the carry then
+    takes a finite NEG_INF maximum, which the row's first visible column,
+    always met later, wipes with alpha = 0.)"""
+    window = band and band[0]
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -226,11 +321,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry_refs, scale,
         v = _f32(v_ref)                             # [bk, w], for p.v
         for i in range(block_q // tq):
             rows = slice(i * tq, (i + 1) * tq)
-            ext = _extent(where, i * tq, tq, tk, block_k // tk, True)
+            ext = _extent(where, i * tq, tq, tk, block_k // tk, True,
+                          window)
+            if ext is None:
+                assert carry_refs
+                continue
             lo, hi = ext[:2]
             # every head of the block sees the same mask: made once
-            valid = functools.cache(lambda shape, col: core.causal_valid(
-                offset + i * tq, col, shape))
+            valid = functools.cache(lambda shape, col, part: _edges_valid(
+                _distance(where, offset) + i * tq, col, shape, part, window,
+                True))
             outs = []
             for h in range(heads):
                 s = core.score_dot(_head_lanes(q_ref[0, rows, :], h, d),
@@ -249,7 +349,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry_refs, scale,
             if outs:
                 o_ref[0, rows, :] = _join_heads(outs, d).astype(o_ref.dtype)
 
-    _on_diagonal_position(causal, offset, block_q, block_k, _body)
+    _on_diagonal_position(causal, offset, block_q, block_k, _body, band)
 
     if carry_refs:
         @pl.when(ik == nk - 1)
@@ -264,7 +364,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry_refs, scale,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
                delta_ref, *acc_ref, scale, causal, block_q, block_k, tiles,
-               heads, lone):
+               heads, lone, band=None):
     """delta = rowsum(out * dout) a head is made HERE, from the out and
     dout blocks as they lie, and is an output too, which dkv reads. With
     ONE kv block each strip makes its own as it goes (a column, as the
@@ -272,6 +372,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     dots); with several, a q block's first kv step makes the block's
     before anything else and the strips read it back. acc_ref: the f32
     dq between kv grid steps; none with one kv block."""
+    window = band and band[0]
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -300,10 +401,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     def _body(where):
         k32 = _f32(k_ref)                           # [bk, w], for ds.k
         for i, rows in enumerate(strips):
-            ext = _extent(where, i * tq, tq, tk, block_k // tk, True)
+            ext = _extent(where, i * tq, tq, tk, block_k // tk, True,
+                          window)
+            if ext is None:
+                assert acc_ref
+                continue
             lo, hi = ext[:2]
-            valid = functools.cache(lambda shape, col: core.causal_valid(
-                offset + i * tq, col, shape))
+            valid = functools.cache(lambda shape, col, part: _edges_valid(
+                _distance(where, offset) + i * tq, col, shape, part, window,
+                True))
             delta = [delta_ref[h, 0, rows][:, None] for h in range(heads)] \
                 if acc_ref else delta_of(rows)
             dqs = []
@@ -326,7 +432,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
             else:
                 dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
 
-    _on_diagonal_position(causal, offset, block_q, block_k, _body)
+    _on_diagonal_position(causal, offset, block_q, block_k, _body, band)
 
     if acc_ref:
         @pl.when(ik == nk - 1)
@@ -336,23 +442,30 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, *acc_refs, scale, causal, block_q, block_k,
-                tiles, heads, lone):
+                tiles, heads, lone, band=None, group=1):
     """Strips of kv columns, computed TRANSPOSED, [tk, rows]: lse and
     delta then broadcast along the lanes they are stored in, and all
     four dots are A.B or A.B^T — none contracts over its left operand's
     rows. acc_refs (dk, dv): the f32 sums between q grid steps; none
     where one q block sees every kv column. (Where it does not — causal
     with Tk > Tq — kv blocks wholly above the diagonal run nothing, and
-    the sums, zeroed at the first step, are what writes their zeros.)"""
+    the sums, zeroed at the first step, are what writes their zeros.)
+    `group` query heads on this key/value head: the grid walks them on
+    one more axis inside the kv block's, and the sums hold all of them."""
+    window = band and band[0]
     ik = pl.program_id(2)
-    iq = pl.program_id(3)
-    nq = pl.num_programs(3)
+    iq = pl.program_id(3 + (group > 1))
+    nq = pl.num_programs(3 + (group > 1))
+    first, last = iq == 0, iq == nq - 1
+    if group > 1:
+        first &= pl.program_id(3) == 0
+        last &= pl.program_id(3) == group - 1
     tq, tk = tiles
     d = q_ref.shape[-1] // heads
     offset = _block_offset(iq, ik, block_q, block_k, lone)
 
     if acc_refs:
-        @pl.when(iq == 0)
+        @pl.when(first)
         def _init():
             for ref in acc_refs:
                 ref[:] = jnp.zeros_like(ref)
@@ -362,10 +475,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         do32 = _f32(do_ref)                         # for p^T.do
         for j in range(block_k // tk):
             cols = slice(j * tk, (j + 1) * tk)
-            ext = _extent(where, j * tk, tk, tq, block_q // tq, False)
+            ext = _extent(where, j * tk, tk, tq, block_q // tq, False,
+                          window)
+            if ext is None:
+                assert acc_refs
+                continue
             lo, hi = ext[:2]
-            valid = functools.cache(lambda shape, row: core.causal_valid(
-                offset + row, j * tk, shape, row_axis=1))
+            valid = functools.cache(lambda shape, row, part: _edges_valid(
+                _distance(where, offset) + row, j * tk, shape, part, window,
+                False))
             dks, dvs = [], []
             for h in range(heads):
                 st = core.score_dot(_head_lanes(k_ref[0, cols, :], h, d),
@@ -390,10 +508,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
                 dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
 
-    _on_diagonal_position(causal, offset, block_q, block_k, _body)
+    _on_diagonal_position(causal, offset, block_q, block_k, _body, band)
 
     if acc_refs:
-        @pl.when(iq == nq - 1)
+        @pl.when(last)
         def _fin():
             dk_ref[0] = acc_refs[0][:].astype(dk_ref.dtype)
             dv_ref[0] = acc_refs[1][:].astype(dv_ref.dtype)
@@ -414,21 +532,25 @@ def _unfold(x, heads):
 
 
 def _in_kernel_layout(heads, xs):
-    """(arrays, heads, g, back) for [B, T, H*D] arrays `xs`: as they lie,
-    g heads to a lane block, where attention_core.heads_per_block finds
-    a block that picks heads out of the lanes; else with the heads
-    FOLDED into the batch ([B*H, T, D]: one head, whose block is the
-    whole minor dimension), which costs a transposing copy of every
-    array each way. back() returns a [.., T, heads * D] result of the
-    kernels to [B, T, H*D]."""
-    g = core.heads_per_block(heads, xs[0].shape[-1] // heads)
+    """(arrays, H, g, group, back) for q-like [B, T, H*D] and kv-like
+    [B, T, KVH*D] arrays `xs`, heads = (H, KVH): as they lie, g heads to
+    a lane block, where attention_core.heads_per_block finds a block
+    that picks heads out of the lanes — `group` = H / KVH query heads to
+    a key/value head, which the entry allows over 1 only where g = 1;
+    else with the heads FOLDED into the batch ([B*H, T, D]: one head,
+    whose block is the whole minor dimension), which costs a transposing
+    copy of every array each way. back() returns a [.., T, heads * D]
+    result of the kernels to [B, T, heads * D]."""
+    H, KVH = heads
+    g = core.heads_per_block(H, xs[0].shape[-1] // H)
     if g is None:
-        return ([_fold(x, heads) for x in xs], 1, 1,
-                functools.partial(_unfold, heads=heads))
-    return xs, heads, g, lambda x: x
+        return ([_fold(x, H) for x in xs], 1, 1, 1,
+                functools.partial(_unfold, heads=H))
+    return xs, H, g, H // KVH, lambda x: x
 
 
-def _index_maps(groups, causal, blocks, n_q, kv_major=False):
+def _index_maps(groups, causal, blocks, n_q, kv_major=False, window=None,
+                group=1):
     """(row, col, stat) index maps over the grid (batch, head group, q
     block, kv block) — dkv's grid, `kv_major`, has the last two swapped:
     blocks of q-like arrays [B, Tq, H*D], of kv-like ones, and of the
@@ -436,46 +558,76 @@ def _index_maps(groups, causal, blocks, n_q, kv_major=False):
     A grid step the causal mask leaves nothing of runs nothing, and
     should move nothing either: along the grid's inner axis its index
     is held at the nearest block that IS computed
-    (attention_core.last_kv_block / first_q_block), and the pipeline
-    starts no copy for an index that did not change."""
+    (attention_core.last_kv_block / first_q_block and, inside a band of
+    `window`, their twins first_kv_block / last_q_block), and the
+    pipeline starts no copy for an index that did not change.
+    `group` query heads share a key/value head: the kv-like arrays are
+    [B, Tk, H/group * D] and their head is `head // group`; dkv's grid
+    walks the key/value heads, with the group's query heads on one more
+    axis before the q blocks': (batch, kv head, kv block, member, q
+    block)."""
     bq, bk = blocks.block_q, blocks.block_k
     if not causal:
         seen = lambda i, j: (i, j)
     elif kv_major:
-        seen = lambda i, j: (jax.lax.max(
-            i, core.first_q_block(j, bq, bk, n_q)), j)
+        def seen(i, j):
+            i = jax.lax.max(i, core.first_q_block(j, bq, bk, n_q))
+            if window:
+                i = jax.lax.min(i, core.last_q_block(j, bq, bk, n_q, window))
+            return i, j
     else:
-        seen = lambda i, j: (i, jax.lax.min(
-            j, core.last_kv_block(i, bq, bk)))
+        def seen(i, j):
+            j = jax.lax.min(j, core.last_kv_block(i, bq, bk))
+            if window:
+                j = jax.lax.max(j, core.first_kv_block(i, bq, bk, window))
+            return i, j
+
+    n = np.int32(group)       # Mosaic takes no i64, and x64 mode is on
 
     def over_grid(f):
-        g = lambda b, h, i, j: f(b, h, *seen(i, j))
+        g = lambda b, h, i, j: f(b, h, jax.lax.div(h, n) if group > 1 else h,
+                                 *seen(i, j))
+        if kv_major and group > 1:
+            return lambda b, kvh, j, r, i: g(b, kvh * n + r, i, j)
         return (lambda b, h, j, i: g(b, h, i, j)) if kv_major else g
-    return (over_grid(lambda b, h, i, j: (b, i, h)),
-            over_grid(lambda b, h, i, j: (b, j, h)),
-            over_grid(lambda b, h, i, j: (b * groups + h, I0, i)))
+    return (over_grid(lambda b, h, kvh, i, j: (b, i, h)),
+            over_grid(lambda b, h, kvh, i, j: (b, j, kvh)),
+            over_grid(lambda b, h, kvh, i, j: (b * groups + h, I0, i)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, heads, causal, scale, interpret):
-    """q, k, v [B, T, H*D] -> out [B, Tq, H*D], all as the model holds
-    them: the residual a remat policy saves is the value the model
-    consumes."""
-    return _flash_fwd_impl(q, k, v, heads, causal, scale, interpret)[0]
+def _band(window, t_q, t_k, blocks):
+    """The kernels' `band`: (window, the distances at which a grid block
+    is crossed by an edge of the band, whether any block lies wholly
+    inside it); None without a window."""
+    if window is None:
+        return None
+    return (window,) + core.band_offsets(
+        t_q, t_k, blocks.block_q, blocks.block_k, window)
 
 
-def _flash_fwd_impl(q, k, v, heads, causal, scale, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, heads, causal, window, scale, interpret):
+    """q [B, Tq, H*D], k, v [B, Tk, KVH*D] -> out [B, Tq, H*D], all as
+    the model holds them: the residual a remat policy saves is the value
+    the model consumes. `heads` = (H, KVH)."""
+    return _flash_fwd_impl(q, k, v, heads, causal, window, scale,
+                           interpret)[0]
+
+
+def _flash_fwd_impl(q, k, v, heads, causal, window, scale, interpret):
     """out [B, Tq, H*D], lse [B*H, 1, Tq]."""
-    (q, k, v), H, g, back = _in_kernel_layout(heads, (q, k, v))
+    (q, k, v), H, g, group, back = _in_kernel_layout(heads, (q, k, v))
     B, Tq, HD = q.shape
     Tk, w = k.shape[1], HD // H * g
     blocks = core.choose_flash_blocks(Tq, Tk, HD // H)
     bq, bk = blocks.block_q, blocks.block_k
-    row, col, stat = _index_maps(H // g, causal, blocks, Tq // bq)
+    row, col, stat = _index_maps(H // g, causal, blocks, Tq // bq,
+                                 window=window, group=group)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, tiles=blocks.fwd,
-                          heads=g, lone=(Tq, Tk) == (bq, bk)),
+                          heads=g, lone=(Tq, Tk) == (bq, bk),
+                          band=_band(window, Tq, Tk, blocks)),
         grid=(B, H // g, Tq // bq, Tk // bk),
         in_specs=[pl.BlockSpec((1, bq, w), row),
                   pl.BlockSpec((1, bk, w), col),
@@ -498,8 +650,9 @@ def _flash_fwd_impl(q, k, v, heads, causal, scale, interpret):
     return back(out), lse
 
 
-def _flash_fwd(q, k, v, heads, causal, scale, interpret):
-    out, lse = _flash_fwd_impl(q, k, v, heads, causal, scale, interpret)
+def _flash_fwd(q, k, v, heads, causal, window, scale, interpret):
+    out, lse = _flash_fwd_impl(q, k, v, heads, causal, window, scale,
+                               interpret)
     # named save points: a caller's remat policy that saves "flash_out"
     # and "flash_lse" keeps this kernel out of its backward pass. The
     # PRIMAL comes from the named value too: tagged only as a residual,
@@ -510,19 +663,21 @@ def _flash_fwd(q, k, v, heads, causal, scale, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(heads, causal, scale, interpret, res, dout):
+def _flash_bwd(heads, causal, window, scale, interpret, res, dout):
     *acts, lse = res
-    (q, k, v, out, dout), H, g, back = _in_kernel_layout(
+    (q, k, v, out, dout), H, g, group, back = _in_kernel_layout(
         heads, (*acts, dout))
     B, Tq, HD = q.shape
     Tk, w = k.shape[1], HD // H * g
     blocks = core.choose_flash_blocks(Tq, Tk, HD // H)
     bq, bk = blocks.block_q, blocks.block_k
     kernel = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
-                  heads=g, lone=(Tq, Tk) == (bq, bk))
+                  heads=g, lone=(Tq, Tk) == (bq, bk),
+                  band=_band(window, Tq, Tk, blocks))
     stats = jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32)
+    maps = dict(window=window, group=group)
 
-    row, col, stat = _index_maps(H // g, causal, blocks, Tq // bq)
+    row, col, stat = _index_maps(H // g, causal, blocks, Tq // bq, **maps)
     dq, delta = pl.pallas_call(
         functools.partial(_dq_kernel, tiles=blocks.dq, **kernel),
         grid=(B, H // g, Tq // bq, Tk // bk),
@@ -541,10 +696,14 @@ def _flash_bwd(heads, causal, scale, interpret, res, dout):
     )(q, k, v, dout, out, lse)
 
     row, col, stat = _index_maps(H // g, causal, blocks, Tq // bq,
-                                 kv_major=True)
+                                 kv_major=True, **maps)
+    # a key/value head's `group` query heads: one more grid axis inside
+    # the kv block's, its gradients summed in the kernel's f32 scratch
+    members = (group,) * (group > 1)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, tiles=blocks.dkv, **kernel),
-        grid=(B, H // g, Tk // bk, Tq // bq),
+        functools.partial(_dkv_kernel, tiles=blocks.dkv, group=group,
+                          **kernel),
+        grid=(B, H // g // group, Tk // bk, *members, Tq // bq),
         in_specs=[pl.BlockSpec((1, bq, w), row),
                   pl.BlockSpec((1, bk, w), col),
                   pl.BlockSpec((1, bk, w), col),
@@ -553,11 +712,12 @@ def _flash_bwd(heads, causal, scale, interpret, res, dout):
                   pl.BlockSpec((g, 1, bq), stat)],
         out_specs=[pl.BlockSpec((1, bk, w), col),
                    pl.BlockSpec((1, bk, w), col)],
-        out_shape=[jax.ShapeDtypeStruct((B, Tk, HD), k.dtype),
-                   jax.ShapeDtypeStruct((B, Tk, HD), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
                         pltpu.VMEM((bk, w), jnp.float32)] * (
-                            Tq > bq or (causal and Tk > Tq)),
+                            Tq > bq or group > 1
+                            or (causal and Tk > Tq)),
         name="flash_attention_dkv",
         interpret=interpret,
     )(q, k, v, dout, lse, delta)
@@ -568,22 +728,55 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention_arrays(q, k, v, causal=False, scale=None,
-                           interpret=False):
-    """Array-level entry: q,k,v [B, T, H, D] → out [B, T, H, D]. Which
-    layout the kernels get is counted as it traces (profiler.monitor
-    `flash.calls.direct`, with `.g<heads a block>`, or
-    `flash.calls.folded`) and names the scope its ops stand under."""
+                           interpret=False, window=None):
+    """Array-level entry: q [B, T, H, D], k, v [B, T, KVH, D] → out
+    [B, T, H, D]; query head i attends key/value head i // (H / KVH).
+    `window`: a query sees a key only if query - key < window (with
+    `causal`, on equal lengths; a window the sequence fits in is none).
+    Which layout the kernels get is counted as it traces
+    (profiler.monitor `flash.calls.direct`, with `.g<heads a block>`, or
+    `flash.calls.folded`; `flash.calls.gqa` where they get fewer
+    key/value heads; `flash.calls.window`, with the share of a causal
+    walk's tiles the band's walk visits observed in
+    `flash.window.visited_share`, in %) and names the scope its ops
+    stand under, with `.kv<key/value heads>` and `.w<window>` after it
+    where the call has them."""
     from ...profiler import monitor
     B, Tq, H, D = q.shape
+    Tk, KVH = k.shape[1:3]
+    if H % KVH:
+        raise ValueError(f"{H} query heads on {KVH} key/value heads")
+    if window is not None:
+        if not causal or Tq != Tk or window < 1:
+            raise ValueError("a window takes causal attention on equal "
+                             f"lengths (causal={causal}, {Tq} x {Tk}, "
+                             f"window={window})")
+        window = None if window >= Tk else int(window)
     scale = core.default_scale(scale, D)
     g = core.heads_per_block(H, D)
+    if KVH != H and g != 1:
+        # no lane block picks a key/value head for several query heads
+        # side by side: every query head gets its own copy
+        k, v = (jnp.repeat(x, H // KVH, axis=2) for x in (k, v))
+        KVH = H
     path = "folded" if g is None else "direct"
     monitor.counter(f"flash.calls.{path}").inc()
     if g is not None:
         monitor.counter(f"flash.calls.direct.g{g}").inc()
-    with jax.named_scope(f"flash.{path}"):
-        out = _flash(*(x.reshape(*x.shape[:2], H * D) for x in (q, k, v)),
-                     H, causal, scale, interpret)
+    if KVH != H:
+        monitor.counter("flash.calls.gqa").inc()
+    if window is not None:
+        monitor.counter("flash.calls.window").inc()
+        monitor.histogram("flash.window.visited_share").observe(
+            100.0 * core.window_visited_share(Tq, D, window))
+    # the scope names the call in a trace and in the compile record's
+    # `kernels` field: "flash.direct.kv4.w4096" has 4 key/value heads
+    # under its query heads and a window of 4,096
+    scope = f"flash.{path}" + (f".kv{KVH}" if KVH != H else "") \
+        + (f".w{window}" if window is not None else "")
+    with jax.named_scope(scope):
+        out = _flash(*(x.reshape(*x.shape[:2], -1) for x in (q, k, v)),
+                     (H, KVH), causal, window, scale, interpret)
     return out.reshape(B, Tq, H, D)
 
 
@@ -604,7 +797,7 @@ def _per_shard(fn, mesh, batch_axis, head_axis, q_shape):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
-                    partition=None):
+                    partition=None, window=None):
     """Tensor-level entry used by F.scaled_dot_product_attention.
     `partition` = (mesh, batch_axis, head_axis), handed down by the
     builder of an auto-partitioned SPMD program
@@ -615,7 +808,8 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
 
     def fn(qa, ka, va):
         call = lambda a, b, c: flash_attention_arrays(
-            a, b, c, causal=causal, scale=scale, interpret=interpret)
+            a, b, c, causal=causal, scale=scale, interpret=interpret,
+            window=window)
         if partition is not None:
             call = _per_shard(call, *partition, qa.shape)
         return call(qa, ka, va)

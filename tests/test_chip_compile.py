@@ -233,37 +233,85 @@ def test_scanned_stack_gradient_runs_flash_forward_once(
     assert copied.count(own) <= at_most, copied
 
 
-def test_glm_flash_ep8_step_fits_the_chip(compile_for_chip, chip_branches):
-    """The whole TrainStep of glm-4.7-flash-ep8's cell (2 x 4096 ids,
-    AdamW with float32 masters, the fused update): what the compiler
-    says it needs stays under the 15.75 GB a v5e leaves a program, with
-    the flash residuals saved; one forward kernel for the leading layer
-    and one for the scan, as many as dq and dkv."""
+def _compiled_train_step(compile_for_chip, config, ids_shape):
+    """(compiled TrainStep, bytes the compiler says it needs) of a
+    decoder preset as the benchmark's train runner builds it: bf16,
+    AdamW with float32 masters, the fused update."""
     import paddle_tpu as paddle
     import paddle_tpu.nn.functional as Fn
     from paddle_tpu import optimizer as opt
     from paddle_tpu.jit import TrainStep
-    from paddle_tpu.models.decoder import (DecoderForCausalLM,
-                                           glm_4_7_flash_ep8)
+    from paddle_tpu.models.decoder import DecoderForCausalLM
     paddle.seed(0)
-    model = DecoderForCausalLM(glm_4_7_flash_ep8())
+    model = DecoderForCausalLM(config)
     model.bfloat16()
     step = TrainStep(
         model, lambda logits, y: Fn.cross_entropy(
             logits.reshape([-1, logits.shape[-1]]), y.reshape([-1])),
         opt.AdamW(learning_rate=1e-4, weight_decay=0.01,
                   parameters=model.parameters(), multi_precision=True))
-    ids = paddle.to_tensor(np.zeros((2, 4096), np.int32))
+    ids = paddle.to_tensor(np.zeros(ids_shape, np.int32))
     _, args = step._prep((ids, ids), 1)
     specs = jax.tree.map(
         lambda a: compile_for_chip.sds((jnp.shape(a), jnp.result_type(a))),
         args)
     c = step._jitted.lower(*specs).compile()
     m = c.memory_analysis()
-    needs = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    return c, (m.argument_size_in_bytes + m.output_size_in_bytes
+               + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_glm_flash_ep8_step_fits_the_chip(compile_for_chip, chip_branches):
+    """The whole TrainStep of glm-4.7-flash-ep8's cell (2 x 4096 ids,
+    AdamW with float32 masters, the fused update): what the compiler
+    says it needs stays under the 15.75 GB a v5e leaves a program, with
+    the flash residuals saved; one forward kernel for the leading layer
+    and one for the scan, as many as dq and dkv."""
+    from paddle_tpu.models.decoder import glm_4_7_flash_ep8
+    c, needs = _compiled_train_step(compile_for_chip, glm_4_7_flash_ep8(),
+                                    (2, 4096))
     assert needs < 15.75e9, needs
     assert _flash_kernel_counts(c) == (2, 2, 2)
+
+
+def test_smallthinker_ep8_step_fits_the_chip(compile_for_chip,
+                                             chip_branches):
+    """The whole TrainStep of smallthinker-21b-ep8's cell (1 x 16,384
+    ids): under the 15.75 GB; the full layer's kernels and the window
+    scan's, each once; and k, v reach the kernels at their own 4 heads —
+    nothing in the module has them at the 28 query heads' width."""
+    from paddle_tpu.models.decoder import smallthinker_21b_ep8
+    c, needs = _compiled_train_step(compile_for_chip, smallthinker_21b_ep8(),
+                                    (1, 16384))
+    assert needs < 15.75e9, needs
+    assert _flash_kernel_counts(c) == (2, 2, 2)
+    operands = _flash_operands(c)
+    for kernel, text in operands.items():
+        # q (dout, out) at 28 x 128 lanes, k and v at 4 x 128
+        assert text.count("bf16[1,16384,3584]{2,1,0}") >= 1, (kernel, text)
+        assert text.count("bf16[1,16384,512]{2,1,0}") == 2, (kernel, text)
+    assert not re.search(r"bf16\[1,16384,4,7,128\]", c.as_text())
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_flash_attention_grouped_at_16k(compile_for_chip, window):
+    """smallthinker-21b-ep8's two attention cores, [1, 16384, 28 on 4,
+    128]: 16 x 32 grid blocks of 1024 x 512 a head; inside the window a
+    body per distance at which an edge crosses a block (-512, 0, 3584,
+    4096) and one for the blocks between; dk, dv written at 4 heads,
+    the group's 7 query heads summed inside dkv."""
+    from paddle_tpu.ops.pallas.flash_attention import \
+        flash_attention_arrays
+    q, kv = ((1, 16384, 28, 128), BF16), ((1, 16384, 4, 128), BF16)
+
+    def loss(q, k, v):
+        out = flash_attention_arrays(q, k, v, causal=True, window=window,
+                                     interpret=False)
+        return jnp.sum(out.astype(F32))
+
+    c = compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert _flash_kernel_counts(c) == (1, 1, 1)
+    assert _flash_operands(c)["dkv"].count("bf16[1,16384,512]{2,1,0}") == 2
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)],
