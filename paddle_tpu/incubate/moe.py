@@ -176,7 +176,7 @@ class MoELayer(nn.Layer):
 # the dropless expert layer
 # ---------------------------------------------------------------------
 STEP_COUNTERS = ("moe.assignments", "moe.local_assignments",
-                 "moe.expert_load_max", "moe.dropped")
+                 "moe.expert_load_max", "moe.dropped", "moe.buffer_rows")
 
 
 SCORINGS = ("sigmoid", "softmax_topk")
@@ -211,11 +211,31 @@ def route_tokens(x, router_w, bias, top_k, scale, norm_topk_prob,
     return chosen.astype(jnp.int32), picked * scale
 
 
+ROW_TILE = 128     # a rung is a whole number of the chip's 128-row tiles
+LOW_RUNG = 1.25    # the lowest rung over the uniform count (PERF.md, PR 34)
+
+
+def buffer_rungs(N, k, n_local, num_experts):
+    """The static row counts the sorted buffer may take, ascending, from
+    shapes alone: LOW_RUNG times what the held experts get of N * k
+    assignments under uniform routing, rounded up to ROW_TILE, and the
+    worst case N * min(k, n_local), which holds any routing. Where the
+    first would not be smaller (every expert held, tiny shapes) the
+    worst case stands alone."""
+    worst = N * min(k, n_local)
+    uniform = N * k * n_local / num_experts
+    low = math.ceil(LOW_RUNG * uniform / ROW_TILE) * ROW_TILE
+    return (low, worst) if low < worst else (worst,)
+
+
 def dispatch_plan(chosen, first, n_local):
     """Where every assignment to a HELD expert stands in the sorted
     buffer: a stable sort of the held assignments by expert, the groups
-    contiguous from row 0 (what jax.lax.ragged_dot takes). The buffer has
-    N * min(k, n_local) rows, the worst case. chosen [N, k]. Returns
+    contiguous from row 0 (what jax.lax.ragged_dot takes). The plan is
+    made at the worst case's N * min(k, n_local) rows and serves every
+    rung of `buffer_rungs`: a buffer of R rows takes src[:R], and `pos`
+    marks "not held" with the worst case's length, which no rung's
+    rows reach. chosen [N, k]. Returns
       sizes [n_local]   assignments to each held expert
       pos [N, k]        the row of each assignment (n_rows: not held)
       src [n_rows]      the assignment (n * k + j) of each row (N * k:
@@ -242,11 +262,13 @@ def dispatch_plan(chosen, first, n_local):
 
 def _gathered(rows, pos):
     """[rows[pos[:, j]] for each of a token's k assignments j], float32
-    [N, D] each, a row past the buffer (`pos` = its length: not held)
-    read as the last one — the caller masks it. An assignment at a time:
-    one [N, k, D] gather has its k (4, 6) padded to the chip's 8-row
-    tile, is written and read back in float32 and laid out anew on the
-    way to its sum (PERF.md section 6, PR 31)."""
+    [N, D] each, a row past the buffer (`pos` >= its length: not held)
+    read as the last one — the caller SELECTS it away (that row may hold
+    anything, see `grouped_matmul`: a product with a zero weight would
+    keep its NaN). An assignment at a time: one [N, k, D] gather has its
+    k (4, 6) padded to the chip's 8-row tile, is written and read back
+    in float32 and laid out anew on the way to its sum (PERF.md section
+    6, PR 31)."""
     last = rows.shape[0] - 1
     return [rows[jnp.minimum(pos[:, j], last)].astype(jnp.float32)
             for j in range(pos.shape[1])]
@@ -280,8 +302,8 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 def _combine(ys, w, src, pos):
     """y [N, D] = sum over a token's held assignments of w[n, j] *
     ys[pos[n, j]] (float32 sum). Backward: a gather by `src`."""
-    wl = jnp.where(pos < ys.shape[0], w, 0.0)
-    return sum(got * wl[:, j, None]
+    held = pos < ys.shape[0]
+    return sum(jnp.where(held[:, j, None], got * w[:, j, None], 0.0)
                for j, got in enumerate(_gathered(ys, pos))).astype(ys.dtype)
 
 
@@ -304,35 +326,99 @@ def _combine_bwd(res, dy):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def grouped_matmul(rows, w, sizes):
+    """rows [R, K] x w [G, K, M], the first sizes[g] rows after the
+    groups before it by w[g]: the compiler's jax.lax.ragged_dot. On the
+    chip the rows PAST the groups come back undefined, in the product
+    and in the gradient of `rows` alike (PERF.md section 6, PR 32's
+    probe: non-zero, and NaN where the memory held NaN); the gradient of
+    `w` contracts over the groups only."""
+    return jax.lax.ragged_dot(rows, w.astype(rows.dtype), sizes)
+
+
+def _experts_at(n_rows, activation, plan, x, w, w_gate, w_up, w_down):
+    """The held experts' part computed over a sorted buffer of the STATIC
+    length n_rows >= sizes.sum(): everything of the layer whose cost goes
+    with the rows. Rows at or past sizes.sum() are zero in xs and
+    undefined from the first product on; nothing reads them: `_combine`
+    and `_dispatch_bwd` gather by `pos` and select the not-held away,
+    `_combine_bwd` writes them as zeros, the experts' weight gradients
+    contract over the groups."""
+    sizes, pos, src = plan
+    src = src[:n_rows]
+    with jax.named_scope("moe.experts"):
+        xs = _dispatch(x, src, pos)
+        h = activation(grouped_matmul(xs, w_gate, sizes)) \
+            * grouped_matmul(xs, w_up, sizes)
+        ys = grouped_matmul(h, w_down, sizes)
+    with jax.named_scope("moe.combine"):
+        return _combine(ys, w, src, pos)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _on_ladder(rungs, activation, rung, plan, ops):
+    """`_experts_at` on the rung `rung` (a traced index) of the static
+    row counts `rungs`, ops = (x, w, w_gate, w_up, w_down): one
+    lax.switch. Its backward is one switch too, each branch the vjp of
+    its own rung, so that no branch makes (as zeros) the residuals of
+    the other rungs."""
+    def at(plan, ops, r):
+        return _experts_at(r, activation, plan, *ops)
+
+    return jax.lax.switch(
+        rung, [functools.partial(at, r=r) for r in rungs], plan, ops)
+
+
+def _on_ladder_fwd(rungs, activation, rung, plan, ops):
+    return _on_ladder(rungs, activation, rung, plan, ops), (rung, plan, ops)
+
+
+def _on_ladder_bwd(rungs, activation, res, dy):
+    rung, plan, ops = res
+
+    def pull(plan, ops, dy, r):
+        return jax.vjp(functools.partial(_experts_at, r, activation, plan),
+                       *ops)[1](dy)
+
+    return None, None, jax.lax.switch(
+        rung, [functools.partial(pull, r=r) for r in rungs], plan, ops, dy)
+
+
+_on_ladder.defvjp(_on_ladder_fwd, _on_ladder_bwd)
+
+
 def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, first,
-                     activation=jax.nn.silu):
+                     activation=jax.nn.silu, num_experts=None):
     """The held experts' part of the layer's result, for tokens x [N, D]
     routed as (chosen, weights) [N, k]: stable sort of the held
     assignments by expert -> gather -> grouped matmul x3 (gated by
     `activation`) -> weight -> sum per token. Every assignment to a held
-    expert (indices first .. first + E_local - 1) is computed, however
-    they fall: the buffer holds the worst case. The grouped matmul is the compiler's
-    jax.lax.ragged_dot (rows past the groups come out zero and get no
-    gradient); what a Pallas kernel gave against it on the chip is in
-    PERF.md section 6 (PR 27). Returns (y [N, D], counters [4] int32:
+    expert (indices first .. first + E_local - 1 of `num_experts`, by
+    default the held ones) is computed, however they fall: the sorted
+    buffer takes the smallest of `buffer_rungs`' static row counts that
+    holds the COUNTED assignments to held experts, picked in the graph
+    by one lax.switch, and the last rung is the worst case. Each rung
+    gives the worst case's bits (`_experts_at` says why the rows past
+    the groups, which the chip leaves undefined, reach nothing). What a
+    Pallas kernel gave against jax.lax.ragged_dot on the chip is in
+    PERF.md section 6 (PR 27). Returns (y [N, D], counters [5] int32:
     STEP_COUNTERS)."""
     N, k = chosen.shape
+    n_local = w_gate.shape[0]
+    rungs = buffer_rungs(N, k, n_local, num_experts or n_local)
     with jax.named_scope("moe.route"):
-        sizes, pos, src = dispatch_plan(chosen, first, w_gate.shape[0])
-
-    def mm(rows, w):
-        return jax.lax.ragged_dot(rows, w.astype(rows.dtype), sizes)
-
-    with jax.named_scope("moe.experts"):
-        xs = _dispatch(x, src, pos)
-        h = activation(mm(xs, w_gate)) * mm(xs, w_up)
-        ys = mm(h, w_down)
-    with jax.named_scope("moe.combine"):
-        y = _combine(ys, weights.astype(jnp.float32), src, pos)
-    local = sizes.sum()
+        plan = sizes, _, _ = dispatch_plan(chosen, first, n_local)
+        local = sizes.sum()
+        rung = (local > jnp.asarray(rungs[:-1], jnp.int32)).sum()
+    ops = (x, weights.astype(jnp.float32), w_gate, w_up, w_down)
+    if len(rungs) == 1:
+        y = _experts_at(rungs[0], activation, plan, *ops)
+    else:
+        y = _on_ladder(rungs, activation, rung, plan, ops)
+    rows = jnp.asarray(rungs, jnp.int32)[rung]
     counters = jnp.stack([jnp.int32(N * k), local, sizes.max(),
-                          local - (src < N * k).sum()]).astype(jnp.int32)
-    return y, counters
+                          jnp.maximum(local - rows, 0), rows])
+    return y, counters.astype(jnp.int32)
 
 
 class DroplessMoE(nn.Layer):
@@ -360,7 +446,11 @@ class DroplessMoE(nn.Layer):
     (`sharding_spec()`, MoELayer's convention).
 
     Each forward records STEP_COUNTERS as one int32 vector
-    (`step_counter_names`, `_step_counters`): jit.TrainStep carries it
+    (`step_counter_names`, `_step_counters`): all assignments, those to
+    held experts, the largest group, those not computed (0), and, fifth,
+    `moe.buffer_rows`: the rows of the rung of `buffer_rungs` the call
+    took, so rows over held assignments says how many empty rows the
+    layer walked. jit.TrainStep carries the vector
     out of the compiled step and folds it into profiler.monitor. A
     container that calls this layer under jax.checkpoint or lax.scan
     must hand the vector out of that inner trace itself
@@ -429,7 +519,7 @@ class DroplessMoE(nn.Layer):
                     self.scoring)
             y, counters = dropless_experts(
                 x2, chosen, weights, wg, wu, wd, self.local_experts.start,
-                GATE_ACTIVATIONS[self.activation])
+                GATE_ACTIVATIONS[self.activation], self.num_experts)
             return y.reshape(xa.shape), counters
 
         y, counters = apply_op(

@@ -281,7 +281,8 @@ def test_no_assignment_to_a_held_expert_is_lost(highest, load):
     np.testing.assert_allclose(y, want, atol=2e-5)
     n_held = int(((chosen >= first) & (chosen < first + held)).sum())
     sizes = [int((chosen == first + e).sum()) for e in range(held)]
-    assert list(map(int, counters)) == [N * k, n_held, max(sizes), 0]
+    assert list(map(int, counters)) == [N * k, n_held, max(sizes), 0,
+                                        N * min(k, held)]
     # and the gradients, through the gathers that stand for scatters
     f = lambda fn: jax.grad(lambda *a: (fn(a[0], chosen, a[1], *a[2:],
                                            first) ** 2).sum(),
