@@ -141,10 +141,15 @@ class CompileCounter:
         return max(self.program, self.backend)
 
 
+def memory_stat(devs, key):
+    """One of the allocator's memory_stats() on the fullest chip; 0 where
+    the backend keeps none (the CPU)."""
+    return max(int((d.memory_stats() or {}).get(key, 0)) for d in devs)
+
+
 def memory_peak_bytes(devs):
     """The allocator's high-water mark on the fullest chip
     (memory_stats()["peak_bytes_in_use"]) — the figure the contract asks
     for. PR 21 found it does not count a program's temporaries; the
     compiler's own figure is reported beside it by the runners."""
-    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in devs)
+    return memory_stat(devs, "peak_bytes_in_use")

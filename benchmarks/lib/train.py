@@ -9,10 +9,14 @@ import collections
 import gc
 import time
 
+import jax.numpy as jnp
 import numpy as np
 
+from ..references.common import seed_arg, weights_from_seed
 from . import correct as C
+from . import memory as M
 from . import program as P
+from .reftrain import STACKED, leafwise_norms, regenerated, unstack_norms
 
 CHECK_STEPS = 3
 
@@ -57,53 +61,108 @@ def build_step(cell, model):
     return TrainStep(model, loss_fn, o)
 
 
-def program_readings(step, w0, losses, hp, first_moment):
+def readings_call(opt_state, spec, pick, seeded=None):
+    """(held, norms): the arrays the step holds its optimizer state in,
+    and the traced function norms(held, arg) -> reftrain.leafwise_norms
+    of `pick` of each leaf's state, less the seeded weights that
+    seeded(arg, flat) gives where `seeded` is given. Each leaf is read
+    out of `held` inside the trace: on the fused path a leaf read out of
+    the flat stores before the call is a copy, and so is a leaf read
+    there in its own shape (the compiler lays the whole store out anew),
+    so it is read as its flat slice."""
+    flat = not isinstance(opt_state, dict)
+    if flat:                    # the fused path's fused_update.LeafStateView
+        held = opt_state._store
+        view = lambda h: type(opt_state)(opt_state._epilogue, h)
+    else:                                       # the tree epilogue
+        held, view = opt_state, (lambda h: h)
+
+    def norms(held, arg):
+        state = view(held)
+
+        def read(k, j):
+            x = pick(state[k if j is None
+                           else k.replace(STACKED, f".h.{j}.")])
+            return x.reshape(-1) if flat else x
+        return leafwise_norms(spec, read,
+                              None if seeded is None else seeded(arg, flat))
+    return held, norms
+
+
+def state_norms(opt_state, spec, pick, phases=None, name="readings",
+                seeded=None, arg=None):
+    """{leaf: float} by readings_call, in ONE compiled call."""
+    held, norms = readings_call(opt_state, spec, pick, seeded)
+    arg = jnp.int32(0) if arg is None else arg
+    exe = M.compiled(norms, held, arg)
+    if phases is not None:
+        phases.readings(name, exe)
+    return unstack_norms(exe(held, arg))
+
+
+def program_readings(step, w0, losses, hp, first_moment, phases=None):
     """What the program's first steps did, in the reference's terms: the
     first gradient as the optimizer got it (its first moment after one
     step is (1 - beta1) g), and how far the float32 master weights have
-    moved from the seeded ones."""
-    import jax
-    from .reftrain import leaf_norms
+    moved from the seeded ones. `w0`: the seeded weights ({name: array},
+    layers stacked), or (spec, seed, dtype), the seed that makes them
+    again inside the call, one leaf at a time."""
     grad_norms = {k: n / (1.0 - hp["beta1"])
                   for k, n in first_moment.items()}
-    masters = {k: s["master"] for k, s in step.opt_state.items()}
-    delta = jax.jit(lambda m, w: {k: m[k] - P.leaf_of(w, k) for k in m})(
-        masters, w0)
+    if isinstance(w0, dict):
+        spec = {k: (v.shape, None) for k, v in w0.items()}
+        seeded, arg = (lambda w, flat: lambda i, k, done: w[k]), w0
+    else:
+        spec, seed, dtype = w0
+        seeded = lambda s, flat: regenerated(spec, s, dtype, flat)
+        arg = jnp.int32(seed_arg(seed))
+    change = state_norms(step.opt_state, spec, lambda s: s["master"],
+                         phases, "readings.change", seeded, arg)
     return {"losses": losses, "grad_norms": grad_norms,
-            "change_norms": leaf_norms(delta)}
+            "change_norms": change}
 
 
 def run(cell, config, devs, seed, seconds, trace, t_process, tracer):
-    import jax
     from paddle_tpu.jit import warm as jwarm
-    from .reftrain import leaf_norms, reference_train
+    from .reftrain import reference_train
     hp = cell["optimizer"]
     B, S = cell["batch"], cell["seq"]
     compiles = P.CompileCounter().install()
+    phases = M.Phases(devs)
 
     # ---- set-up: one object, its state from the seed, its first steps
-    from ..references.common import weights_from_seed
+    phases.start("setup")
     spec = P.reference_of(config).param_spec(config)
     model = P.build_model(config)
     P.install_weights(model, weights_from_seed(spec, seed, config["dtype"]))
+    phases.mark("model")
     step = build_step(cell, model)
+    phases.mark("state")
     batches = make_batches(seed, config["vocab_size"], B, S,
                            cell["distinct_batches"])
     it = feed(batches)
     x, y = next(it)
-    jwarm.join([step.warm(x, y)])
+    warmed = step.warm(x, y)
+    jwarm.join([warmed])
+    step_exe = warmed.result()[0]
+    phases.executable("train.step", step_exe)
+    phases.mark("compiled")
     losses, first_moment = [], None
     for i in range(CHECK_STEPS):
         if i:
             x, y = next(it)
         losses.append(float(step(x, y).item()))
+        phases.mark(f"step{i + 1}")
         if i == 0:
-            first_moment = leaf_norms(
-                {k: s["state"][0] for k, s in step.opt_state.items()})
-    prog = program_readings(
-        step, weights_from_seed(spec, seed, config["dtype"]), losses, hp,
-        first_moment)
+            first_moment = state_norms(
+                step.opt_state, spec, lambda s: s["state"][0], phases,
+                "readings.first_moment")
+    prog = program_readings(step, (spec, seed, config["dtype"]), losses,
+                            hp, first_moment, phases)
+    phases.mark("readings")
     gc.collect()
+    phases.end()
+    phases.start("window")
 
     # ---- the window: the same object goes on from step 4
     setup_s = time.perf_counter() - t_process
@@ -131,15 +190,20 @@ def run(cell, config, devs, seed, seconds, trace, t_process, tracer):
     n_compiles = compiles.stop()
     it.close()
     peak = P.memory_peak_bytes(devs)
+    phases.executable("train.step", step_exe)
+    phases.end()
     retraces = getattr(step, "retraces", None)
 
     # ---- free the program, then the reference follows the three steps
-    del step, model, it, x, y
+    del step, model, it, x, y, warmed, step_exe
     gc.collect()
+    phases.start("reference")
     t_ref = time.perf_counter()
     ref = reference_train(P.reference_of(config), config, seed,
                           batches[:CHECK_STEPS], hp,
-                          micro=cell["reference_micro_batch"])
+                          micro=cell["reference_micro_batch"],
+                          phases=phases)
+    phases.end()
     numbers, notes = C.train_numbers(prog, ref)
     ok, rows = C.judge(numbers, cell["correct"]["limits"])
     ok = ok and bad == 0
@@ -153,6 +217,7 @@ def run(cell, config, devs, seed, seconds, trace, t_process, tracer):
                    "tokens": tokens, "host_step_s": host_s, "batch": B,
                    "seq": S, "compiles": n_compiles},
         "extra": {"reference_s": time.perf_counter() - t_ref,
+                  "memory_by_phase": phases.out,
                   "losses": losses, "reference_losses": ref["losses"],
                   "retraces": retraces, **notes,
                   "not_compared": {k: v for k, v in numbers.items()

@@ -14,6 +14,7 @@ program.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 PRECISIONS = ("f32", "bf16", "fp8")
 # the control of a configuration that states the key's precision
@@ -69,45 +70,60 @@ def mean_xent(logits, labels):
     return (lse - picked).mean()
 
 
+def seed_arg(seed):
+    """--seed, which may pass 2**31, folded on the host into the int32
+    that make_weights' key takes."""
+    return int(seed) % (2 ** 31 - 1)
+
+
 def weights_from_seed(spec, seed, dtype="float32"):
     """make_weights as ONE jitted call on the device: float32 leaves
     whose values are those of the dtype the configuration states (so the
     program, which holds them in that dtype, and the reference, which
-    computes in float32, start from the same numbers). --seed may pass
-    2**31, so it is folded on the host first."""
+    computes in float32, start from the same numbers)."""
     return jax.jit(lambda s: make_weights(spec, s, jnp.dtype(dtype)))(
-        int(seed) % (2 ** 31 - 1))
+        seed_arg(seed))
 
 
 def make_weights(spec, seed, round_to=jnp.float32):
     """Every leaf of `spec` ({name: (shape, kind)}) from the seed, in one
-    traced computation. Kinds: "normal" N(0, 0.02), "normal:<std>";
-    "ones"; "zeros"; "sign" (+1 or -1, for a norm's gain: see
-    references/mamba.py); "a_log" and "dt_bias" (the S4/Mamba inits: decay
-    rates log(1..N) per channel, steps log-uniform in [1e-3, 1e-1])."""
+    traced computation (seeded_leaf, in the order of the sorted names)."""
     key = jax.random.PRNGKey(seed)
-    out = {}
-    for i, (name, (shape, kind)) in enumerate(sorted(spec.items())):
-        if kind.startswith("normal"):       # "normal" or "normal:<std>"
-            std = float(kind.partition(":")[2] or 0.02)
-            v = std * jax.random.normal(jax.random.fold_in(key, i), shape,
-                                        jnp.float32)
-        elif kind == "dt_bias":     # Mamba's dt init: softplus^-1 of a
-            u = jax.random.uniform(  # log-uniform step in [1e-3, 1e-1]
-                jax.random.fold_in(key, i), shape, jnp.float32)
-            dt = jnp.exp(u * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
-            v = dt + jnp.log(-jnp.expm1(-dt))
-        elif kind == "ones":
-            v = jnp.ones(shape, jnp.float32)
-        elif kind == "zeros":
-            v = jnp.zeros(shape, jnp.float32)
-        elif kind == "sign":
-            v = jnp.where(jax.random.bernoulli(jax.random.fold_in(key, i),
-                                               0.5, shape), 1.0, -1.0)
-        elif kind == "a_log":
-            v = jnp.broadcast_to(jnp.log(jnp.arange(
-                1, shape[-1] + 1, dtype=jnp.float32)), shape)
-        else:
-            raise ValueError(f"{name}: unknown init kind {kind!r}")
-        out[name] = v.astype(round_to).astype(jnp.float32)
-    return out
+    return {name: seeded_leaf(key, i, shape, kind, round_to)
+            for i, (name, (shape, kind)) in enumerate(sorted(spec.items()))}
+
+
+def seeded_leaf(key, i, shape, kind, round_to=jnp.float32, flat=False):
+    """The i-th leaf of make_weights, by its kind: "normal" N(0, 0.02),
+    "normal:<std>"; "ones"; "zeros"; "sign" (+1 or -1, for a norm's gain:
+    see references/mamba.py); "a_log" and "dt_bias" (the S4/Mamba inits:
+    decay rates log(1..N) per channel, steps log-uniform in [1e-3,
+    1e-1]). Float32, holding values of `round_to`. `flat`: the same
+    values in one row, drawn so (a draw of JAX's counter-based generator
+    depends on an entry's place in the row, not on the shape; the
+    benchmark's tests hold the two equal)."""
+    if flat and kind != "a_log":
+        shape = (int(np.prod(shape)),)
+    if kind.startswith("normal"):       # "normal" or "normal:<std>"
+        std = float(kind.partition(":")[2] or 0.02)
+        v = std * jax.random.normal(jax.random.fold_in(key, i), shape,
+                                    jnp.float32)
+    elif kind == "dt_bias":     # Mamba's dt init: softplus^-1 of a
+        u = jax.random.uniform(  # log-uniform step in [1e-3, 1e-1]
+            jax.random.fold_in(key, i), shape, jnp.float32)
+        dt = jnp.exp(u * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+        v = dt + jnp.log(-jnp.expm1(-dt))
+    elif kind == "ones":
+        v = jnp.ones(shape, jnp.float32)
+    elif kind == "zeros":
+        v = jnp.zeros(shape, jnp.float32)
+    elif kind == "sign":
+        v = jnp.where(jax.random.bernoulli(jax.random.fold_in(key, i),
+                                           0.5, shape), 1.0, -1.0)
+    elif kind == "a_log":
+        v = jnp.broadcast_to(jnp.log(jnp.arange(
+            1, shape[-1] + 1, dtype=jnp.float32)), shape)
+        v = v.reshape(-1) if flat else v
+    else:
+        raise ValueError(f"unknown init kind {kind!r}")
+    return v.astype(round_to).astype(jnp.float32)
