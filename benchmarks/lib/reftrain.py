@@ -3,18 +3,25 @@ the configuration's reference forward, and AdamW with decoupled weight
 decay as Loshchilov & Hutter (2019) state it and as the cell's file
 parameterises it — all float32, nothing of the program imported.
 
+The loss is the cell's objective's (lib/train.py): next-token
+prediction, the mean cross-entropy of the reference's logits of a batch
+[B, S+1] against its next tokens; or block diffusion, weighted_xent of
+the logits the reference gives for each noised position, with
+`ref.forward(w, config, (noised, clean), prec)`, against the clean
+tokens under the batch's weights.
+
 Also the reference put in the program's place, for the control and the
 planted faults (run by benchmarks/tools/calibrate.py and the tests,
 never by a benchmark run): `prec` below float32, or `fault`:
-  "half_batch"   the second half of every batch left out, the mean
-                 taken over the rest
+  "half_batch"   the second half of every batch's rows (of each of its
+                 arrays) left out, the mean taken over the rest
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..references.common import (mean_xent, seed_arg, seeded_leaf,
-                                  weights_from_seed)
+                                  weighted_xent, weights_from_seed)
 from .memory import compiled
 
 STACKED = ".h.*."
@@ -113,39 +120,56 @@ def adamw(w, g, m, v, t, hp):
             {k: o[2] for k, o in out.items()})
 
 
+def objective_loss(ref, config, w, batch, prec):
+    """The reference's loss of one batch, a tuple of arrays: (tokens
+    [B, S+1],) for next-token prediction, (noised, clean, weights) for
+    block diffusion."""
+    if len(batch) == 1:
+        toks, = batch
+        return mean_xent(ref.forward(w, config, toks[:, :-1], prec),
+                         toks[:, 1:])
+    noised, clean, weights = batch
+    return weighted_xent(ref.forward(w, config, (noised, clean), prec),
+                         clean, weights)
+
+
 def reference_train(ref, config, seed, batches, hp, prec="f32", micro=4,
                     fault=None, phases=None):
-    """batches: the first steps' token arrays [B, S+1] (host). Returns
-    {"losses", "grad_norms" (first step), "change_norms" (after all)}.
-    Holds w, m, v and one gradient: the seeded weights are made again
-    for the change, and a batch split into micro-batches adds each part's
-    gradient into the one sum in place. `phases` (lib/memory.py), where
-    given, is told each executable and marked at each step's fullest."""
+    """batches: the first steps' host batches, as lib/train.py draws
+    them (token arrays [B, S+1], or the objective's tuples of arrays).
+    Returns {"losses", "grad_norms" (first step), "change_norms" (after
+    all)}. Holds w, m, v and one gradient: the seeded weights are made
+    again for the change, and a batch split into micro-batches (the rows
+    of each of its arrays) adds each part's gradient into the one sum in
+    place. `phases` (lib/memory.py), where given, is told each executable
+    and marked at each step's fullest."""
     spec = ref.param_spec(config)
 
-    def loss_and_grad(w, toks):
-        def loss(w):
-            return mean_xent(ref.forward(w, config, toks[:, :-1], prec),
-                             toks[:, 1:])
-        return jax.value_and_grad(loss)(w)
+    def loss_and_grad(w, batch):
+        return jax.value_and_grad(
+            lambda w: objective_loss(ref, config, w, batch, prec))(w)
 
-    def add_grad(w, toks, acc, share):
-        l, g = loss_and_grad(w, toks)
+    def add_grad(w, batch, acc, share):
+        l, g = loss_and_grad(w, batch)
         return l, jax.tree.map(lambda a, b: a + b * share, acc, g)
 
     w = weights_from_seed(spec, seed, config["dtype"])
     m = jax.tree.map(jnp.zeros_like, w)
     v = jax.tree.map(jnp.zeros_like, w)
     exe = {}
+    shapes = lambda part: tuple(a.shape for a in part)
     out = {"losses": []}
-    for t, toks in enumerate(batches, 1):
-        toks = np.asarray(toks)
+    for t, batch in enumerate(batches, 1):
+        arrays = tuple(np.asarray(a) for a in (
+            batch if isinstance(batch, tuple) else (batch,)))
+        rows = arrays[0].shape[0]
         if fault == "half_batch":
-            toks = toks[:toks.shape[0] // 2]
-        parts = [jnp.asarray(toks[i:i + micro], jnp.int32)
-                 for i in range(0, toks.shape[0], micro)]
+            rows //= 2
+            arrays = tuple(a[:rows] for a in arrays)
+        parts = [tuple(jnp.asarray(a[i:i + micro]) for a in arrays)
+                 for i in range(0, rows, micro)]
         if len(parts) == 1:
-            key = ("grad", parts[0].shape)
+            key = ("grad", shapes(parts[0]))
             if key not in exe:
                 exe[key] = compiled(loss_and_grad, w, parts[0],
                                     phases=phases, name="reference.grad")
@@ -154,8 +178,8 @@ def reference_train(ref, config, seed, batches, hp, prec="f32", micro=4,
         else:
             loss, grads = 0.0, jax.tree.map(jnp.zeros_like, w)
             for p in parts:
-                share = p.shape[0] / toks.shape[0]
-                key = ("add_grad", p.shape)
+                share = p[0].shape[0] / rows
+                key = ("add_grad", shapes(p))
                 if key not in exe:
                     exe[key] = compiled(add_grad, w, p, grads,
                                         jnp.float32(share), phases=phases,

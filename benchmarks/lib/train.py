@@ -4,7 +4,26 @@ same object to the measured window, then hold what the three steps did
 to the plain reference.
 
 From the program: jit.TrainStep, optimizer.AdamW, io.device_prefetch —
-called as a user calls them. Everything that measures or judges lives here."""
+called as a user calls them. Everything that measures or judges lives here.
+
+The objective is the cell file's. With no "objective" key it is
+next-token prediction: host batches [B, S+1] of ids, the step called as
+step(inputs, next tokens) with the mean cross-entropy over every position
+as its loss. With {"kind": "block_diffusion", "block_length": L,
+"t_min": t0} (MDLM, arXiv:2406.07524; BD3-LM, arXiv:2503.09573) the host
+batches are triples drawn from the seed (make_diffusion_batches) and the
+contract with the program is:
+  - the step is TrainStep(model, None, optimizer, model_returns_loss=True)
+    called as step(noised, clean, weights): int32 [B, S], int32 [B, S],
+    float32 [B, S];
+  - the program's model returns the float32 scalar
+    sum_i weights[i] * CE(its logits at noised position i, clean[i]) / (B S)
+    — how it runs the 2S positions of [noised ; clean] under the
+    BD3-LM mask is the program's business;
+  - `tokens` counts the clean tokens consumed, steps x B x S: the doubled
+    positions are the objective's own cost, for a configuration's MFU
+    reader to count.
+The reference scores the same arrays (reftrain.objective_loss)."""
 import collections
 import gc
 import time
@@ -29,17 +48,84 @@ def make_batches(seed, vocab, batch, seq, n):
             for _ in range(n)]
 
 
+OBJECTIVES = ("block_diffusion",)
+
+
+def objective(cell, config):
+    """The cell's training objective: None for next-token prediction (no
+    "objective" key), else its dict, held to what it needs: a known kind,
+    blocks that tile the sequence (and are the configuration's own, where
+    it states a block_length), t_min in (0, 1], and the configuration's
+    mask_token_id inside the vocabulary the ids are drawn over."""
+    obj = cell.get("objective")
+    if obj is None:
+        return None
+    if obj["kind"] not in OBJECTIVES:
+        raise ValueError(f"unknown objective {obj['kind']!r}")
+    L = obj["block_length"]
+    if L < 1 or cell["seq"] % L:
+        raise ValueError(f"block_length {L} does not divide seq "
+                         f"{cell['seq']}")
+    if config.get("block_length", L) != L:
+        raise ValueError(f"block_length {L}: the configuration states "
+                         f"{config['block_length']}")
+    if not 0 < obj["t_min"] <= 1:
+        raise ValueError(f"t_min {obj['t_min']} is not in (0, 1]")
+    if not 0 <= config["mask_token_id"] < config["vocab_size"]:
+        raise ValueError(f"mask_token_id {config['mask_token_id']} is "
+                         f"outside the vocabulary of {config['vocab_size']}")
+    return obj
+
+
+def make_diffusion_batches(seed, vocab, batch, seq, n, block_length, t_min,
+                           mask_id):
+    """n host triples (noised, clean, weights) from the seed, in
+    make_batches' own stream: clean int32 [batch, seq] ids over the
+    vocabulary less the mask id; for each row's block of block_length
+    tokens a t ~ U[t_min, 1); each token masked with probability its
+    block's t; noised the mask id where masked and clean elsewhere;
+    weights float32, 1/t where masked and 0 elsewhere."""
+    rng = np.random.RandomState((seed + 1) % (2 ** 32))
+    out = []
+    for _ in range(n):
+        clean = rng.randint(0, vocab - 1, size=(batch, seq))
+        clean = (clean + (clean >= mask_id)).astype(np.int32)
+        t = np.repeat(rng.uniform(t_min, 1.0, (batch, seq // block_length)),
+                      block_length, axis=1)
+        masked = rng.random_sample((batch, seq)) < t
+        out.append((np.where(masked, mask_id, clean).astype(np.int32), clean,
+                    np.where(masked, 1.0 / t, 0.0).astype(np.float32)))
+    return out
+
+
+def host_batches(cell, config, seed, n):
+    """The cell's n host batches from the seed, by its objective."""
+    obj = objective(cell, config)
+    if obj is None:
+        return make_batches(seed, config["vocab_size"], cell["batch"],
+                            cell["seq"], n)
+    return make_diffusion_batches(
+        seed, config["vocab_size"], cell["batch"], cell["seq"], n,
+        obj["block_length"], obj["t_min"], config["mask_token_id"])
+
+
+def step_args(batch):
+    """A host batch as the step's arguments: a token array [B, S+1] as
+    (inputs, next tokens), an objective's tuple of arrays as it is."""
+    return batch if isinstance(batch, tuple) else (batch[:, :-1],
+                                                   batch[:, 1:])
+
+
 def feed(batches):
     """The window's feed: the host batches, cycled, staged onto the
-    device by the program's prefetch ring as (inputs, next tokens)."""
+    device by the program's prefetch ring as the step's arguments."""
     import itertools
     import paddle_tpu as paddle
     from paddle_tpu.io.device_prefetch import device_prefetch_iterator
 
     def source():
-        for toks in itertools.cycle(batches):
-            yield (paddle.to_tensor(toks[:, :-1]),
-                   paddle.to_tensor(toks[:, 1:]))
+        for batch in itertools.cycle(batches):
+            yield tuple(paddle.to_tensor(a) for a in step_args(batch))
     return device_prefetch_iterator(source(), depth=2)
 
 
@@ -50,7 +136,9 @@ def loss_fn(logits, labels):
         logits.reshape([-1, V]), labels.reshape([-1]))
 
 
-def build_step(cell, model):
+def build_step(cell, model, **step_kw):
+    """The program's compiled step for the cell's objective (see the
+    module's docstring); `step_kw` goes to TrainStep."""
     from paddle_tpu import optimizer as opt
     hp = cell["optimizer"]
     o = opt.AdamW(learning_rate=hp["lr"], beta1=hp["beta1"],
@@ -58,7 +146,9 @@ def build_step(cell, model):
                   weight_decay=hp["weight_decay"],
                   parameters=model.parameters(), multi_precision=True)
     from paddle_tpu.jit import TrainStep
-    return TrainStep(model, loss_fn, o)
+    if cell.get("objective") is not None:
+        return TrainStep(model, None, o, model_returns_loss=True, **step_kw)
+    return TrainStep(model, loss_fn, o, **step_kw)
 
 
 def readings_call(opt_state, spec, pick, seeded=None):
@@ -138,11 +228,10 @@ def run(cell, config, devs, seed, seconds, trace, t_process, tracer):
     phases.mark("model")
     step = build_step(cell, model)
     phases.mark("state")
-    batches = make_batches(seed, config["vocab_size"], B, S,
-                           cell["distinct_batches"])
+    batches = host_batches(cell, config, seed, cell["distinct_batches"])
     it = feed(batches)
-    x, y = next(it)
-    warmed = step.warm(x, y)
+    args = next(it)
+    warmed = step.warm(*args)
     jwarm.join([warmed])
     step_exe = warmed.result()[0]
     phases.executable("train.step", step_exe)
@@ -150,8 +239,8 @@ def run(cell, config, devs, seed, seconds, trace, t_process, tracer):
     losses, first_moment = [], None
     for i in range(CHECK_STEPS):
         if i:
-            x, y = next(it)
-        losses.append(float(step(x, y).item()))
+            args = next(it)
+        losses.append(float(step(*args).item()))
         phases.mark(f"step{i + 1}")
         if i == 0:
             first_moment = state_norms(
@@ -174,10 +263,10 @@ def run(cell, config, devs, seed, seconds, trace, t_process, tracer):
     while time.perf_counter() - t0 < seconds:
         tracer.tick(time.perf_counter() - t0)
         with tracer.span("bench.feed"):
-            x, y = next(it)
+            args = next(it)
         h0 = time.perf_counter()
         with tracer.span("bench.step"):
-            pending.append(step(x, y))
+            pending.append(step(*args))
         host_s.append(time.perf_counter() - h0)
         n_steps += 1
         if len(pending) > 2:       # at most two steps run ahead
@@ -195,7 +284,7 @@ def run(cell, config, devs, seed, seconds, trace, t_process, tracer):
     retraces = getattr(step, "retraces", None)
 
     # ---- free the program, then the reference follows the three steps
-    del step, model, it, x, y, warmed, step_exe
+    del step, model, it, args, warmed, step_exe
     gc.collect()
     phases.start("reference")
     t_ref = time.perf_counter()

@@ -62,12 +62,24 @@ def layer_norm(x, g, b, eps, prec):
     return y.astype(act_dtype(prec))
 
 
-def mean_xent(logits, labels):
-    """Mean over all positions of -log softmax(logits)[label], float32."""
+def token_xent(logits, labels):
+    """-log softmax(logits)[label] at every position, float32."""
     lg = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(lg, axis=-1)
     picked = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]
-    return (lse - picked).mean()
+    return lse - picked
+
+
+def mean_xent(logits, labels):
+    """Mean over all positions of -log softmax(logits)[label], float32."""
+    return token_xent(logits, labels).mean()
+
+
+def weighted_xent(logits, labels, weights):
+    """Sum over all positions of weights x -log softmax(logits)[label],
+    over the number of positions, float32: the block-diffusion loss,
+    whose weights are 1/t at the masked positions and 0 elsewhere."""
+    return (weights.astype(jnp.float32) * token_xent(logits, labels)).mean()
 
 
 def seed_arg(seed):

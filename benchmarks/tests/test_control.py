@@ -16,12 +16,11 @@ from benchmarks.tests.test_rehearsal import SERVE, TRAIN
 @pytest.mark.parametrize("cell_name", TRAIN)
 def test_train_control_is_not_correct(monkeypatch, cell_name):
     from benchmarks.lib.reftrain import reference_train
-    from benchmarks.lib.train import CHECK_STEPS, make_batches
+    from benchmarks.lib.train import CHECK_STEPS, host_batches
     tiny.patch(monkeypatch)
     cell, config, _, _ = P.load_cell(cell_name)
     ref_mod = P.reference_of(config)
-    batches = make_batches(5, config["vocab_size"], cell["batch"],
-                           cell["seq"], CHECK_STEPS)
+    batches = host_batches(cell, config, 5, CHECK_STEPS)
     run = lambda **kw: reference_train(ref_mod, config, 5, batches,
                                        cell["optimizer"], micro=1, **kw)
     ref = run()

@@ -1,9 +1,16 @@
 """BENCHMARK.json against the files it names: every configuration, cell
-and per-layer metric is data under benchmarks/, found by its name."""
+and per-layer metric is data under benchmarks/, found by its name, and
+every configuration has its tiny copy for the CPU rehearsal."""
+import copy
 import importlib
 import json
 import os
 import re
+
+import pytest
+
+from benchmarks.lib import train
+from benchmarks.tests import tiny
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
@@ -55,6 +62,53 @@ def test_cells_report_what_the_manifest_says():
         assert any(w["name"] in m.get("workloads", [w["name"]])
                    for m in M["per_layer"])
         assert set(cell["correct"]["limits"])
+        if cell["kind"] == "train":
+            train.objective(cell, load(*config_file(w["config"])))
+
+
+def config_file(name):
+    entry = next(c for c in M["configs"] if c["name"] == name)
+    return entry["file"].split("/")[1:]
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in M["configs"]])
+def test_every_configuration_has_a_tiny_copy(config):
+    """tests/tiny/<config>.json, named where it is missing."""
+    used = [w for w in M["workloads"] if w["config"] == config]
+    got = tiny.tiny_file(config)
+    assert {"sizes", "published_names"} <= set(got)
+    if any(".train." in w["name"] for w in used):
+        assert got["train_limits"]
+
+
+def test_a_missing_tiny_copy_is_named():
+    with pytest.raises(FileNotFoundError,
+                       match="benchmarks/tests/tiny/no-such-model.json"):
+        tiny.tiny_file("no-such-model")
+
+
+def test_rehearsal_diffusion_cell_states_its_objective():
+    extra = tiny.rehearsal_cell("diffusion_cell")
+    cell, config = extra["cell"], extra["config"]
+    obj = train.objective(cell, config)
+    assert obj["kind"] == "block_diffusion" and cell["kind"] == "train"
+    assert cell["seq"] % obj["block_length"] == 0
+    assert 0 <= config["mask_token_id"] < config["vocab_size"]
+
+
+@pytest.mark.parametrize("broken", [
+    ("objective", "kind", "next_sentence"), ("objective", "block_length", 5),
+    ("config", "block_length", 8), ("objective", "t_min", 0.0),
+    ("config", "mask_token_id", 256)])
+def test_an_objective_that_cannot_run_is_refused(broken):
+    extra = tiny.rehearsal_cell("diffusion_cell")
+    files = {"objective": copy.deepcopy(extra["cell"]["objective"]),
+             "config": copy.deepcopy(extra["config"])}
+    where, key, value = broken
+    files[where][key] = value
+    with pytest.raises(ValueError):
+        train.objective(dict(extra["cell"], objective=files["objective"]),
+                        files["config"])
 
 
 def test_per_layer_metrics_have_readers_and_move_what_is_reported():
@@ -64,7 +118,9 @@ def test_per_layer_metrics_have_readers_and_move_what_is_reported():
         spec = load("metrics", f"{m['name']}.json")
         for k in ("name", "unit", "layer", "moves"):
             assert spec[k] == m[k], (m["name"], k)
-        assert spec.get("workloads") == m.get("workloads")
+        # the cells a metric is read in are listed in BENCHMARK.json
+        # alone, so that a cell is added without editing a metric's file
+        assert "workloads" not in spec, m["name"]
         reader = importlib.import_module(
             f"benchmarks.readers.{spec['reader']}")
         assert callable(reader.read)

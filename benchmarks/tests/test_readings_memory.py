@@ -24,7 +24,7 @@ from benchmarks.lib import reftrain, train
 from benchmarks.references.common import (make_weights, mean_xent,
                                           seeded_leaf, weights_from_seed)
 from benchmarks.tests import tiny
-from benchmarks.tests.test_rehearsal import TRAIN
+from benchmarks.tests.test_rehearsal import NEXT_TOKEN, TRAIN
 
 SEED = 3000000029
 REL = 1e-6
@@ -95,22 +95,15 @@ def assert_close(new, old):
 def driven(monkeypatch, cell_name, fused):
     """A tiny cell's step through the runner's three checked steps, with
     the new and the old readings of each."""
-    from paddle_tpu import optimizer as opt
-    from paddle_tpu.jit import TrainStep
     tiny.patch(monkeypatch)
     cell, config, _, _ = P.load_cell(cell_name)
     spec = P.reference_of(config).param_spec(config)
     model = P.build_model(config)
     P.install_weights(model, weights_from_seed(spec, SEED, config["dtype"]))
-    hp = cell["optimizer"]
-    step = TrainStep(model, train.loss_fn, opt.AdamW(
-        learning_rate=hp["lr"], beta1=hp["beta1"], beta2=hp["beta2"],
-        epsilon=hp["epsilon"], weight_decay=hp["weight_decay"],
-        parameters=model.parameters(), multi_precision=True),
-        fused_update=None if fused else False)
+    step = train.build_step(cell, model,
+                            fused_update=None if fused else False)
     assert isinstance(step.opt_state, dict) is not fused
-    it = train.feed(train.make_batches(SEED, config["vocab_size"],
-                                       cell["batch"], cell["seq"],
+    it = train.feed(train.host_batches(cell, config, SEED,
                                        train.CHECK_STEPS))
     phases = M.Phases(jax.devices()[:1])
     phases.start("setup")
@@ -245,13 +238,12 @@ def test_readings_call_memory(v5e, config, fused):
 def tiny_cell(monkeypatch, cell_name):
     tiny.patch(monkeypatch)
     cell, config, _, _ = P.load_cell(cell_name)
-    batches = train.make_batches(SEED, config["vocab_size"], cell["batch"],
-                                 cell["seq"], train.CHECK_STEPS)
+    batches = train.host_batches(cell, config, SEED, train.CHECK_STEPS)
     return cell, config, P.reference_of(config), batches
 
 
 @pytest.mark.parametrize("split", [True, False], ids=["split", "whole"])
-@pytest.mark.parametrize("cell", TRAIN)
+@pytest.mark.parametrize("cell", NEXT_TOKEN)
 def test_reference_readings_equal_old(monkeypatch, cell, split):
     cell, config, ref, batches = tiny_cell(monkeypatch, cell)
     micro = 1 if split else cell["batch"]

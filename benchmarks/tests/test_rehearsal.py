@@ -18,11 +18,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     MANIFEST = json.load(f)
-# BENCHMARK.json's cells, and the rehearsal's own serving cell
-MANIFEST = tiny.manifest_with_serve_cell(MANIFEST, tiny.serve_cell())
+# BENCHMARK.json's cells, and the rehearsal's own (tests/data/)
+MANIFEST = tiny.manifest_with_rehearsal_cells(MANIFEST)
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 TRAIN = [c for c in CELLS if ".train." in c]
 SERVE = [c for c in CELLS if ".serve." in c]
+NEXT_TOKEN = [c for c in TRAIN if "objective" not in tiny.cell_file(c)]
 SEED = 3000000019            # the driver's seeds pass 2**31
 RECORDED = os.path.join(os.path.dirname(os.path.dirname(__file__)),
                         "testdata", "small.xplane.pb")
@@ -120,6 +121,19 @@ def half_batch_loss(logits, labels):
     return train._whole_batch_loss(logits[:half], labels[:half])
 
 
+def half_batch_weights(batches):
+    """The half-batch fault where the objective's arrays carry the
+    loss's weights: the second half of the rows weighed 0, the first
+    half's weights doubled — the mean over the first half."""
+    out = []
+    for noised, clean, w in batches:
+        w, half = w.copy(), w.shape[0] // 2
+        w[:half] *= 2
+        w[half:] = 0
+        out.append((noised, clean, w))
+    return out
+
+
 @pytest.mark.parametrize("cell", TRAIN)
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch"])
 def test_train_fault_is_not_correct(cpu_runner, monkeypatch, cell, fault):
@@ -128,10 +142,14 @@ def test_train_fault_is_not_correct(cpu_runner, monkeypatch, cell, fault):
         build = train.build_step
         monkeypatch.setattr(train, "build_step",
                             lambda *a: FrozenStep(build(*a)))
-    else:
+    elif cell in NEXT_TOKEN:
         monkeypatch.setattr(train, "_whole_batch_loss", train.loss_fn,
                             raising=False)
         monkeypatch.setattr(train, "loss_fn", half_batch_loss)
+    else:                       # planted in the program's feed
+        real = train.feed
+        monkeypatch.setattr(train, "feed",
+                            lambda batches: real(half_batch_weights(batches)))
     line = run.run_cell(cell, SEED + 2, 1.0, 0)
     assert line["correct"] is False, line["compared"]
 

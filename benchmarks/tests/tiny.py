@@ -1,72 +1,59 @@
 """Tiny copies of the configurations and cells for the CPU rehearsal,
 and the patches that steer the runner from a test: the cell's files, the
 look for a chip, and the peaks table are replaced here — the runner
-itself has no option for any of it."""
+itself has no option for any of it.
+
+Each configuration's tiny copy is a file of its own,
+tests/tiny/<config>.json: `sizes` (the keys changed for the CPU),
+`published_names` (keys the configuration file also carries under its
+source's own names, which the program's config object does not take) and
+`train_limits` (what a tiny copy's training is held to: bf16 or
+default-precision float32 against the float32 reference at hidden 64, in
+place of the cells' own limits; sound runs read under them, each fault
+and each control over one of them). Adding a configuration is adding
+that file.
+
+The rehearsal cells (tests/data/<name>.json) are cells BENCHMARK.json
+does not hold: each file carries its workload and configuration entries,
+the cell, and the metrics it reports, new ones with their specs; where it
+carries its configuration and a plain reference of its own, those come
+from the file and from the module it names."""
 import copy
+import importlib
 import json
 import os
 
 from benchmarks.lib import peaks
 from benchmarks.lib import program as P
 
-TINY = {
-    "gpt2-medium": {"vocab_size": 512, "hidden_size": 64, "num_layers": 2,
-                    "num_heads": 4, "intermediate_size": 256,
-                    "max_position_embeddings": 128},
-    "mamba-1.4b": {"vocab_size": 512, "hidden_size": 64, "num_layers": 2,
-                   "dt_rank": 4, "max_position_embeddings": 128},
-    # the dense layer and two expert layers; 2 of 8 experts held
-    "glm-4.7-flash-ep8": dict(
-        vocab_size=256, hidden_size=64, intermediate_size=128,
-        num_hidden_layers=3, num_attention_heads=4, q_lora_rank=32,
-        kv_lora_rank=16, qk_nope_head_dim=24, qk_rope_head_dim=8,
-        v_head_dim=32, moe_intermediate_size=48, n_routed_experts=2,
-        router_experts=8, local_expert_start=2, num_experts_per_tok=2),
-    # one period: a full layer without positions, three window layers
-    # with rotary; 2 of 8 experts held; a window the tokens overflow
-    "smallthinker-21b-ep8": dict(
-        vocab_size=256, hidden_size=64, num_attention_heads=4,
-        num_key_value_heads=2, head_dim=16, sliding_window_size=8,
-        n_routed_experts=2, router_experts=8, local_expert_start=2,
-        num_experts_per_tok=2, moe_intermediate_size=48),
-}
-# keys a configuration file also carries under its source's own names,
-# which the program's config object does not take
-PUBLISHED_NAMES = {
-    "smallthinker-21b-ep8": dict(moe_num_primary_experts=2,
-                                 moe_num_active_primary_experts=2,
-                                 moe_ffn_hidden_size=48),
-}
+HERE = os.path.dirname(os.path.abspath(__file__))
+REHEARSAL = ("serve_cell", "diffusion_cell")
 TINY_CELL = {
     "train": {"batch": 2, "seq": 64, "distinct_batches": 4,
               "reference_micro_batch": 1},
     "serve": {"warmup_seconds": 0.5},
 }
-# what the TINY copies are held to (bf16 or default-precision float32
-# against the float32 reference at hidden 64), in place of the cells' own
-# limits: sound runs read under them, each fault and each control over
-# one of them. The expert-layer decoders' from six seeds each of the
-# program, the fp8 control and the half-batch fault at these sizes (PR
-# 33): glm's first loss reads up to 2.3e-5 against the control's 9.6e-5
-# and more; smallthinker's second up to 2.1e-5 against 5.3e-5 and more;
-# their grad_norm_gap (up to 0.022 against 0.0095 to 0.036) separates
-# only the half batch's 0.48 and more.
-TRAIN_LIMITS = {
-    "gpt2-medium": {"loss1_gap": 1e-4, "loss2_gap": 1e-4,
-                    "grad_norm_gap": 0.01, "change_norm_gap": 0.2},
-    "glm-4.7-flash-ep8": {"loss1_gap": 6e-5, "loss2_gap": 1e-4,
-                          "grad_norm_gap": 0.08, "change_norm_gap": 0.05},
-    "smallthinker-21b-ep8": {"loss1_gap": 1e-4, "loss2_gap": 4e-5,
-                             "grad_norm_gap": 0.08,
-                             "change_norm_gap": 0.05},
-}
 SERVE_LIMITS = {"max_logit_gap": 1e-4}
+
+
+def tiny_file(config_name):
+    """tests/tiny/<config>.json; a configuration without one is named in
+    the error."""
+    path = os.path.join(HERE, "tiny", f"{config_name}.json")
+    if not os.path.exists(path):
+        raise FileNotFoundError(
+            f"configuration {config_name!r} has no tiny copy: add "
+            f"{os.path.relpath(path, P.ROOT)} (sizes, published_names, "
+            "train_limits)")
+    with open(path) as f:
+        return json.load(f)
 
 
 def tiny_config(config):
     cfg = copy.deepcopy(config)
-    small = TINY[cfg["name"]]
-    cfg.update(small, **PUBLISHED_NAMES.get(cfg["name"], {}))
+    tiny = tiny_file(cfg["name"])
+    small = tiny["sizes"]
+    cfg.update(small, **tiny["published_names"])
     prog = cfg["program"]
     if prog["config"].endswith(":gpt_medium"):
         prog["config"] = "paddle_tpu.models.gpt:GPTConfig"
@@ -99,46 +86,85 @@ def tiny_cell(cell, cfg):
     return c
 
 
-def serve_cell():
-    """The serving cell the rehearsal drives (tests/data/serve_cell.json;
-    BENCHMARK.json holds no serving cell yet)."""
-    with open(os.path.join(os.path.dirname(__file__), "data",
-                           "serve_cell.json")) as f:
+def rehearsal_cell(name):
+    with open(os.path.join(HERE, "data", f"{name}.json")) as f:
         return json.load(f)
 
 
-def manifest_with_serve_cell(manifest, extra):
+def serve_cell():
+    """The serving cell the rehearsal drives (tests/data/serve_cell.json;
+    BENCHMARK.json holds no serving cell yet)."""
+    return rehearsal_cell("serve_cell")
+
+
+def real_manifest():
+    with open(os.path.join(P.ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def rehearsal_only():
+    """{workload: rehearsal file} of the rehearsal cells BENCHMARK.json
+    does not hold (one registered there is run as a real cell)."""
+    real = {w["name"] for w in real_manifest()["workloads"]}
+    out = {}
+    for extra in map(rehearsal_cell, REHEARSAL):
+        if extra["workload"]["name"] not in real:
+            out[extra["workload"]["name"]] = extra
+    return out
+
+
+def cell_file(name):
+    """A workload's cell file: BENCHMARK.json's, or a rehearsal cell's."""
+    extras = rehearsal_only()
+    if name in extras:
+        return extras[name]["cell"]
+    return P.load_json("workloads", f"{name}.json")
+
+
+def manifest_with_rehearsal_cells(manifest):
+    """BENCHMARK.json with the rehearsal-only cells added: their workload
+    and configuration entries, their own metrics, and their names in the
+    lists of the manifest's metrics that they report."""
     m = copy.deepcopy(manifest)
-    m["workloads"].append(extra["workload"])
-    m["configs"].append(extra["config_entry"])
-    m["end_to_end"][:0] = extra["end_to_end"]
-    m["per_layer"] += [p["manifest"] for p in extra["per_layer"]]
+    for name, extra in rehearsal_only().items():
+        m["workloads"].append(extra["workload"])
+        m["configs"].append(extra["config_entry"])
+        reports = set(extra.get("reports", ()))
+        for metric in m["end_to_end"] + m["per_layer"]:
+            if metric["name"] in reports and "workloads" in metric:
+                metric["workloads"].append(name)
+        m["end_to_end"][:0] = extra.get("end_to_end", [])
+        m["per_layer"] += [p["manifest"] for p in extra.get("per_layer", [])]
     return m
 
 
 def patch(monkeypatch):
     """Route P.load_cell to tiny copies (of BENCHMARK.json's cells, and
-    of the rehearsal's serving cell) and P.require_tpu to the CPU's
-    devices. A tiny copy is held to its own limits, not the cell's."""
+    of the rehearsal cells), P.reference_of to a rehearsal cell's own
+    reference, and P.require_tpu to the CPU's devices. A tiny copy is
+    held to its own limits, not the cell's."""
     import jax
-    real, real_json = P.load_cell, P.load_json
-    extra = serve_cell()
+    real, real_json, real_ref = P.load_cell, P.load_json, P.reference_of
+    extras = rehearsal_only()
     specs = {f"{p['spec']['name']}.json": p["spec"]
-             for p in extra["per_layer"]}
+             for e in extras.values() for p in e.get("per_layer", [])}
+    refs = {e["config"]["name"]: e["reference_module"]
+            for e in extras.values() if "reference_module" in e}
 
     def load_cell(name):
-        if name == extra["workload"]["name"]:
-            with open(os.path.join(P.ROOT, "BENCHMARK.json")) as f:
-                manifest = manifest_with_serve_cell(json.load(f), extra)
+        if name in extras:
+            extra = extras[name]
+            manifest = manifest_with_rehearsal_cells(real_manifest())
             cell, entry = copy.deepcopy(extra["cell"]), extra["workload"]
-            config = real_json("configs", f"{entry['config']}.json")
+            config = copy.deepcopy(extra["config"]) if "config" in extra \
+                else real_json("configs", f"{entry['config']}.json")
         else:
             cell, config, entry, manifest = real(name)
         cfg = tiny_config(config)
         cell = tiny_cell(cell, cfg)
         cell["correct"]["limits"] = dict(
-            TRAIN_LIMITS[cfg["name"]] if cell["kind"] == "train"
-            else SERVE_LIMITS)
+            tiny_file(cfg["name"])["train_limits"]
+            if cell["kind"] == "train" else SERVE_LIMITS)
         return cell, cfg, entry, manifest
 
     def load_json(*parts):
@@ -146,8 +172,14 @@ def patch(monkeypatch):
             return specs[parts[1]]
         return real_json(*parts)
 
+    def reference_of(config):
+        if config["name"] in refs:
+            return importlib.import_module(refs[config["name"]])
+        return real_ref(config)
+
     monkeypatch.setattr(P, "load_cell", load_cell)
     monkeypatch.setattr(P, "load_json", load_json)
+    monkeypatch.setattr(P, "reference_of", reference_of)
     monkeypatch.setattr(P, "require_tpu",
                         lambda chips: jax.devices()[:chips])
     kind = jax.devices()[0].device_kind

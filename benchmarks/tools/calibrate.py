@@ -28,18 +28,20 @@ from benchmarks.references.common import CONTROL_OF  # noqa: E402
 
 def train_control(cell, config, seed):
     """The reference in the control's precision, and with each planted
-    fault, against the reference itself."""
+    fault, against the reference itself, on the cell's objective's
+    batches. A batch of one row has no half: that fault is skipped."""
     from benchmarks.lib.reftrain import reference_train
-    from benchmarks.lib.train import CHECK_STEPS, make_batches
+    from benchmarks.lib.train import CHECK_STEPS, host_batches
     ref_mod = P.reference_of(config)
-    batches = make_batches(seed, config["vocab_size"], cell["batch"],
-                           cell["seq"], CHECK_STEPS)
+    batches = host_batches(cell, config, seed, CHECK_STEPS)
     kw = dict(micro=cell["reference_micro_batch"])
     hp = cell["optimizer"]
     ref = reference_train(ref_mod, config, seed, batches, hp, **kw)
     out = {}
-    for what, args in (("control", {"prec": CONTROL_OF[config["dtype"]]}),
-                       ("half_batch", {"fault": "half_batch"})):
+    planted = [("control", {"prec": CONTROL_OF[config["dtype"]]})]
+    if cell["batch"] > 1:
+        planted.append(("half_batch", {"fault": "half_batch"}))
+    for what, args in planted:
         got = reference_train(ref_mod, config, seed, batches, hp, **kw,
                               **args)
         out[what], _ = C.train_numbers(got, ref)

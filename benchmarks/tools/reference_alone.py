@@ -30,9 +30,8 @@ def main(argv=None):
     from paddle_tpu.framework import compile_cache
     compile_cache.enable_compile_cache()
     from benchmarks.lib.reftrain import reference_train
-    from benchmarks.lib.train import CHECK_STEPS, make_batches
-    batches = make_batches(args.seed, config["vocab_size"], cell["batch"],
-                           cell["seq"], CHECK_STEPS)
+    from benchmarks.lib.train import CHECK_STEPS, host_batches
+    batches = host_batches(cell, config, args.seed, CHECK_STEPS)
     t0 = time.perf_counter()
     out = reference_train(P.reference_of(config), config, args.seed, batches,
                           cell["optimizer"], prec=args.prec,
