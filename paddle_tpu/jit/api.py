@@ -52,6 +52,11 @@ __all__ = ["functional_call", "to_static", "TrainStep", "not_to_static",
 _trace_lock = threading.RLock()
 
 
+# what a traced model keeps for its backward pass, by save point name
+# (models/decoder.py counts it as it traces)
+_SAVED_BYTES = "remat.saved_bytes."
+
+
 def aot_compile(jitted, args, tag=None, static=None, arg_names=None):
     """Explicitly lower + compile a jax.jit function for `args` — the
     AOT dispatch path TrainStep/HybridTrainStep use instead of jax.jit's
@@ -67,8 +72,10 @@ def aot_compile(jitted, args, tag=None, static=None, arg_names=None):
     HLO text + cost analysis. It is also the compilation observatory's
     key (profiler/compile_observatory.py): every call lands one
     `kind:"compile"` ledger record (lower/compile split, cache hit, HLO
-    instruction/fusion counts, bytes/flops, peak-memory estimate), and
-    a tag recompiling under a NEW abstract signature emits a structured
+    instruction/fusion counts, bytes/flops, peak-memory estimate, and
+    the bytes the traced program keeps for its backward pass by save
+    point, which profiler.monitor's `remat.saved_bytes.<name>` gained
+    while it traced), and a tag recompiling under a NEW abstract signature emits a structured
     retrace event naming the argument that changed — BEFORE the
     recompile runs, so even a hung compile leaves the diagnosis.
 
@@ -96,7 +103,11 @@ def aot_compile(jitted, args, tag=None, static=None, arg_names=None):
         # phase across the warm executor's workers; the XLA compile
         # below runs unlocked (GIL-released C++) and overlaps freely
         with _trace_lock:
+            kept0 = _monitor.counters_under(_SAVED_BYTES)
             lowered = jitted.lower(*args)
+            kept = {k: v - kept0.get(k, 0) for k, v in
+                    _monitor.counters_under(_SAVED_BYTES).items()
+                    if v != kept0.get(k, 0)}
     finally:
         lower_s = _stat.end_span()
     _stat.begin_span("jit.compile")
@@ -125,7 +136,8 @@ def aot_compile(jitted, args, tag=None, static=None, arg_names=None):
         _flight.register_executable(tag, compiled)
     _observatory.record_compile(
         obs_tag, sig, sig_key, lower_s, compile_s, cache_hit, compiled,
-        cost=ca, arg_names=arg_names, cache_entries_added=len(added))
+        cost=ca, arg_names=arg_names, cache_entries_added=len(added),
+        remat_saved_bytes=kept)
     return compiled, info
 
 

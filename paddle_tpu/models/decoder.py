@@ -21,11 +21,16 @@ Parameter names follow the published module tree (`model.embed_tokens`,
 ...). The layers stand
 in two lists: `model.lead.<i>`, the leading layers that differ from the
 rest, and `model.h.<i>`, the uniform run that ends the stack. Under a
-trace every layer is rematerialised in the backward pass
-(jax.checkpoint) from its input and the flash kernel's output (`out`,
-`lse`), so that the kernel runs once a layer, and a uniform run of more
-than one layer is one lax.scan over its stacked parameters, traced and
-compiled once whatever its length.
+trace every layer is wrapped in jax.checkpoint under one policy
+(`_REMAT_POLICY`): its backward pass reads the layer's input, the flash
+kernel's output (`out`, `lse`) and the result of every matmul that
+contracts over the full hidden width, each part tagging its own
+(`KEPT`), so that neither the kernel nor such a matmul runs twice; the
+norms, rotary, the router, latent attention's low-rank up-projections
+and the expert layer run again. The bytes kept are counted as the stack
+traces (profiler.monitor `remat.saved_bytes.<name>`). A uniform run of
+more than one layer is one lax.scan over its stacked parameters, traced
+and compiled once whatever its length.
 
 A pattern that repeats with a period over one layer (SmallThinker's
 full, window, window, window) is NOT one scan over periods: everything
@@ -43,6 +48,7 @@ import jax.numpy as jnp
 from ..framework.core import Tensor
 from .. import nn
 from ..nn import functional as F
+from ..nn.layer.common import save_point, tally_save_points
 
 __all__ = ["DecoderConfig", "DecoderStack", "DecoderForCausalLM",
            "LatentAttention", "GroupedQueryAttention", "glm_4_7_flash_ep8",
@@ -177,11 +183,16 @@ class LatentAttention(nn.Layer):
         B, T, _ = x.shape
         nh, nope, rope, vd = self.nh, self.nope, self.rope, self.vd
         with jax.named_scope("mla.project"):
-            q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
+            # the two latents are what a backward pass keeps (the
+            # norms' backward reads their inputs); the up-projections
+            # from them run again
+            cq = save_point(self.q_a_proj(x), "mla_q_latent")
+            q = self.q_b_proj(self.q_a_layernorm(cq))
             q_nope, q_rope = split(q.reshape([B, T, nh, nope + rope]),
                                    [nope, rope], axis=-1)
-            ckv, kr = split(self.kv_a_proj_with_mqa(x), [self.rkv, rope],
-                            axis=-1)
+            ckv, kr = split(
+                save_point(self.kv_a_proj_with_mqa(x), "mla_kv_latent"),
+                [self.rkv, rope], axis=-1)
             kv = self.kv_b_proj(self.kv_a_layernorm(ckv))
             k_nope, v = split(kv.reshape([B, T, nh, nope + vd]),
                               [nope, vd], axis=-1)
@@ -244,6 +255,8 @@ class GroupedQueryAttention(nn.Layer):
             v = self.v_proj(x).reshape([B, T, self.nkv, self.hd])
             if self.rotary is not None:
                 q, k = self.rotary(q), self.rotary(k)
+            q, k, v = (save_point(t, n) for t, n in
+                       ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
         with jax.named_scope("gqa.core"):
             o = F.scaled_dot_product_attention(q, k, v, is_causal=True,
                                                window=self.window)
@@ -286,10 +299,24 @@ ROUTED_ON_LAYER_INPUT = ("moe_pre",)
 
 # what a layer keeps for its backward pass beside its input: the flash
 # kernel's output and row statistics (named in ops/pallas/
-# flash_attention.py), so that the backward pass rebuilds the
-# projections and the feed-forward but does not run the kernel again
+# flash_attention.py), so that the kernel runs once a layer, and the
+# result of every matmul that contracts over the full hidden width, so
+# that none runs again. Each part tags its own (`save_point`, nn/layer/
+# common.py): grouped-query attention q, k, v as the kernel takes them,
+# after rotary; latent attention its two latents as q_a_proj and
+# kv_a_proj_with_mqa make them, before their norms; the layer the
+# residual after the mixer, so o_proj does not run again; a gated
+# feed-forward (the dense layer, the shared expert) its gate and up
+# outputs. Recomputed: the norms, rotary, the router (from the kept
+# residual or the layer's input) and latent attention's up-projections
+# q_b_proj and kv_b_proj, whose outputs are the layer's widest arrays
+# and cost a rank (768, 512) of FLOPs an element against the hidden
+# width's 2,048; the expert layer runs its rung's forward again by
+# design (incubate/moe.py `_on_ladder_bwd`)
+KEPT = ("attn_q", "attn_k", "attn_v", "mla_q_latent", "mla_kv_latent",
+        "layer_h", "mlp_gate", "mlp_up")
 _REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
-    "flash_out", "flash_lse")
+    "flash_out", "flash_lse", *KEPT)
 
 
 class DecoderLayer(nn.Layer):
@@ -304,24 +331,41 @@ class DecoderLayer(nn.Layer):
         self.routes_on_input = ffn in ROUTED_ON_LAYER_INPUT
 
     def forward(self, x):
-        h = x + self.self_attn(self.input_layernorm(x))
+        h = save_point(x + self.self_attn(self.input_layernorm(x)),
+                       "layer_h")
         y = self.post_attention_layernorm(h)
         return h + (self.mlp(y, router_input=x) if self.routes_on_input
                     else self.mlp(y))
 
 
-def _call_layer(layer, h, names):
+def _call_layer(layer, h, names, kept):
     """(output, counter vector or None) of one layer on the array h, as
     a function of arrays: what jax.checkpoint and lax.scan can wrap. The
     counters a sublayer recorded leave the inner trace here; what they
-    are called goes into the list `names`."""
+    are called goes into the list `names`, and the bytes the layer tags
+    to keep for its backward pass, by name, into the dict `kept`."""
     from ..jit.api import take_step_counters
-    out = layer(Tensor(h)).value
+    with tally_save_points() as tally:
+        out = layer(Tensor(h)).value
+    kept.clear()
+    kept.update(tally)
     counters = take_step_counters(layer)
     if counters is None:
         return out, None
     names[:] = counters[0]
     return out, counters[1]
+
+
+def _count_kept(tagged, layers):
+    """Add what one traced layer call keeps, by name, for `layers`
+    layers to profiler.monitor's `remat.saved_bytes.<name>` (the compile
+    record of the step carries what its trace added:
+    jit.api.aot_compile). `tagged` holds every save point the call
+    tagged; the policy keeps those named in KEPT."""
+    from ..profiler import monitor
+    for name, n in tagged.items():
+        if name in KEPT:
+            monitor.counter(f"remat.saved_bytes.{name}").inc(n * layers)
 
 
 class DecoderStack(nn.Layer):
@@ -358,23 +402,25 @@ class DecoderStack(nn.Layer):
             if counters is not None:
                 self.step_counter_names, self._step_counters = counters
             return self.norm(x)
-        h, names, found = x.value, [], []
+        h, names, found, kept = x.value, [], [], {}
         scans = len(self.h) > 1
         for layer in [*self.lead, *([] if scans else self.h)]:
             h, c = jax.checkpoint(
-                lambda hv, layer=layer: _call_layer(layer, hv, names),
+                lambda hv, layer=layer: _call_layer(layer, hv, names, kept),
                 policy=_REMAT_POLICY)(h)
             found.append(c)
+            _count_kept(kept, 1)
         if scans:
-            h, c = self._scan(h, names)
+            h, c = self._scan(h, names, kept)
             found.append(None if c is None else c.sum(0, dtype=c.dtype))
+            _count_kept(kept, len(self.h))
         found = [c for c in found if c is not None]
         if found:
             self.step_counter_names = tuple(names)
             self._step_counters = sum(found)
         return self.norm(Tensor(h))
 
-    def _scan(self, h, names):
+    def _scan(self, h, names, kept):
         """The uniform run as ONE lax.scan over its stacked parameters
         and buffers (stacked here, under the trace: at rest they stay
         per layer, as state_dict and the optimizer see them)."""
@@ -388,7 +434,7 @@ class DecoderStack(nn.Layer):
         def step(hv, arrays):
             saved = _bind(proto, arrays)
             try:
-                return _call_layer(proto, hv, names)
+                return _call_layer(proto, hv, names, kept)
             finally:
                 _restore(saved)
 

@@ -1,4 +1,7 @@
 """Common layers. Parity: python/paddle/nn/layer/common.py."""
+import contextlib
+import threading
+
 import numpy as np
 
 from ...framework.core import Tensor
@@ -45,12 +48,48 @@ class Linear(Layer):
                f"out_features={self._out_features}"
 
 
+_tally = threading.local()
+
+
+def save_point(t, name):
+    """Tag the traced activation `t` as the remat save point `name`
+    (jax.ad_checkpoint.checkpoint_name): a jax.checkpoint policy that
+    names it keeps it for the backward pass, any other policy treats the
+    tag as the identity. Inside `tally_save_points()` its bytes are
+    counted under its name. Eager values pass untouched (the tape holds
+    them; re-wrapping would orphan them from it)."""
+    import jax
+    v = t.value
+    if not isinstance(v, jax.core.Tracer):
+        return t
+    counts = getattr(_tally, "counts", None)
+    if counts is not None:
+        counts[name] = counts.get(name, 0) + v.size * v.dtype.itemsize
+    from jax.ad_checkpoint import checkpoint_name
+    return Tensor(checkpoint_name(v, name))
+
+
+@contextlib.contextmanager
+def tally_save_points():
+    """{save point name: bytes} of what `save_point` tags while the block
+    traces (this thread only)."""
+    outer = getattr(_tally, "counts", None)
+    _tally.counts = counts = {}
+    try:
+        yield counts
+    finally:
+        _tally.counts = outer
+
+
 class GatedMLP(Layer):
     """SiLU-gated feed-forward (Shazeer, 2020, "GLU variants"):
     down(act(gate(x)) * up(x)), three bias-free Linear layers `gate_proj`,
     `up_proj` [hidden_size, intermediate_size] and `down_proj`
     [intermediate_size, hidden_size]. `activation` names a function of
-    nn.functional ("silu" by default)."""
+    nn.functional ("silu" by default). The gate and up outputs are the
+    save points "mlp_gate" and "mlp_up", kept by a remat policy that
+    names them (models/decoder.py's) so that a backward pass does not
+    run the two full-width matmuls again."""
 
     def __init__(self, hidden_size, intermediate_size, activation="silu",
                  weight_attr=None, name=None):
@@ -64,7 +103,9 @@ class GatedMLP(Layer):
         self._act = getattr(F, activation)
 
     def forward(self, x):
-        return self.down_proj(self._act(self.gate_proj(x)) * self.up_proj(x))
+        gate = save_point(self.gate_proj(x), "mlp_gate")
+        up = save_point(self.up_proj(x), "mlp_up")
+        return self.down_proj(self._act(gate) * up)
 
 
 class RotaryEmbedding(Layer):

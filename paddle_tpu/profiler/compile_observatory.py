@@ -15,7 +15,10 @@ much; this module keeps the per-executable WHAT:
   executable itself: instruction counts by op kind, fusion count, bytes
   accessed + FLOPs (`cost_analysis()`, per *Operator Fusion in XLA*,
   arxiv 2301.13062 — XLA's own analysis is the fusion-accounting source
-  of truth), and a peak-memory estimate (`memory_analysis()`). Records
+  of truth), a peak-memory estimate (`memory_analysis()`), and the
+  bytes the traced program keeps for its backward pass by remat save
+  point (`remat_saved_bytes`, from what models/decoder.py counts in
+  profiler.monitor as it traces). Records
   land in the flight-recorder ring (always) and the metrics JSONL
   (when `PADDLE_TPU_METRICS_FILE` is set; schema enforced by
   tools/check_metrics_schema.py).
@@ -303,9 +306,11 @@ def compile_started(tag, sig, arg_names=None):
 
 def record_compile(tag, sig, sig_key, lower_s, compile_s, cache_hit,
                    compiled, cost=None, arg_names=None,
-                   cache_entries_added=0):
+                   cache_entries_added=0, remat_saved_bytes=None):
     """One finished compile -> one ledger entry + one `kind:"compile"`
     record (flight-recorder ring always; metrics JSONL when configured).
+    `remat_saved_bytes`: {save point name: bytes} the traced program
+    keeps for its backward pass (`remat_saved_bytes` of the record).
     Returns the record. Never raises — the ledger is telemetry."""
     try:
         stats = hlo_stats(compiled)
@@ -321,6 +326,8 @@ def record_compile(tag, sig, sig_key, lower_s, compile_s, cache_hit,
             "fusion_count": int(stats["fusion_count"]),
             "op_counts": stats["op_counts"],
             "kernels": stats["kernels"],
+            "remat_saved_bytes": {str(k): int(v) for k, v in sorted(
+                (remat_saved_bytes or {}).items())},
             # cost_analysis can answer -1 for "unknown"; the schema (and
             # the ratchet math) want "unknown" as 0
             "flops": max(float(cost.get("flops", 0.0)), 0.0),

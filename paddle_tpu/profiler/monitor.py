@@ -97,7 +97,8 @@ from . import flight_recorder
 
 __all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge",
            "histogram", "get_metric", "metrics_snapshot", "reset_metrics",
-           "rank", "metrics_file", "export_step", "host_blocked_s",
+           "counters_under", "rank", "metrics_file", "export_step",
+           "host_blocked_s",
            "set_clock_offset", "clock_offset"]
 
 # this rank's estimated wall-clock offset vs rank 0 (seconds), set by
@@ -258,6 +259,15 @@ def metrics_snapshot():
     with _lock:
         return {name: _registry[name].snapshot()
                 for name in sorted(_registry)}
+
+
+def counters_under(prefix):
+    """{name after `prefix`: value} of the counters whose name starts
+    with `prefix`."""
+    with _lock:
+        return {name[len(prefix):]: m.value
+                for name, m in _registry.items()
+                if name.startswith(prefix) and isinstance(m, Counter)}
 
 
 def reset_metrics():

@@ -261,15 +261,30 @@ def _compiled_train_step(compile_for_chip, config, ids_shape):
                + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+# the benchmark's decoder cells: preset, ids a step
+_CELLS = {"glm-4.7-flash-ep8": ("glm_4_7_flash_ep8", (2, 4096)),
+          "smallthinker-21b-ep8": ("smallthinker_21b_ep8", (1, 16384))}
+_cell_steps = {}
+
+
+def _cell_step(compile_for_chip, cell):
+    """_compiled_train_step of a cell, compiled once for this file (a
+    minute or so on the host each)."""
+    if cell not in _cell_steps:
+        from paddle_tpu.models import decoder
+        preset, ids_shape = _CELLS[cell]
+        _cell_steps[cell] = _compiled_train_step(
+            compile_for_chip, getattr(decoder, preset)(), ids_shape)
+    return _cell_steps[cell]
+
+
 def test_glm_flash_ep8_step_fits_the_chip(compile_for_chip, chip_branches):
     """The whole TrainStep of glm-4.7-flash-ep8's cell (2 x 4096 ids,
     AdamW with float32 masters, the fused update): what the compiler
     says it needs stays under the 15.75 GB a v5e leaves a program, with
     the flash residuals saved; one forward kernel for the leading layer
     and one for the scan, as many as dq and dkv."""
-    from paddle_tpu.models.decoder import glm_4_7_flash_ep8
-    c, needs = _compiled_train_step(compile_for_chip, glm_4_7_flash_ep8(),
-                                    (2, 4096))
+    c, needs = _cell_step(compile_for_chip, "glm-4.7-flash-ep8")
     assert needs < 15.75e9, needs
     assert _flash_kernel_counts(c) == (2, 2, 2)
 
@@ -280,9 +295,7 @@ def test_smallthinker_ep8_step_fits_the_chip(compile_for_chip,
     ids): under the 15.75 GB; the full layer's kernels and the window
     scan's, each once; and k, v reach the kernels at their own 4 heads —
     nothing in the module has them at the 28 query heads' width."""
-    from paddle_tpu.models.decoder import smallthinker_21b_ep8
-    c, needs = _compiled_train_step(compile_for_chip, smallthinker_21b_ep8(),
-                                    (1, 16384))
+    c, needs = _cell_step(compile_for_chip, "smallthinker-21b-ep8")
     assert needs < 15.75e9, needs
     assert _flash_kernel_counts(c) == (2, 2, 2)
     operands = _flash_operands(c)
@@ -291,6 +304,29 @@ def test_smallthinker_ep8_step_fits_the_chip(compile_for_chip,
         assert text.count("bf16[1,16384,3584]{2,1,0}") >= 1, (kernel, text)
         assert text.count("bf16[1,16384,512]{2,1,0}") == 2, (kernel, text)
     assert not re.search(r"bf16\[1,16384,4,7,128\]", c.as_text())
+
+
+# what each decoder cell's compiled step needs and how many convolutions
+# (the matmuls) its module holds, as measured when the decoder's remat
+# policy came to keep the result of every full-width matmul (43 and 69
+# convolutions before, 10.63 and 13.40 GB): the bytes with 0.25 GB of
+# room, so that a later save cannot creep up to the chip's limit unseen
+_STEP_CEILINGS = {"glm-4.7-flash-ep8": (14_507_351_040 + 0.25e9, 59),
+                  "smallthinker-21b-ep8": (11_953_884_160 + 0.25e9, 35)}
+
+
+@pytest.mark.parametrize("cell", list(_STEP_CEILINGS))
+def test_decoder_step_recomputes_no_full_width_matmul(
+        compile_for_chip, chip_branches, cell):
+    """The compiled TrainStep of a decoder cell holds no more matmuls
+    than the forward, the backward and the recompute of latent
+    attention's up-projections and the expert layer need, and needs no
+    more than it did when the policy came."""
+    c, needs = _cell_step(compile_for_chip, cell)
+    most_bytes, most_convolutions = _STEP_CEILINGS[cell]
+    assert needs <= most_bytes, needs
+    convolutions = len(re.findall(r"= [^\n]*? convolution\(", c.as_text()))
+    assert convolutions <= most_convolutions, convolutions
 
 
 @pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])

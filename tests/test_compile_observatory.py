@@ -467,3 +467,22 @@ def test_compile_record_names_the_pallas_kernels_under_their_scope():
     assert any("kernels" in e for e in cms.validate_line(json.dumps(bad)))
     text = _load_tool("obs_report").render([rec])
     assert "kernels: flash.direct/flash_attention_dq x2" in text
+
+
+def test_compile_record_carries_the_bytes_kept_for_the_backward_pass():
+    """`remat_saved_bytes` ({save point: bytes}, what the traced program
+    counted under profiler.monitor's `remat.saved_bytes.*` while it
+    lowered): the schema tool takes it and refuses a negative count, and
+    obs_report prints it under the tag."""
+    rec = {"ts": 1.0, "rank": 0, "kind": "compile", "tag": "train.step",
+           "signature": "abc", "lower_s": 0.1, "compile_s": 0.2,
+           "cache_hit": False, "instructions": 3, "fusion_count": 0,
+           "bytes_accessed": 1.0, "flops": 1.0, "peak_memory_bytes": 64.0,
+           "remat_saved_bytes": {"layer_h": 3 * 2**20, "attn_q": 2**21}}
+    cms = _load_tool("check_metrics_schema")
+    assert cms.validate_line(json.dumps(rec)) == []
+    bad = dict(rec, remat_saved_bytes={"layer_h": -1})
+    assert any("remat_saved_bytes" in e
+               for e in cms.validate_line(json.dumps(bad)))
+    text = _load_tool("obs_report").render([rec])
+    assert "kept for backward: attn_q 2.0 MiB  layer_h 3.0 MiB" in text

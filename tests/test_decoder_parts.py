@@ -218,6 +218,11 @@ def test_grads_equal_the_stack_without_remat_bit_for_bit(
 
 def test_one_layer_saves_the_flash_residuals_and_nothing_else(
         flash_interpret, remat_saved):
+    """Beside the flash kernel's out and lse, the dense latent layer keeps
+    only what its parts tag (tests/test_decoder_remat.py has each kind):
+    the two latents (32; 16 + 8 wide), the residual after the mixer, and
+    the feed-forward's up and gate (96 wide), the gate through its
+    jitted activation."""
     from paddle_tpu.framework.core import Tensor
     from paddle_tpu.models import decoder
     model, _, _ = _flash_stack(2)
@@ -225,7 +230,14 @@ def test_one_layer_saves_the_flash_residuals_and_nothing_else(
     fn = jax.checkpoint(lambda h: layer(Tensor(h)).value,
                         policy=decoder._REMAT_POLICY)
     saved = remat_saved(fn, jnp.ones((2, 128, 64), jnp.float32))
-    assert [aval for aval, _ in saved] == ["float32[2,128,64]",
+    flash = [(aval, why) for aval, why in saved
+             if "flash_attention.py" in why]
+    assert [aval for aval, _ in flash] == ["float32[2,128,64]",
                                            "float32[4,1,128]"]
-    assert all("flash_attention.py" in why for _, why in saved)
-    assert "named 'flash_lse'" in saved[1][1]
+    assert "named 'flash_lse'" in flash[1][1]
+    rest = [(aval, why) for aval, why in saved if (aval, why) not in flash]
+    assert sorted(aval for aval, _ in rest) == [
+        "float32[2,128,24]", "float32[2,128,32]", "float32[2,128,64]",
+        "float32[2,128,96]", "float32[2,128,96]"]
+    assert all("(save_point)" in why or "jitted function 'silu'" in why
+               for _, why in rest), rest

@@ -162,6 +162,9 @@ Schema (documented in docs/OBSERVABILITY.md):
                   op_counts    dict    {op kind: count >= 0}
                   kernels      dict    {"<scope>/<Pallas kernel>":
                                        count >= 0}
+                  remat_saved_bytes dict  {remat save point: bytes
+                                       >= 0 the program keeps for its
+                                       backward pass}
   kind == "warm" (one record per resolved warm set —
                   paddle_tpu/jit/warm.py join) additionally requires:
                   n_executables int    handles in the set (>= 0)
@@ -902,7 +905,7 @@ def validate_line(line, where="<line>"):
                 f"{where}: cache_hit record spent {comp}s in compile_s "
                 f"(> {CACHE_HIT_COMPILE_S_MAX}s) — a hit loads an "
                 "artifact, it does not compile")
-        for key in ("op_counts", "kernels"):
+        for key in ("op_counts", "kernels", "remat_saved_bytes"):
             ops = rec.get(key)
             if ops is None:
                 continue

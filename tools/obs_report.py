@@ -86,9 +86,11 @@ def section_compiles(recs, out, top):
     by_tag = {}
     for r in comps:
         t = by_tag.setdefault(r.get("tag", "?"),
-                              {"n": 0, "s": 0.0, "hits": 0, "kernels": {}})
+                              {"n": 0, "s": 0.0, "hits": 0, "kernels": {},
+                               "kept": {}})
         t["n"] += 1
         t["kernels"] = r.get("kernels") or t["kernels"]  # the newest
+        t["kept"] = r.get("remat_saved_bytes") or t["kept"]
         t["s"] += float(r.get("lower_s", 0.0)) + \
             float(r.get("compile_s", 0.0))
         t["hits"] += 1 if r.get("cache_hit") else 0
@@ -101,6 +103,10 @@ def section_compiles(recs, out, top):
         if t["kernels"]:    # Pallas kernels under their scope: which
             out.append("    kernels: " + "  ".join(     # layout flash got
                 f"{k} x{v}" for k, v in sorted(t["kernels"].items())))
+        if t["kept"]:       # what the backward pass reads, by save point
+            out.append("    kept for backward: " + "  ".join(
+                f"{k} {v / 2**20:.1f} MiB"
+                for k, v in sorted(t["kept"].items())))
     out.append("")
 
 
