@@ -16,7 +16,8 @@ and the device never waits on an H2D copy.
 or, one level up, `DataLoader(..., prefetch_to_device=2)` — the hapi
 `Model.fit` wires the step's `input_sharding` in automatically.
 
-Telemetry: per-batch staging lands as the "prefetch.h2d" span, real
+Telemetry: the consumer's wait for a staged batch lands as the
+"prefetch.wait" span, per-batch staging as the "prefetch.h2d" span, real
 staging traffic (host arrays moved to device, or device arrays re-placed
 to the step's sharding — NOT copy-free pass-throughs of already-placed
 batches) in the `prefetch.h2d_bytes` counter, and the ring's fill level
@@ -143,7 +144,8 @@ class DevicePrefetchRing:
     def __next__(self):
         if self._stop.is_set():
             raise StopIteration
-        item = self._q.get()
+        with _stat.span("prefetch.wait"):
+            item = self._q.get()
         _monitor.gauge("prefetch.depth").set(self._q.qsize())
         if item is _END:
             self._stop.set()

@@ -15,6 +15,7 @@ differentiation on this path).
 import collections
 import contextlib
 import functools
+import statistics
 import threading
 import time
 
@@ -37,6 +38,7 @@ from . import warm as _warm
 
 __all__ = ["functional_call", "to_static", "TrainStep", "not_to_static",
            "aot_compile", "count_train_use", "export_step_metrics",
+           "stall_record",
            "DeferredLoss", "HealthMonitorMixin",
            "CheckpointSnapshotMixin"]
 
@@ -163,6 +165,14 @@ def count_train_use(owner, info):
     owner.last_compile_s = total
 
 
+# a steady step interval over STALL_FACTOR times the median of the last
+# STALL_KEEP steady intervals, once STALL_MIN are kept, is a stall: at
+# twice the step, the two steps in flight no longer hide a host pause
+# from the device. The median is taken again every STALL_MIN intervals,
+# and afresh before a stall is declared.
+STALL_KEEP, STALL_MIN, STALL_FACTOR = 32, 8, 2.0
+
+
 def export_step_metrics(step, dispatch_s, info, compiled_now):
     """Per-step telemetry for a train-step object: step-time histogram,
     cost-analysis FLOPs/MFU gauges, and — when PADDLE_TPU_METRICS_FILE
@@ -173,15 +183,41 @@ def export_step_metrics(step, dispatch_s, info, compiled_now):
     returned: under async dispatch the call itself returns early, but in
     a steady train loop the inter-dispatch interval converges on the
     true device step time. The first (or a recompiling) step falls back
-    to its own dispatch time minus the compile."""
-    now = time.perf_counter()
+    to its own dispatch time minus the compile.
+
+    A steady interval over twice the median of the last 32 (once 8 are
+    kept; an ordinary step compares with the median taken at most 8
+    intervals ago) is a stall: one `kind:"stall"` record says what covered it
+    (`stall_record`), and `train.stalls` / `train.stall_s` (the excess
+    over the median) count it."""
+    now = (time.perf_counter_ns(), time.process_time(), _stat.gc_pause_s())
     prev = getattr(step, "_last_step_end", None)
     step._last_step_end = now
     compile_s = info["lower_s"] + info["compile_s"] if compiled_now \
         else 0.0
     steady = prev is not None and not compiled_now
-    step_time = now - prev if steady \
+    step_time = (now[0] - prev[0]) * 1e-9 if steady \
         else max(dispatch_s - compile_s, 0.0)
+    if steady:
+        kept = getattr(step, "_steady_intervals", None)
+        if kept is None:
+            kept = step._steady_intervals = collections.deque(
+                maxlen=STALL_KEEP)
+            step._stall_median, step._since_median = None, 0
+        median = step._stall_median
+        if median is not None and step_time > STALL_FACTOR * median:
+            median = statistics.median(kept)
+            if step_time > STALL_FACTOR * median:
+                _monitor.export_step(
+                    stall_record(int(step._step_i), prev, now, median),
+                    kind="stall")
+                _monitor.counter("train.stalls").inc()
+                _monitor.counter("train.stall_s").inc(step_time - median)
+        kept.append(step_time)
+        step._since_median += 1
+        if step._since_median == STALL_MIN:
+            step._stall_median = statistics.median(kept)
+            step._since_median = 0
     flops = float(info.get("flops", 0.0))
     # MFU only from the steady inter-dispatch interval: the fallback
     # dispatch time is near zero under async dispatch and would publish
@@ -223,6 +259,23 @@ def export_step_metrics(step, dispatch_s, info, compiled_now):
     # periodic device-memory attribution (kind:"memory") — same cadence
     # shape: first step always, then every PADDLE_TPU_MEMORY_EVERY-th
     _mobs.maybe_memory(int(step._step_i), source="train")
+
+
+def stall_record(step_i, prev, now, median_s):
+    """The `kind:"stall"` record of the interval between two step
+    stamps (perf_counter_ns, process_time, gc_pause_s): how long, the
+    median it ran over, its ends on the span ring's clock, the CPU
+    seconds the process spent in it (far below the interval: the
+    process was off the CPU), the collector's pause in it, the ring's
+    spans that overlap it by name on the thread that calls the step and
+    on every other thread, and the part no span on the step's thread
+    covers."""
+    mine, others, uncovered = _stat.coverage(prev[0], now[0])
+    return {"step": step_i, "interval_s": (now[0] - prev[0]) * 1e-9,
+            "median_s": median_s, "start_ns": prev[0], "end_ns": now[0],
+            "cpu_s": now[1] - prev[1], "gc_s": now[2] - prev[2],
+            "covered": {"step_thread": mine, "other_threads": others},
+            "uncovered_s": uncovered}
 
 
 def state_arrays(layer):

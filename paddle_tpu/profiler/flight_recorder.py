@@ -8,9 +8,10 @@ timeline when it got slow* and *what state was it in when it crashed or
 hung*. The span store (`statistic.py`) and metrics registry
 (`monitor.py`) aggregate; this module additionally keeps the RAW tail:
 
-- **spans** — every closed host span (name, start, duration, thread,
-  nesting depth), the events `trace_export.py` renders into a Perfetto
-  timeline;
+- **spans** — every closed host span (name, start and duration in
+  `time.perf_counter_ns()` nanoseconds, thread, nesting depth, and the
+  args of the few spans that carry them: `host.gc`'s generation), the
+  events `trace_export.py` renders into a Perfetto timeline;
 - **samples** — every counter/gauge/histogram update (the counter
   tracks of the timeline: queue depth, prefetch depth, host.blocked_s);
 - **records** — the per-step / per-batch JSONL records
@@ -108,16 +109,18 @@ def perf_to_wall(t_perf):
     return t_perf + _PERF_TO_WALL
 
 
-def record_span_event(name, t0_perf, dur_s, thread_ident, depth=0):
-    """One CLOSED span (called by statistic.py when a span ends or an
-    already-measured duration is recorded). t0_perf is the span's start
-    on the perf_counter clock."""
-    _spans.append((name, t0_perf, dur_s, thread_ident, depth))
+def record_span_event(name, t0_ns, dur_ns, thread_ident, depth=0,
+                      args=None):
+    """One CLOSED span (called by statistic.py when a span ends or a
+    garbage collection stops). t0_ns and dur_ns are on the
+    time.perf_counter_ns() clock."""
+    _spans.append((name, t0_ns, dur_ns, thread_ident, depth, args))
 
 
 def span_events():
-    """The span ring, oldest first: (name, t0_perf, dur_s, thread_ident,
-    depth) of each closed span it still holds."""
+    """The span ring, in the order the spans closed: (name, t0_ns,
+    dur_ns, thread_ident, depth, args) of each closed span it still
+    holds."""
     return list(_spans)
 
 
@@ -221,9 +224,9 @@ def heartbeat(step=None):
 
 def snapshot():
     """The rings as plain JSON-serializable dicts (spans carry wall ts)."""
-    spans = [{"name": n, "ts": perf_to_wall(t0), "dur_s": d,
+    spans = [{"name": n, "ts": perf_to_wall(t0 * 1e-9), "dur_s": d * 1e-9,
               "tid": tid, "depth": depth}
-             for (n, t0, d, tid, depth) in span_events()]
+             for (n, t0, d, tid, depth, _) in span_events()]
     samples = [{"ts": ts, "name": n, "kind": k, "value": v}
                for (ts, n, k, v) in list(_samples)]
     return {"spans": spans, "samples": samples,

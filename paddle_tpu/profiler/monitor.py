@@ -59,6 +59,8 @@ Record kinds riding the exporter (one line each; full field schemas in
 tools/check_metrics_schema.py):
 
     step        one per optimizer step (TrainStep / HybridTrainStep)
+    stall       one per step interval over twice the median of the last
+                32: what covered it (jit/api.py export_step_metrics)
     scan        one per scanned-layer-group step (scan-over-layers path)
     serve       one per dispatched serving batch (GenerationEngine)
     health      one per resolved async health vector (health monitor)
@@ -264,9 +266,9 @@ def metrics_snapshot():
 def counters_under(prefix):
     """{name after `prefix`: value} of the counters whose name starts
     with `prefix`."""
-    with _lock:
+    with _lock:   # a copy: a collection's hook may add gc.* counters
         return {name[len(prefix):]: m.value
-                for name, m in _registry.items()
+                for name, m in list(_registry.items())
                 if name.startswith(prefix) and isinstance(m, Counter)}
 
 

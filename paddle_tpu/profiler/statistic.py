@@ -13,6 +13,27 @@ that did the work and on the clock the device ops are on, so an idle
 gap of the device can be put down to the span that covers it. With no
 profiler session the annotation is jax's own no-op.
 
+The ring of closed spans (`closed_spans()`, the flight recorder's) is on
+another clock: `time.perf_counter_ns()`, stamped where the span opens
+(`start_ns`; `start_s` is the same stamp in seconds). A trace's events
+carry the profiler session's own clock, a constant offset away. The
+offset is found from pairs: where the trace holds a host event that
+wraps exactly one ring span (the benchmark's `bench.step` around each
+`train.step` call), pair the trace's k such events with the ring's last
+k spans of that name; the offset is the median of their start
+differences, and a pairing whose largest residual from that median
+exceeds 100 us is refused (benchmarks/readers/host_spans.py). Mapped
+so, every span in the ring lies on the device ops' timeline, also those
+that closed before the trace started.
+
+Garbage collections are recorded too: a `gc.callbacks` hook, installed
+when this module is imported, counts every collection in
+`profiler.monitor` (`gc.collections.gen<k>`, `gc.pause_s.gen<k>`) and
+puts it in the ring as a `host.gc` span carrying its generation, on the
+thread that collected, at that thread's depth. It is not an annotation
+and not in the aggregate tree: a collection can start inside any
+allocation, also one this module makes while it walks the tree.
+
 Spans nest: a span that begins while another is open on the same thread
 becomes its child, and the summary table indents children under their
 parent with per-node call counts, total/avg/max wall time, and the share
@@ -20,14 +41,16 @@ of all recorded top-level time. Threads merge into one tree (a node
 remembers which threads hit it); `thread_sep=True` renders one tree per
 thread.
 """
+import gc
 import threading
 import time
 
 from . import flight_recorder
+from . import monitor
 
-__all__ = ["SpanNode", "span", "begin_span", "end_span", "record_span",
-           "closed_spans", "reset_statistics", "snapshot", "summary_table",
-           "get_events", "SortedKeys"]
+__all__ = ["SpanNode", "span", "begin_span", "end_span", "closed_spans",
+           "coverage", "gc_pause_s", "reset_statistics", "snapshot",
+           "summary_table", "get_events", "SortedKeys"]
 
 
 class SortedKeys:
@@ -99,55 +122,121 @@ def begin_span(name, **args):
     ann = (jax.profiler.StepTraceAnnotation if "step_num" in args
            else jax.profiler.TraceAnnotation)(name, **args)
     ann.__enter__()
-    _stack().append((name, time.perf_counter(), ann))
+    _stack().append((name, time.perf_counter_ns(), ann))
 
 
 def end_span():
-    """Close the innermost open span on this thread and record it."""
+    """Close the innermost open span on this thread and record it;
+    returns its duration in seconds."""
     st = _stack()
     if not st:
         return 0.0
     name, t0, ann = st.pop()
-    dt = time.perf_counter() - t0
+    dur = time.perf_counter_ns() - t0
     ann.__exit__(None, None, None)
-    _record(name, dt, [e[0] for e in st], t0)
-    return dt
-
-
-def record_span(name, seconds):
-    """Record an already-measured duration as a span nested under this
-    thread's currently-open spans. Handed over after the fact, it cannot
-    be an annotation in a profiler trace: code that times a real region
-    uses `span` instead."""
-    seconds = float(seconds)
-    _record(name, seconds, [e[0] for e in _stack()],
-            time.perf_counter() - seconds)
+    ident = threading.get_ident()
+    with _lock:
+        node = _root
+        for e in st:
+            node = node.child(e[0])
+        node.child(name).add(dur * 1e-9, ident)
+    # raw event tail for the timeline view (trace_export.py): the
+    # aggregation above answers "how much", the flight-recorder ring
+    # answers "when" — a bounded deque append, negligible per span
+    flight_recorder.record_span_event(name, t0, dur, ident, len(st))
+    return dur * 1e-9
 
 
 def closed_spans():
     """The recorder's tail of closed spans, in the order they closed:
-    dicts of name, start_s (time.perf_counter clock), dur_s, thread
-    (ident) and depth (open spans above it on its thread). A parent
+    dicts of name, start_ns (time.perf_counter_ns clock), start_s (the
+    same in seconds), dur_s, thread (ident), depth (open spans above it
+    on its thread) and, for the spans that carry them, args. A parent
     follows its children; the ring holds the newest
     flight_recorder.SPAN_RING."""
-    return [{"name": n, "start_s": t0, "dur_s": d, "thread": tid,
-             "depth": depth}
-            for n, t0, d, tid, depth in flight_recorder.span_events()]
+    out = []
+    for n, t0, d, tid, depth, args in flight_recorder.span_events():
+        e = {"name": n, "start_ns": t0, "start_s": t0 * 1e-9,
+             "dur_s": d * 1e-9, "thread": tid, "depth": depth}
+        if args is not None:
+            e["args"] = args
+        out.append(e)
+    return out
 
 
-def _record(name, seconds, parent_names, t0=None):
-    ident = threading.get_ident()
-    with _lock:
-        node = _root
-        for p in parent_names:
-            node = node.child(p)
-        node.child(name).add(seconds, ident)
-    # raw event tail for the timeline view (trace_export.py): the
-    # aggregation above answers "how much", the flight-recorder ring
-    # answers "when" — a bounded deque append, negligible per span
-    flight_recorder.record_span_event(
-        name, t0 if t0 is not None else time.perf_counter() - seconds,
-        seconds, ident, len(parent_names))
+# the ring is in closing order only to within the time a thread takes
+# from its span's end stamp to the append (a thread switch at most, in
+# practice): a span that closed this long before an interval ends the
+# walk back through the ring
+_RING_ORDER_NS = 10_000_000
+
+
+def coverage(t0_ns, t1_ns):
+    """What the ring says the process did in [t0_ns, t1_ns]
+    (time.perf_counter_ns clock): ({name: seconds} of the spans on this
+    thread that overlap it, open ones included; the same on every other
+    thread; the seconds of the interval that no span on this thread
+    covers). Nested spans each count under their own name. Walks the
+    ring back from its newest span to the first one that closed well
+    before t0_ns."""
+    mine, others, cover = {}, {}, []
+    me = threading.get_ident()
+    spans = [(n, s, t1_ns, me) for n, s, _ in _stack()]
+    for n, s, d, tid, _, _ in reversed(flight_recorder.span_events()):
+        if s + d < t0_ns - _RING_ORDER_NS:
+            break
+        spans.append((n, s, s + d, tid))
+    for name, s, e, tid in spans:
+        lo, hi = max(s, t0_ns), min(e, t1_ns)
+        if hi <= lo:
+            continue
+        into = mine if tid == me else others
+        into[name] = into.get(name, 0.0) + (hi - lo) * 1e-9
+        if tid == me:
+            cover.append((lo, hi))
+    covered, end = 0, t0_ns
+    for lo, hi in sorted(cover):
+        if hi > end:
+            covered += hi - max(lo, end)
+            end = hi
+    return mine, others, (t1_ns - t0_ns - covered) * 1e-9
+
+
+# -- garbage collections -------------------------------------------------
+_gc_t0 = [0]
+_gc_pause_ns = [0]
+
+
+def gc_pause_s():
+    """Seconds of collector pause since the process started, on every
+    thread."""
+    return _gc_pause_ns[0] * 1e-9
+
+
+def _on_gc(phase, info):
+    # collections never overlap (the collector holds the GIL and does
+    # not nest), so one start slot serves every thread
+    now = time.perf_counter_ns()
+    if phase == "start":
+        _gc_t0[0] = now
+        return
+    dur = now - _gc_t0[0]
+    gen = info["generation"]
+    _gc_pause_ns[0] += dur
+    try:  # a hook that raises is reported on stderr at every collection
+        monitor.counter(f"gc.collections.gen{gen}").inc()
+        monitor.counter(f"gc.pause_s.gen{gen}").inc(dur * 1e-9)
+        flight_recorder.record_span_event(
+            "host.gc", _gc_t0[0], dur, threading.get_ident(),
+            len(_stack()), {"gen": gen})
+    except Exception:
+        pass
+
+
+if not any(getattr(cb, "__qualname__", "") == _on_gc.__qualname__
+           and getattr(cb, "__module__", "") == __name__
+           for cb in gc.callbacks):
+    gc.callbacks.append(_on_gc)
 
 
 class span:

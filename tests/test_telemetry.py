@@ -19,6 +19,7 @@ import importlib.util
 import json
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -81,7 +82,8 @@ def test_spans_nest_and_aggregate():
 def test_record_span_merges_threads():
     def worker():
         with statistic.span("shared"):
-            statistic.record_span("leaf", 0.001)
+            with statistic.span("leaf"):
+                time.sleep(0.001)
 
     threads = [threading.Thread(target=worker) for _ in range(3)]
     for t in threads:
@@ -89,7 +91,8 @@ def test_record_span_merges_threads():
     for t in threads:
         t.join()
     with statistic.span("shared"):
-        statistic.record_span("leaf", 0.002)
+        with statistic.span("leaf"):
+            time.sleep(0.002)
     shared = statistic.get_events("shared")[0]
     leaf = statistic.get_events("leaf")[0]
     assert shared["count"] == 4 and leaf["count"] == 4
@@ -101,12 +104,15 @@ def test_record_span_merges_threads():
 
 
 def test_summary_table_renders_sorted():
-    statistic.record_span("big", 1.0)
-    statistic.record_span("small", 0.1)
+    with statistic.span("small"):
+        time.sleep(0.005)
+    with statistic.span("big"):
+        time.sleep(0.05)
     table = statistic.summary_table(time_unit="ms")
     assert "Total(ms)" in table
     assert table.index("big") < table.index("small")  # sorted by total
-    assert "100" in table  # small = 100 ms
+    big = statistic.get_events("big")[0]["total_s"]
+    assert big >= 0.05 and f"{big * 1e3:.3f}" in table
 
 
 # --------------------------------------------------- metrics registry

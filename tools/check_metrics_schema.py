@@ -140,6 +140,20 @@ Schema (documented in docs/OBSERVABILITY.md):
                   event        str     non-empty event name
                                        (nan_detected, loss_spike,
                                        watchdog_expired, retrace, ...)
+  kind == "stall" (one record per step interval over twice the median
+                  of the last 32 — jit/api.export_step_metrics)
+                  additionally requires:
+                  step         int     >= 1, the call that ended it
+                  interval_s   number  > 2 * median_s
+                  median_s     number  > 0
+                  start_ns, end_ns int the interval's ends, end > start
+                                       (time.perf_counter_ns)
+                  cpu_s, gc_s  number  >= 0: process CPU seconds and
+                                       collector pause inside it
+                  covered      dict    {"step_thread": {span: s},
+                                       "other_threads": {span: s}},
+                                       every s >= 0
+                  uncovered_s  number  in [0, interval_s]
   kind == "compile" (one record per AOT-compiled executable signature —
                   profiler/compile_observatory.py, fed by
                   jit/api.aot_compile) additionally requires:
@@ -531,6 +545,11 @@ HEALTH_REQUIRED = {"step": int, "loss": (int, float, str),
                    "update_ratio": (int, float, str),
                    "found_inf": (int, float, str)}
 EVENT_REQUIRED = {"event": str}
+STALL_REQUIRED = {"step": int, "interval_s": (int, float),
+                  "median_s": (int, float), "start_ns": int,
+                  "end_ns": int, "cpu_s": (int, float),
+                  "gc_s": (int, float), "covered": dict,
+                  "uncovered_s": (int, float)}
 COMPILE_REQUIRED = {"tag": str, "signature": str,
                     "lower_s": (int, float), "compile_s": (int, float),
                     "cache_hit": bool, "instructions": int,
@@ -740,6 +759,40 @@ def _check_types(rec, required, where, errors):
                           f"{type(val).__name__}, expected {types}")
 
 
+def _check_stall(rec, where, errors):
+    """kind:"stall" — the interval, its median and ends, the CPU and
+    collector seconds in it, what covered it and what did not."""
+    n = len(errors)
+    _check_types(rec, STALL_REQUIRED, where, errors)
+    if len(errors) > n:
+        return
+    if rec["step"] < 1:
+        errors.append(f"{where}: step must be >= 1, got {rec['step']}")
+    if not rec["median_s"] > 0 or not \
+            rec["interval_s"] > 2 * rec["median_s"]:
+        errors.append(
+            f"{where}: a stall's interval_s ({rec['interval_s']}) must "
+            f"exceed twice its median_s ({rec['median_s']}) > 0")
+    if rec["end_ns"] <= rec["start_ns"]:
+        errors.append(f"{where}: end_ns must follow start_ns")
+    for key in ("cpu_s", "gc_s"):
+        if rec[key] < 0:
+            errors.append(f"{where}: {key} must be >= 0, got {rec[key]}")
+    if not 0 <= rec["uncovered_s"] <= rec["interval_s"] + 1e-9:
+        errors.append(
+            f"{where}: uncovered_s must lie in [0, interval_s], got "
+            f"{rec['uncovered_s']}")
+    cov = rec["covered"]
+    if set(cov) != {"step_thread", "other_threads"} or not all(
+            isinstance(v, dict) and all(
+                isinstance(s, (int, float)) and not isinstance(s, bool)
+                and s >= 0 for s in v.values())
+            for v in cov.values()):
+        errors.append(
+            f"{where}: covered must be {{'step_thread': {{span: s}}, "
+            f"'other_threads': {{span: s}}}} with every s >= 0")
+
+
 def _check_spec_fields(rec, where, errors):
     """The speculative-decoding trio (optional on serve, request, and
     journey records — inference/speculative.py): proposed_tokens /
@@ -882,6 +935,8 @@ def validate_line(line, where="<line>"):
         _check_types(rec, EVENT_REQUIRED, where, errors)
         if isinstance(rec.get("event"), str) and not rec["event"]:
             errors.append(f"{where}: event name must be non-empty")
+    elif rec.get("kind") == "stall":
+        _check_stall(rec, where, errors)
     elif rec.get("kind") == "compile":
         _check_types(rec, COMPILE_REQUIRED, where, errors)
         for key in ("tag", "signature"):

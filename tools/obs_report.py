@@ -3,7 +3,8 @@
 
 Renders a human summary from a paddle_tpu metrics JSONL file (the
 PADDLE_TPU_METRICS_FILE export — docs/OBSERVABILITY.md): training step
-rollup (+ measured device time when the probe sampled), the compile
+rollup with every kind:"stall" record under it (what covered the long
+step interval), the compile
 ledger per executable, the serving SLO/goodput rollup, the front-door
 routing section (per-engine placements, handoffs, fleet SLO), the
 cross-engine journey section (kind:"journey" phase splits + the
@@ -76,7 +77,28 @@ def section_steps(recs, out):
     if scans:
         n = sum(int(r.get("steps", 0)) for r in scans)
         out.append(f"  {len(scans)} scanned segments ({n} steps)")
+    for r in recs:
+        if r.get("kind") == "stall":
+            out.extend(_stall_lines(r))
     out.append("")
+
+
+def _stall_lines(r):
+    """A kind:"stall" record under its step: the interval against the
+    median, the collector's and the CPU's seconds in it, what no span
+    on the step's thread covered, and the spans that covered most."""
+    def top(spans):
+        return ", ".join(f"{k} {_fmt_s(float(v))}" for k, v in sorted(
+            spans.items(), key=lambda kv: -kv[1])[:4]) or "nothing"
+    cov = r.get("covered", {})
+    return [f"  stall at step {r.get('step')}: "
+            f"{_fmt_s(float(r.get('interval_s', 0.0)))} against a median "
+            f"of {_fmt_s(float(r.get('median_s', 0.0)))}  gc "
+            f"{_fmt_s(float(r.get('gc_s', 0.0)))}  cpu "
+            f"{_fmt_s(float(r.get('cpu_s', 0.0)))}  uncovered "
+            f"{_fmt_s(float(r.get('uncovered_s', 0.0)))}",
+            f"    step thread: {top(cov.get('step_thread', {}))}",
+            f"    other threads: {top(cov.get('other_threads', {}))}"]
 
 
 def section_compiles(recs, out, top):
